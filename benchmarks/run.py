@@ -7,13 +7,18 @@ timestamp} object per row). Checked-in baselines follow the
 ``BENCH_<suite>.json`` naming convention at the repo root (e.g.
 ``--only kernel_bench --json BENCH_kernels.json``) so the perf trajectory
 is diffable across PRs.
+
+The persistent compile cache is ``JAX_COMPILATION_CACHE_DIR`` when set, else
+``.jax_cache/`` at the repository root.
 """
 from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 import time
+from pathlib import Path
 
 
 def write_json_record(path: str, rows: list[str], quick: bool) -> None:
@@ -37,6 +42,12 @@ def main() -> None:
                     help="also write the rows as a JSON perf record at PATH "
                          "(checked-in baselines: BENCH_<suite>.json)")
     args = ap.parse_args()
+
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        import jax
+
+        cache = Path(__file__).resolve().parents[1] / ".jax_cache"
+        jax.config.update("jax_compilation_cache_dir", str(cache))
 
     from benchmarks import fault_tolerance, kernel_bench, max_data_size
     from benchmarks import sampling_methods, serving_latency, training_curves

@@ -114,6 +114,7 @@ import numpy as np
 from repro.data.pages import TransferStats
 from repro.fault import inject as fault_inject
 from repro.fault.retry import RetryPolicy
+from repro.kernels import ref
 
 Array = jax.Array
 
@@ -213,6 +214,24 @@ def level_row_counts(positions: Array, offset: int, count: int) -> Array:
         return jnp.sum(hit, axis=0).astype(jnp.int32)
     safe = jnp.where(valid, lp, count)  # overflow slot for non-window rows
     return jnp.zeros(count + 1, jnp.int32).at[safe].add(1)[:count]
+
+
+@functools.partial(jax.jit, static_argnames=("n_nodes",))
+def node_grad_sums(positions: Array, g: Array, h: Array, n_nodes: int) -> tuple[Array, Array]:
+    """(sum g, sum h) of the rows at each global node id in ``[0, n_nodes)``;
+    rows with a negative position count nowhere.
+
+    Each sum is exact up to about one rounding, whatever the order of the
+    rows (`kernels.ref.scatter_sum`), so builders that hold the same rows in
+    other pages or shards agree to f32 precision once their partial sums
+    are added."""
+    valid = positions >= 0
+    flat = jnp.where(valid, positions, 0).astype(jnp.int32)
+    n = positions.shape[0]
+    return tuple(
+        ref.scatter_sum(flat, jnp.where(valid, w.astype(jnp.float32), 0.0), n_nodes, n)
+        for w in (g, h)
+    )
 
 
 @jax.jit

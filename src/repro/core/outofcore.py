@@ -37,6 +37,7 @@ from repro.core.histcache import (
     HistogramCache,
     LevelPlan,
     level_row_counts,
+    node_grad_sums,
     node_row_counts,
 )
 from repro.core.policy import ExecutionPolicy
@@ -204,9 +205,19 @@ def build_tree_paged(
                 counts = c if counts is None else counts + c
         return counts
 
+    def leaf_sums_fn():
+        # positions live on device; no page needs to be streamed again
+        sums = [
+            node_grad_sums(
+                positions[i], g_j[ro:ro + nr], h_j[ro:ro + nr], tp.n_total_nodes
+            )
+            for i, (ro, nr) in enumerate(page_extents)
+        ]
+        return tuple(sum(parts[1:], parts[0]) for parts in zip(*sums))
+
     tree = tree_growth_driver(tp)(
-        hist_fn, partition_fn, jnp.sum(g_j), jnp.sum(h_j), n_bins, bin_valid,
-        tp, cut_values, cut_ptrs, hist_cache=hist_cache,
+        hist_fn, partition_fn, leaf_sums_fn, jnp.sum(g_j), jnp.sum(h_j), n_bins,
+        bin_valid, tp, cut_values, cut_ptrs, hist_cache=hist_cache,
     )
     return tree, positions
 

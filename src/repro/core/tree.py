@@ -9,14 +9,15 @@ jit-able:
             -> EvaluateSplit              (core.split.evaluate_splits)
             -> RepartitionInstances       (kernels.ops.partition_rows)
 
-`grow_tree_generic` drives the levels through two callbacks — histogram
-accumulation and row repartition — so the same driver serves:
+`grow_tree_generic` drives the levels through three callbacks — histogram
+accumulation, row repartition and the final per-node gradient sums — so the
+same driver serves:
   * the in-core builder (`grow_tree`, one device-resident page, Alg. 1),
   * the out-of-core streaming builder (page loop per level, Alg. 6),
   * the distributed paged builder (sharded staging + per-page mesh reduce).
 
 `grow_tree_lossguide_generic` is the best-first (LightGBM lossguide) sibling
-over the same two callbacks: a gain-ordered frontier pops one leaf at a time,
+over the same callbacks: a gain-ordered frontier pops one leaf at a time,
 expands it via per-node 2-wide `LevelPlan`s, and repartitions only that
 node's rows. Select with ``TreeParams(grow_policy="lossguide",
 max_leaves=...)``; every builder dispatches through `tree_growth_driver`.
@@ -35,6 +36,14 @@ window is rebuilt from rows. Disable per tree with
 Rows carry a global node-id position; once their node becomes a leaf the
 position freezes, so after the last level `leaf_value[pos]` is the tree's
 prediction for every training row (a single gather for the margin update).
+
+Leaf weights come from the gradient sums of the rows that end at each leaf
+(`LeafSumsFn`), not from the split search's running sums. Those are
+``node - cumsum(bins)`` differences of large f32 numbers, so a deep leaf's
+sum inherits an error of about one ulp of its ancestors' sums, and two
+builders that add the same rows in another order (pages, shards, the MXU
+kernel and the XLA scatter) would disagree on small leaves beyond f32
+tolerance. Splits still come from the histograms.
 """
 from __future__ import annotations
 
@@ -50,6 +59,7 @@ from repro.core.histcache import (
     HistogramCache,
     LevelPlan,
     level_row_counts,
+    node_grad_sums,
     node_row_counts,
 )
 from repro.core.split import LevelSplits, SplitParams, evaluate_splits, leaf_weight
@@ -190,10 +200,27 @@ PartitionFn = Callable[
     [Array, Array, Array, Array, "tuple[int, int] | Array | None"], Array | None
 ]
 
+# LeafSumsFn() -> (node_g, node_h), each (n_total,) f32
+#
+# Called once, after the last repartition: the sums of g and h over the rows
+# at each node (summed across pages/shards — use
+# `core.histcache.node_grad_sums`). The drivers take every leaf weight from
+# these.
+LeafSumsFn = Callable[[], "tuple[Array, Array]"]
+
+
+def leaf_values(is_leaf: Array, node_g: Array, node_h: Array, reg_lambda: float) -> Array:
+    """Eq.-(6) weight at every reachable leaf (the root, or a child of a
+    split node); 0 at internal nodes and unreachable heap slots."""
+    parents = (jnp.arange(1, is_leaf.shape[0]) - 1) // 2
+    reachable = jnp.concatenate([jnp.ones(1, bool), ~is_leaf[parents]])
+    return jnp.where(is_leaf & reachable, leaf_weight(node_g, node_h, reg_lambda), 0.0)
+
 
 def grow_tree_generic(
     hist_fn: HistFn,
     partition_fn: PartitionFn,
+    leaf_sums_fn: LeafSumsFn,
     total_g: Array,
     total_h: Array,
     n_bins: int,
@@ -215,7 +242,6 @@ def grow_tree_generic(
     split_bin = jnp.zeros(n_total, jnp.int32)
     default_left = jnp.zeros(n_total, bool)
     is_leaf = jnp.ones(n_total, bool)
-    leaf_value = jnp.zeros(n_total, jnp.float32)
     node_g = jnp.zeros(n_total, jnp.float32).at[0].set(total_g)
     node_h = jnp.zeros(n_total, jnp.float32).at[0].set(total_h)
 
@@ -242,9 +268,6 @@ def grow_tree_generic(
         split_bin = split_bin.at[idx].set(jnp.where(do_split, splits.split_bin, 0))
         default_left = default_left.at[idx].set(splits.default_left & do_split)
         is_leaf = is_leaf.at[idx].set(~do_split)
-        # nodes finalized as leaves at this level get their weight (eq. 6)
-        w = leaf_weight(lvl_g, lvl_h, params.split.reg_lambda)
-        leaf_value = leaf_value.at[idx].set(jnp.where(do_split | ~growable, 0.0, w))
 
         left_idx, right_idx = 2 * idx + 1, 2 * idx + 2
         node_g = node_g.at[left_idx].set(jnp.where(do_split, splits.left_g, 0.0))
@@ -266,21 +289,9 @@ def grow_tree_generic(
             feature, split_bin, default_left, is_leaf, count_level
         )
 
-    # final level: every still-growable node is a leaf with eq.-(6) weight
-    offset = 2**max_depth - 1
-    count = 2**max_depth
-    idx = offset + jnp.arange(count)
-    lvl_g = jax.lax.dynamic_slice(node_g, (offset,), (count,))
-    lvl_h = jax.lax.dynamic_slice(node_h, (offset,), (count,))
-    growable = (
-        ~jax.lax.dynamic_slice(is_leaf, (offset,), (count,))
-        if max_depth
-        else jnp.ones(1, bool)
-    )
-    w = leaf_weight(lvl_g, lvl_h, params.split.reg_lambda)
-    leaf_value = leaf_value.at[idx].set(jnp.where(growable, w, leaf_value[idx]))
-    is_leaf = is_leaf.at[idx].set(True)
-
+    # the last level's nodes are all leaves
+    is_leaf = is_leaf.at[2**max_depth - 1:].set(True)
+    leaf_value = leaf_values(is_leaf, *leaf_sums_fn(), params.split.reg_lambda)
     split_value = _finalize_split_values(feature, split_bin, is_leaf, cut_values, cut_ptrs)
 
     return TreeArrays(
@@ -326,6 +337,7 @@ def _finalize_split_values(
 def grow_tree_lossguide_generic(
     hist_fn: HistFn,
     partition_fn: PartitionFn,
+    leaf_sums_fn: LeafSumsFn,
     total_g: Array,
     total_h: Array,
     n_bins: int,
@@ -336,7 +348,7 @@ def grow_tree_lossguide_generic(
     hist_cache: HistogramCache | None = None,
 ) -> TreeArrays:
     """Best-first (loss-guided, LightGBM-style) growth over the same
-    HistFn/PartitionFn contracts as `grow_tree_generic`.
+    HistFn/PartitionFn/LeafSumsFn contracts as `grow_tree_generic`.
 
     A gain-ordered frontier pops the single best candidate leaf and expands
     only it: the split is written into the heap-layout arrays, one
@@ -480,10 +492,7 @@ def grow_tree_lossguide_generic(
     for _, node, _ in frontier:
         cache.discard_node(node)
 
-    # every reachable leaf gets its eq.-(6) weight; unreachable heap slots
-    # have node_g == node_h == 0 so their weight is exactly 0
-    w = leaf_weight(node_g, node_h, params.split.reg_lambda)
-    leaf_value = jnp.where(is_leaf, w, 0.0)
+    leaf_value = leaf_values(is_leaf, *leaf_sums_fn(), params.split.reg_lambda)
     split_value = _finalize_split_values(feature, split_bin, is_leaf, cut_values, cut_ptrs)
 
     return TreeArrays(
@@ -498,7 +507,7 @@ def grow_tree_lossguide_generic(
 
 def tree_growth_driver(params: TreeParams):
     """The generic driver for ``params.grow_policy`` — both drivers share the
-    HistFn/PartitionFn contracts, so every builder dispatches through here."""
+    HistFn/PartitionFn/LeafSumsFn contracts, so every builder dispatches through here."""
     if params.grow_policy == "lossguide":
         return grow_tree_lossguide_generic
     return grow_tree_generic
@@ -556,6 +565,7 @@ def grow_tree(
     tree = tree_growth_driver(params)(
         hist_fn,
         partition_fn,
+        lambda: node_grad_sums(pos_box[0], g, h, params.n_total_nodes),
         jnp.sum(g),
         jnp.sum(h),
         n_bins,

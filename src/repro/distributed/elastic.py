@@ -11,9 +11,10 @@ pillars instead of inventing new distributed state:
                               builder; its HistFn sums per-shard histograms
                               returned over RPC (in shard-id order, so the
                               f32 total is independent of *which worker*
-                              serves a shard) and its PartitionFn broadcasts
+                              serves a shard), its PartitionFn broadcasts
                               the split arrays and sums the returned row
-                              counts. All split evaluation, subtraction
+                              counts, and its LeafSumsFn sums the shards'
+                              per-node gradient sums. All split evaluation, subtraction
                               planning, and tree layout stay centralized and
                               bit-identical to the single-process builders.
 
@@ -39,7 +40,7 @@ RPC discipline: requests carry a ``req_id`` and replies echo it, so a
 timed-out request's late reply is discarded rather than mismatched. Worker
 errors marked transient (I/O class) are retried under ``ElasticConfig.retry``
 — every op the coordinator retries is idempotent (``begin_tree`` resets
-per-tree state; ``hist`` is a pure read; ``partition`` re-routes rows to
+per-tree state; ``hist`` and ``leaf_sums`` are pure reads; ``partition`` re-routes rows to
 freshly-split children whose rows are not yet re-partitioned anywhere else).
 ``finish_tree`` mutates margins cumulatively and is therefore *never*
 retried: if it fails, the coordinator falls back to checkpoint recovery,
@@ -251,6 +252,11 @@ class WorkerHandle:
                 pass
 
 
+# Workers are CPU processes. A chip belongs to the one process that loaded
+# it first, so a worker that inherited a TPU platform would fail or hang.
+WORKER_ENV = {"JAX_PLATFORMS": "cpu"}
+
+
 # -------------------------------------------------------------------- config
 @dataclasses.dataclass(frozen=True)
 class ElasticConfig:
@@ -272,7 +278,7 @@ class ElasticConfig:
     checkpoint_every: int = 1
     retry: RetryPolicy = RetryPolicy(max_attempts=3, base_delay=0.1)
     python: str | None = None  # interpreter for workers (None = sys.executable)
-    env: dict[str, str] | None = None  # extra env for workers
+    env: dict[str, str] | None = None  # extra env for workers, over WORKER_ENV
 
     def __post_init__(self) -> None:
         if self.n_workers < 1:
@@ -399,7 +405,7 @@ class ElasticTrainer:
             print(f"[elastic] {msg}", file=sys.stderr)
 
     def _spawn_worker(self, *, with_faults: bool) -> WorkerHandle:
-        env = dict(self.cfg.env or {})
+        env = {**WORKER_ENV, **(self.cfg.env or {})}
         if with_faults and self.fault_plan is not None:
             env[fault_inject.ENV_VAR] = self.fault_plan.to_json()
         else:
@@ -584,10 +590,21 @@ class ElasticTrainer:
                     counts = c if counts is None else counts + c
             return None if counts is None else jnp.asarray(counts)
 
+        def leaf_sums_fn():
+            total = np.zeros((2, tp.n_total_nodes), np.float32)
+            for sid in sorted(self._owner):
+                rep = self._request(
+                    self._owner[sid],
+                    {"op": "leaf_sums", "shard": sid, "n_nodes": tp.n_total_nodes},
+                )
+                total += rep["sums"]
+            return jnp.asarray(total[0]), jnp.asarray(total[1])
+
         grow = tree_growth_driver(tp)
         return grow(
             hist_fn,
             partition_fn,
+            leaf_sums_fn,
             jnp.float32(total_g),
             jnp.float32(total_h),
             self.n_bins,

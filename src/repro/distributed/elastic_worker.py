@@ -215,6 +215,20 @@ class _WorkerState:
                 counts = c if counts is None else counts + c
         return {"counts": None if counts is None else np.asarray(counts)}
 
+    def _op_leaf_sums(self, msg: dict) -> dict:
+        import numpy as np
+
+        from repro.core.histcache import node_grad_sums
+
+        sh = self.shards[int(msg["shard"])]
+        n_nodes = int(msg["n_nodes"])
+        total = np.zeros((2, n_nodes), np.float32)
+        for i, (ro, nr) in enumerate(sh.pages.page_extents):
+            total += np.asarray(node_grad_sums(
+                sh.positions[i], sh.g[ro : ro + nr], sh.h[ro : ro + nr], n_nodes
+            ))
+        return {"sums": total}
+
     def _op_finish_tree(self, msg: dict) -> dict:
         import numpy as np
 

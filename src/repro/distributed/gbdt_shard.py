@@ -50,28 +50,27 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.histcache import (
     HistogramStore,
     expand_level,
     level_row_counts,
+    node_grad_sums,
     plan_level,
 )
-from repro.core.split import evaluate_splits, leaf_weight
-from repro.core.tree import TreeArrays, TreeParams, grow_tree_lossguide_generic
+from repro.core.split import evaluate_splits
+from repro.core.tree import (
+    TreeArrays,
+    TreeParams,
+    grow_tree_lossguide_generic,
+    leaf_values,
+)
 from repro.kernels import ops, ref
 
 Array = jax.Array
 
-# jax >= 0.6 exposes shard_map at top level (check_vma); older releases ship
-# it under jax.experimental with the check_rep spelling.
-if hasattr(jax, "shard_map"):
-    _shard_map = functools.partial(jax.shard_map, check_vma=False)
-else:  # pragma: no cover - exercised on older jax only
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    _shard_map = functools.partial(_experimental_shard_map, check_rep=False)
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -225,7 +224,6 @@ def _grow_tree_local(
     split_bin = jnp.zeros(n_total, jnp.int32)
     default_left = jnp.zeros(n_total, bool)
     is_leaf = jnp.ones(n_total, bool)
-    leaf_value = jnp.zeros(n_total, jnp.float32)
     total_g = jax.lax.psum(jnp.sum(g), cfg.data_axes)
     total_h = jax.lax.psum(jnp.sum(h), cfg.data_axes)
     node_g = jnp.zeros(n_total, jnp.float32).at[0].set(total_g)
@@ -290,8 +288,6 @@ def _grow_tree_local(
         split_bin = split_bin.at[idx].set(jnp.where(do_split, s_bin, 0))
         default_left = default_left.at[idx].set(s_dleft & do_split)
         is_leaf = is_leaf.at[idx].set(~do_split)
-        w = leaf_weight(lvl_g, lvl_h, tp.split.reg_lambda)
-        leaf_value = leaf_value.at[idx].set(jnp.where(do_split | ~growable, 0.0, w))
 
         left_idx, right_idx = 2 * idx + 1, 2 * idx + 2
         node_g = node_g.at[left_idx].set(jnp.where(do_split, s_lg, 0.0))
@@ -336,20 +332,11 @@ def _grow_tree_local(
                 level_row_counts(positions, noff, ncnt), cfg.data_axes
             )
 
-    # final level
-    offset = 2**max_depth - 1
-    count = 2**max_depth
-    idx = offset + jnp.arange(count)
-    lvl_g = jax.lax.dynamic_slice(node_g, (offset,), (count,))
-    lvl_h = jax.lax.dynamic_slice(node_h, (offset,), (count,))
-    growable = (
-        ~jax.lax.dynamic_slice(is_leaf, (offset,), (count,))
-        if max_depth
-        else jnp.ones(1, bool)
-    )
-    w = leaf_weight(lvl_g, lvl_h, tp.split.reg_lambda)
-    leaf_value = leaf_value.at[idx].set(jnp.where(growable, w, leaf_value[idx]))
-    is_leaf = is_leaf.at[idx].set(True)
+    # the last level's nodes are all leaves; their weights come from the
+    # rows that end there, summed over the data shards
+    is_leaf = is_leaf.at[2**max_depth - 1:].set(True)
+    sums = jax.lax.psum(node_grad_sums(positions, g, h, n_total), cfg.data_axes)
+    leaf_value = leaf_values(is_leaf, *sums, tp.split.reg_lambda)
 
     if cut_values is not None and cut_ptrs is not None:
         split_value = cut_values[cut_ptrs[feature] + split_bin]
@@ -438,6 +425,12 @@ def _grow_tree_distributed_lossguide(
         in_specs=(bins_spec, vec_spec, rep, rep, rep, rep, rep),
         out_specs=(vec_spec, rep),
     ))
+    sums_step = jax.jit(_shard_map(
+        lambda pos_l, g_l, h_l: jax.lax.psum(
+            node_grad_sums(pos_l, g_l, h_l, tp.n_total_nodes), cfg.data_axes
+        ),
+        mesh=mesh, in_specs=(vec_spec, vec_spec, vec_spec), out_specs=rep,
+    ))
 
     def hist_fn(offset, count, plan):
         node_map = (
@@ -464,7 +457,8 @@ def _grow_tree_distributed_lossguide(
         grad_transport=cfg.grad_transport,  # narrows spill/fetch wires too
     )
     tree = grow_tree_lossguide_generic(
-        hist_fn, partition_fn, jnp.sum(g_j), jnp.sum(h_j), n_bins, bin_valid,
+        hist_fn, partition_fn, lambda: sums_step(pos_box[0], g_j, h_j),
+        jnp.sum(g_j), jnp.sum(h_j), n_bins, bin_valid,
         tp, cut_values, cut_ptrs, hist_cache=cache,
     )
     return tree, pos_box[0]
@@ -584,8 +578,13 @@ def grow_tree_distributed(
 
 def sharded_page_put(mesh: Mesh, cfg: DistConfig) -> Callable[[np.ndarray], Array]:
     """Device-put for `repro.pipeline.PageStream`: stage a page row-sharded
-    over the data axes (uint8 over the wire, int32 on device)."""
-    sharding = NamedSharding(mesh, P(cfg.data_axes))
+    over the data axes (uint8 over the wire, int32 on device).
+
+    The page is placed on an Auto-typed view of ``mesh``: the paged builder
+    mixes these pages with replicated per-page positions and node tables,
+    and leaves the sharding of each result to the compiler."""
+    auto = Mesh(mesh.devices, mesh.axis_names, axis_types=(AxisType.Auto,) * mesh.devices.ndim)
+    sharding = NamedSharding(auto, P(cfg.data_axes))
 
     def put(arr: np.ndarray) -> Array:
         out = jax.device_put(arr, sharding)
@@ -764,6 +763,7 @@ def fit_sharded(
     from repro.core.booster import EvalRecord
     from repro.core.tree import predict_tree_bins
 
+    row_sharding = NamedSharding(mesh, P(cfg.data_axes))
     t0 = time.perf_counter()
     for it in range(params.n_estimators):
         g, h = booster.objective.grad_hess(margin, labels_j)
@@ -776,7 +776,10 @@ def fit_sharded(
             transfer_stats=booster.stats,
         )
         booster.trees.append(tree)
-        margin = margin + params.learning_rate * tree.leaf_value[positions]
+        # the leaf table is replicated and positions are row-sharded: the
+        # gather keeps the rows' sharding
+        leaf = tree.leaf_value.at[positions].get(out_sharding=row_sharding)
+        margin = margin + params.learning_rate * leaf
         if eval_bins is not None:
             pred = predict_tree_bins(tree, eval_bins, tp.max_depth)
             eval_margin = eval_margin + params.learning_rate * pred

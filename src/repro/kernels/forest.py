@@ -3,21 +3,22 @@ r"""Pallas TPU kernel: fused batched forest traversal via one-hot MXU gathers.
 Serving adaptation of the same scatter->matmul reformulation the histogram
 kernel uses. CUDA serving kernels (the 1806.11248 fused predictor) walk one
 tree per thread with gather loads; TPUs have no per-lane gathers from VMEM, so
-every node-attribute lookup ``attr[pos]`` is reformulated as a one-hot
-contraction that lowers to an MXU matmul:
+each descent step reuses the partition kernel's pieces
+(`kernels.partition.gather_nodes` / `route`): the four node attributes of
+every row's current node come from one one-hot contraction, and the value of
+its split feature from a masked sublane sum over feature-major bins.
 
-    attr_r = onehot(pos_r == j) @ attr[j]          # (R, n_total) @ (n_total, k)
-    bval_r = sum_f bins[r, f] * onehot(f == f_r)   # (R, m) elementwise + reduce
+The final leaf value is gathered on the VPU, as a masked sum over a node
+column in which exactly one term is nonzero, so it is the exact f32 leaf.
 
 The grid tiles (rows, trees); trees are the innermost (sequential) grid dim so
-the output margin block is revisited and accumulated in VMEM across trees —
-one launch predicts the whole forest, and the accumulation order (tree 0, 1,
-...) matches the per-tree reference bit-for-bit.
+the ``(1, R)`` output margin block is revisited and accumulated in VMEM across
+trees — one launch predicts the whole forest, and the accumulation order
+(tree 0, 1, ...) matches the per-tree reference bit-for-bit.
 
-VMEM working set per grid step (defaults R=256, n_total<=8191, m<=512):
-  node one-hot (R, n_total) f32 <= 8 MiB at depth 12, attrs (n_total, 4) f32,
-  bins (R, m) f32, margin block (R,) f32 — under 16 MiB VMEM for the tree
-  depths GBDT serving sees (deeper forests page through chunked launches).
+VMEM per grid step (R=512, N_p=512 at depth 8, m<=512): node one-hot
+(N_p, R) f32 = 1 MiB, bins (m, R) int32 <= 1 MiB, node tables (8, N_p) and
+(N_p, 1) f32.
 """
 from __future__ import annotations
 
@@ -27,47 +28,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels._backend import resolve_interpret
+from repro.kernels._backend import LANES, resolve_interpret, round_up
+from repro.kernels.partition import MISSING_BIN, gather_nodes, node_attr_rows, route
 
-MISSING_BIN = 255
 
-
-def _forest_kernel(
-    bins_ref, attrs_ref, leaf_ref, margin_ref, out_ref, *, n_total: int, max_depth: int,
-):
-    t_step = pl.program_id(1)
-    bins = bins_ref[...].astype(jnp.float32)  # (R, m); bin ids exact in f32
-    attrs = attrs_ref[0]  # (n_total, 4) f32: feature, split_bin, default_left, is_leaf
-    leaf_value = leaf_ref[0]  # (n_total,) f32
-    R, m = bins.shape
-
-    def node_onehot(pos):
-        node_iota = jax.lax.broadcasted_iota(jnp.int32, (R, n_total), 1)
-        return (pos[:, None] == node_iota).astype(jnp.float32)
-
-    contract = (((1,), (0,)), ((), ()))  # contract nodes
-    pos = jnp.zeros((R,), jnp.int32)
+def _forest_kernel(bins_ref, attrs_ref, leaf_ref, margin_ref, out_ref, *, max_depth: int):
+    bins = bins_ref[...]  # (m, R) int32
+    table = attrs_ref[0]  # (8, N_p) f32: feature, split_bin, default_left, is_leaf
+    leaf_col = leaf_ref[0]  # (N_p, 1) f32, pre-scaled by the learning rate
+    pos = jnp.zeros((1, bins.shape[1]), jnp.int32)
     for _ in range(max_depth):
-        a = jax.lax.dot_general(
-            node_onehot(pos), attrs, contract, preferred_element_type=jnp.float32
-        )  # (R, 4) — the four node attributes of each row's current node
-        f_idx = a[:, 0].astype(jnp.int32)
-        sbin, dleft, leaf = a[:, 1], a[:, 2] > 0.5, a[:, 3] > 0.5
-        feat_iota = jax.lax.broadcasted_iota(jnp.int32, (R, m), 1)
-        feat_oh = (f_idx[:, None] == feat_iota).astype(jnp.float32)
-        bval = jnp.sum(bins * feat_oh, axis=1)  # bins[r, f_idx_r]
-        missing = bval == float(MISSING_BIN)
-        go_left = jnp.where(missing, dleft, bval <= sbin)
-        child = 2 * pos + 1 + jnp.where(go_left, 0, 1)
-        pos = jnp.where(leaf, pos, child)
+        pos = route(bins, pos, gather_nodes(table, pos))
 
-    # leaf gather: one nonzero term per row, every other product exactly 0.0,
-    # so the contraction is the exact leaf value
-    leaf_val = jax.lax.dot_general(
-        node_onehot(pos), leaf_value[:, None], contract, preferred_element_type=jnp.float32
-    )[:, 0]
+    node_iota = jax.lax.broadcasted_iota(jnp.int32, (leaf_col.shape[0], pos.shape[1]), 0)
+    leaf_val = jnp.sum(jnp.where(node_iota == pos, leaf_col, 0.0), axis=0, keepdims=True)
 
-    @pl.when(t_step == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = margin_ref[...]
 
@@ -75,14 +51,6 @@ def _forest_kernel(
     # add — no multiply-add for the compiler to contract into an FMA, keeping
     # the accumulation bit-for-bit the per-tree reference's
     out_ref[...] += leaf_val
-
-
-def _pad_rows(x: jax.Array, size: int, fill) -> jax.Array:
-    pad = size - x.shape[0]
-    if pad <= 0:
-        return x
-    widths = [(0, pad)] + [(0, 0)] * (x.ndim - 1)
-    return jnp.pad(x, widths, constant_values=fill)
 
 
 @functools.partial(
@@ -99,48 +67,40 @@ def predict_forest(
     max_depth: int,
     margin_in: jax.Array,  # (n_rows,) f32
     *,
-    row_tile: int = 256,
+    row_tile: int = 512,
     interpret: bool | None = None,
 ) -> jax.Array:
     """One fused launch over the whole forest; returns the updated margins."""
     interpret = resolve_interpret(interpret)
     n_rows, m = bins.shape
     n_trees, n_total = feature.shape
-    n_rows_p = n_rows + (-n_rows % row_tile)
+    rt = min(row_tile, round_up(max(n_rows, 1), LANES))
+    n_rows_p = round_up(max(n_rows, 1), rt)
+    n_p = round_up(n_total, LANES)
 
-    # pack the per-step node attributes into one (T, n_total, 4) matrix so a
-    # single MXU contraction gathers all four at once; ids are small ints,
-    # exact in f32
-    attrs = jnp.stack(
-        [
-            feature.astype(jnp.float32),
-            split_bin.astype(jnp.float32),
-            default_left.astype(jnp.float32),
-            is_leaf.astype(jnp.float32),
-        ],
-        axis=-1,
-    )
+    attrs = node_attr_rows(feature, split_bin, default_left, is_leaf)  # (T, 8, N_p)
+    leaf_col = jnp.pad(
+        leaf_value.astype(jnp.float32), ((0, 0), (0, n_p - n_total))
+    )[:, :, None]
     # padding rows traverse on MISSING_BIN (default direction) — harmless,
     # sliced off below
-    bins_p = _pad_rows(bins.astype(jnp.int32), n_rows_p, MISSING_BIN)
-    margin_p = _pad_rows(margin_in.astype(jnp.float32), n_rows_p, 0.0)
+    bins_t = jnp.pad(
+        bins.astype(jnp.int32).T, ((0, 0), (0, n_rows_p - n_rows)),
+        constant_values=MISSING_BIN,
+    )
+    margin_p = jnp.pad(margin_in.astype(jnp.float32), (0, n_rows_p - n_rows))[None, :]
 
-    grid = (n_rows_p // row_tile, n_trees)
     out = pl.pallas_call(
-        functools.partial(
-            _forest_kernel,
-            n_total=n_total,
-            max_depth=max_depth,
-        ),
-        grid=grid,
+        functools.partial(_forest_kernel, max_depth=max_depth),
+        grid=(n_rows_p // rt, n_trees),
         in_specs=[
-            pl.BlockSpec((row_tile, m), lambda r, t: (r, 0)),
-            pl.BlockSpec((1, n_total, 4), lambda r, t: (t, 0, 0)),
-            pl.BlockSpec((1, n_total), lambda r, t: (t, 0)),
-            pl.BlockSpec((row_tile,), lambda r, t: (r,)),
+            pl.BlockSpec((m, rt), lambda r, t: (0, r)),
+            pl.BlockSpec((1, 8, n_p), lambda r, t: (t, 0, 0)),
+            pl.BlockSpec((1, n_p, 1), lambda r, t: (t, 0, 0)),
+            pl.BlockSpec((1, rt), lambda r, t: (0, r)),
         ],
-        out_specs=pl.BlockSpec((row_tile,), lambda r, t: (r,)),
-        out_shape=jax.ShapeDtypeStruct((n_rows_p,), jnp.float32),
+        out_specs=pl.BlockSpec((1, rt), lambda r, t: (0, r)),
+        out_shape=jax.ShapeDtypeStruct((1, n_rows_p), jnp.float32),
         interpret=interpret,
-    )(bins_p, attrs, leaf_value.astype(jnp.float32), margin_p)
-    return out[:n_rows]
+    )(bins_t, attrs, leaf_col, margin_p)
+    return out[0, :n_rows]

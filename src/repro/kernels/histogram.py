@@ -2,19 +2,31 @@ r"""Pallas TPU kernel: node-aware gradient histogram via one-hot MXU contraction
 
 TPU adaptation of the paper's BuildHistograms hot spot. CUDA builds gradient
 histograms with atomic scatter-adds into shared memory; TPUs have no atomics,
-so we reformulate the scatter as two dense one-hot contractions that lower to
-MXU matmuls:
+so we reformulate the scatter as dense one-hot contractions that lower to
+MXU matmuls, one per feature:
 
-    hist[n, f*B + b] = sum_r (onehot(pos_r == n) * g_r)  @  onehot(bin_{r,f} == b)
-                        \____________(R, N)___________/     \______(R, F*B)______/
+    slab[k, f*B + b] = sum_r  W[k, r]  *  [bin_{r,f} == b]
+    W[k, r] = [pos_r == node_k] * g_r     for k <  S   (gradient rows)
+    W[k, r] = [pos_r == node_k] * h_r     for k >= S   (hessian rows)
 
-The grid tiles (features, rows); rows are the innermost (sequential) grid dim
-so the output block is revisited and accumulated in VMEM across row tiles.
+Each g and h is split exactly into an integer part ``q / scale`` and a small
+remainder (`_fixed_point_split`). The integer parts sum exactly, in f32 on
+the MXU within a row tile and in int32 across tiles, so a bin comes out as
+its exact sum rounded once, whatever the order of the rows, and builders
+that add the rows in another order (pages, shards, the XLA scatter oracle)
+see the same bins and choose the same splits.
 
-VMEM working set per grid step (defaults R=256, Ft=8, B=256, N<=128):
-  bin one-hot (R, Ft*B) f32 = 2 MiB, node one-hot (R, N) f32 = 128 KiB,
-  out block (N, Ft, B, 2) f32 <= 2 MiB  -> comfortably under 16 MiB VMEM,
-MXU shapes (N x R) @ (R x Ft*B) with Ft*B a multiple of 128.
+The kernel reads bins feature-major, ``(m, n_rows)``, so that a (features,
+rows) block is (8, R): sublanes by lanes, as the TPU tiling wants. It writes
+the ``(2S, m*B)`` slabs the contraction produces; `build_histogram_nodes`
+reshapes them to the ``(S, m, B, 2)`` layout every caller reads. The grid
+tiles (features, rows); rows are the innermost (sequential) grid dim, so the
+output blocks stay in VMEM and accumulate across row tiles.
+
+VMEM per grid step (R=1024, Ft=8, B=256, S=128): bin one-hot (B, R) f32 =
+1 MiB, weights 2 x (2S, R) f32 = 2 MiB, output blocks 2 x (2S, Ft*B) =
+4 MiB, double-buffered: about 20 MiB, over the default scoped limit of
+16 MiB, hence `_VMEM_LIMIT`. Larger build sets run in chunks of `_MAX_SLOTS`.
 """
 from __future__ import annotations
 
@@ -25,152 +37,152 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._backend import resolve_interpret
+from repro.kernels._backend import LANES, resolve_interpret, round_up
 from repro.kernels.ref import apply_node_map
 
 MISSING_BIN = 255
+_MAX_SLOTS = 128  # build nodes per launch
+_VMEM_LIMIT = 32 * 2**20  # of the v5e's 128 MiB VMEM
 
 
-def _hist_kernel(bins_ref, g_ref, h_ref, pos_ref, out_ref, *, n_nodes: int, n_bins: int):
-    r_step = pl.program_id(1)
-    bins = bins_ref[...]  # (R, Ft) int32
-    g = g_ref[...]  # (R,) f32
-    h = h_ref[...]
-    pos = pos_ref[...]  # (R,) int32
-    R, Ft = bins.shape
+def _fixed_point_split(
+    w: jax.Array, max_terms: int, q_bits: int
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Split f32 ``w`` into ``q / scale + lo``, exactly.
 
-    node_iota = jax.lax.broadcasted_iota(jnp.int32, (R, n_nodes), 1)
-    node_oh = (pos[:, None] == node_iota).astype(jnp.float32)  # (R, N); pos<0 matches none
-    bin_iota = jax.lax.broadcasted_iota(jnp.int32, (R, Ft, n_bins), 2)
-    valid = (bins != MISSING_BIN)[..., None]
-    bin_oh = jnp.where((bins[..., None] == bin_iota) & valid, 1.0, 0.0)
-    bin_oh = bin_oh.reshape(R, Ft * n_bins)
-
-    contract = (((0,), (0,)), ((), ()))  # contract rows
-    hg = jax.lax.dot_general(
-        node_oh * g[:, None], bin_oh, contract, preferred_element_type=jnp.float32
-    )
-    hh = jax.lax.dot_general(
-        node_oh * h[:, None], bin_oh, contract, preferred_element_type=jnp.float32
-    )
-    update = jnp.stack(
-        [hg.reshape(n_nodes, Ft, n_bins), hh.reshape(n_nodes, Ft, n_bins)], axis=-1
-    )
-
-    @pl.when(r_step == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += update
-
-
-def _fused_hist_kernel(
-    nodes_ref, bins_ref, g_ref, h_ref, pos_ref, out_ref, acc_ref, *, n_bins: int
-):
-    """Fused bin-lookup + multi-node scatter, one launch per (feat, row) tile.
-
-    Fuses what used to be two separate device passes — the caller-side window
-    mask / `apply_node_map` remap and the one-hot scatter — into a single
-    kernel: rows are matched against the *global* node ids in ``nodes_ref``
-    directly (a broadcast compare, no gather), so non-contiguous build sets
-    (batched lossguide pops) cost nothing extra. The accumulator is privatized
-    in VMEM scratch (`acc_ref`) across the sequential row-tile grid dim —
-    the Pallas analogue of CUDA's shared-memory histogram privatization —
-    and flushed to the output block once, on the last row step.
+    ``scale`` is a power of two and ``q`` holds integers with
+    ``|q| <= 2^q_bits``, capped so that ``max_terms`` of them sum below 2^30:
+    their sum is exact in int32, whatever the order. ``|lo| <= 0.5 / scale``.
     """
-    r_step = pl.program_id(1)
-    bins = bins_ref[...]  # (R, Ft) int32
-    g = g_ref[...]  # (R,) f32
-    h = h_ref[...]
-    pos = pos_ref[...]  # (R,) int32 global node ids
-    nodes = nodes_ref[...]  # (S,) int32 global build-node ids (all >= 0)
-    R, Ft = bins.shape
-    S = nodes.shape[0]
+    k = min(q_bits, 30 - max(int(max_terms) - 1, 1).bit_length())
+    _, exp = jnp.frexp(jnp.max(jnp.abs(w), initial=0.0))
+    e = jnp.clip(k - exp, -100, 100).astype(jnp.int32)
+    scale = jax.lax.bitcast_convert_type((e + 127) << 23, jnp.float32)  # 2^e
+    q = jnp.round(w * scale)
+    return q, w - q / scale, scale
 
-    # pad rows carry pos == -1 and match no build node (nodes are all >= 0)
-    slot_oh = (pos[:, None] == nodes[None, :]).astype(jnp.float32)  # (R, S)
-    bin_iota = jax.lax.broadcasted_iota(jnp.int32, (R, Ft, n_bins), 2)
-    valid = (bins != MISSING_BIN)[..., None]
-    bin_oh = jnp.where((bins[..., None] == bin_iota) & valid, 1.0, 0.0)
-    bin_oh = bin_oh.reshape(R, Ft * n_bins)
 
-    # one MXU contraction for both gradients: stack g- and h-weighted one-hots
-    # along the slot axis, (R, 2S) @ (R, Ft*B) -> (2S, Ft*B)
-    wm = jnp.concatenate([slot_oh * g[:, None], slot_oh * h[:, None]], axis=1)
-    contract = (((0,), (0,)), ((), ()))  # contract rows
-    hist = jax.lax.dot_general(wm, bin_oh, contract, preferred_element_type=jnp.float32)
-    update = hist.reshape(2, S, Ft, n_bins).transpose(1, 2, 3, 0)  # (S, Ft, B, 2)
+def _fixed_point_join(q_sum: jax.Array, lo_sum: jax.Array, scale: jax.Array) -> jax.Array:
+    """``q_sum / scale + lo_sum`` for ``|q_sum| < 2^30``, rounded about once:
+    the high part of ``q_sum`` converts to f32 exactly, and the low part is
+    small enough that adding it to ``lo_sum`` first costs no precision."""
+    q_hi = (q_sum >> 6) << 6
+    q_lo = (q_sum - q_hi).astype(jnp.float32)
+    return q_hi.astype(jnp.float32) / scale + (q_lo / scale + lo_sum)
 
-    @pl.when(r_step == 0)
+
+def _hist_kernel(nodes_ref, bins_ref, w_ref, pos_ref, q_out, lo_out, *, n_bins: int):
+    """One (feature tile, row tile) step: ``out += W @ onehot(bins)^T``, for
+    the integer parts of g and h (exact, in int32) and for the remainders."""
+    bins = bins_ref[...]  # (Ft, R) int32; missing and padding are -1
+    pos = pos_ref[...]  # (1, R) int32 global node ids; padding is -1
+    nodes = nodes_ref[...]  # (2S, 1) int32: the build set, twice
+    w = w_ref[...]  # (4, R) f32: q_g, q_h, lo_g, lo_h
+    two_s = nodes.shape[0]
+    ft, r = bins.shape
+
+    # rows of W: the g-weighted slot one-hot, then the h-weighted one; pad
+    # rows carry pos -1 and match no build node (ids are all >= 0)
+    hit = nodes == pos  # (2S, R)
+    grad_rows = jax.lax.broadcasted_iota(jnp.int32, (two_s, 1), 0) < two_s // 2
+    wq = jnp.where(hit, jnp.where(grad_rows, w[0:1], w[1:2]), 0.0)
+    wlo = jnp.where(hit, jnp.where(grad_rows, w[2:3], w[3:4]), 0.0)
+
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        q_out[...] = jnp.zeros_like(q_out)
+        lo_out[...] = jnp.zeros_like(lo_out)
 
-    acc_ref[...] += update
-
-    @pl.when(r_step == pl.num_programs(1) - 1)
-    def _flush():
-        out_ref[...] = acc_ref[...]
-
-
-def _pad_to(x: jax.Array, size: int, axis: int, fill) -> jax.Array:
-    pad = size - x.shape[axis]
-    if pad <= 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths, constant_values=fill)
+    bin_iota = jax.lax.broadcasted_iota(jnp.int32, (n_bins, r), 0)
+    contract = (((1,), (1,)), ((), ()))  # rows
+    for f in range(ft):
+        onehot = (bins[f : f + 1, :] == bin_iota).astype(jnp.float32)  # (B, R)
+        # HIGHEST keeps f32 operands unrounded on the MXU; the integer parts
+        # of one row tile sum below 2^24, so their f32 sum is exact
+        part_q = jax.lax.dot_general(
+            wq, onehot, contract, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+        part_lo = jax.lax.dot_general(
+            wlo, onehot, contract, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+        cols = slice(f * n_bins, (f + 1) * n_bins)
+        q_out[:, cols] += part_q.astype(jnp.int32)
+        lo_out[:, cols] += part_lo
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("n_nodes", "n_bins", "row_tile", "feat_tile", "interpret"),
+    jax.jit, static_argnames=("n_bins", "row_tile", "feat_tile", "interpret")
 )
-def build_histogram(
+def build_histogram_slab(
     bins: jax.Array,  # (n_rows, m) int32 (uint8 ok; cast below)
     g: jax.Array,
     h: jax.Array,
-    positions: jax.Array,
-    n_nodes: int,
+    positions: jax.Array,  # (n_rows,) int32 GLOBAL node ids; < 0 = inactive
+    build_nodes: jax.Array,  # (S,) int32 global build-node ids, all >= 0
     n_bins: int,
-    node_map: jax.Array | None = None,  # (level_nodes,) int32 -> build slot or -1
     *,
-    row_tile: int = 256,
+    row_tile: int = 1024,
     feat_tile: int = 8,
     interpret: bool | None = None,
 ) -> jax.Array:
-    # node_map (histogram subtraction): compact positions to build slots so the
-    # one-hot node contraction and the VMEM out block cover only n_nodes build
-    # nodes; rows at derive nodes drop to -1 and match no one-hot column.
+    """The kernel's result: a ``(2S, m_p * B_p)`` f32 slab.
+
+    Row ``s`` holds the gradient sums of ``build_nodes[s]`` and row ``S + s``
+    its hessian sums; column ``f * B_p + b`` is feature ``f``, bin ``b``.
+    ``m_p`` is ``m`` rounded up to ``feat_tile`` and ``B_p`` is ``n_bins``
+    rounded up to 128; the padding columns are zero. Each bin is its exact
+    sum rounded to f32 (to within an ulp), whatever the order of the rows.
+    """
     interpret = resolve_interpret(interpret)
-    if node_map is not None:
-        positions = apply_node_map(positions, node_map)
     n_rows, m = bins.shape
-    r_pad = -n_rows % row_tile
-    f_pad = -m % feat_tile
-    n_rows_p, m_p = n_rows + r_pad, m + f_pad
+    s = build_nodes.shape[0]
+    b_p = round_up(n_bins, LANES)
+    rt = min(row_tile, round_up(max(n_rows, 1), LANES))
+    n_rows_p, m_p = round_up(max(n_rows, 1), rt), round_up(m, feat_tile)
 
-    bins_p = _pad_to(_pad_to(bins.astype(jnp.int32), n_rows_p, 0, MISSING_BIN), m_p, 1, MISSING_BIN)
-    g_p = _pad_to(g.astype(jnp.float32), n_rows_p, 0, 0.0)
-    h_p = _pad_to(h.astype(jnp.float32), n_rows_p, 0, 0.0)
-    pos_p = _pad_to(positions.astype(jnp.int32), n_rows_p, 0, -1)
-
-    grid = (m_p // feat_tile, n_rows_p // row_tile)
-    out = pl.pallas_call(
-        functools.partial(_hist_kernel, n_nodes=n_nodes, n_bins=n_bins),
-        grid=grid,
+    # feature-major bins; missing values and padding become -1, which
+    # matches no bin, so the kernel needs no validity mask
+    bins_i = bins.astype(jnp.int32)
+    bins_t = jnp.pad(
+        jnp.where(bins_i == MISSING_BIN, -1, bins_i).T,
+        ((0, m_p - m), (0, n_rows_p - n_rows)),
+        constant_values=-1,
+    )
+    q_bits = 24 - (rt - 1).bit_length()  # a row tile's integer parts stay exact
+    qg, lo_g, scale_g = _fixed_point_split(g.astype(jnp.float32), n_rows, q_bits)
+    qh, lo_h, scale_h = _fixed_point_split(h.astype(jnp.float32), n_rows, q_bits)
+    w = jnp.pad(jnp.stack([qg, qh, lo_g, lo_h]), ((0, 0), (0, n_rows_p - n_rows)))
+    pos = jnp.pad(
+        positions.astype(jnp.int32), (0, n_rows_p - n_rows), constant_values=-1
+    )[None, :]
+    nodes = jnp.concatenate([build_nodes, build_nodes]).astype(jnp.int32)[:, None]
+    slab = jax.ShapeDtypeStruct((2 * s, m_p * b_p), jnp.float32)
+    block = pl.BlockSpec((2 * s, feat_tile * b_p), lambda f, r: (0, f))
+    q_sum, lo_sum = pl.pallas_call(
+        functools.partial(_hist_kernel, n_bins=b_p),
+        grid=(m_p // feat_tile, n_rows_p // rt),
         in_specs=[
-            pl.BlockSpec((row_tile, feat_tile), lambda f, r: (r, f)),
-            pl.BlockSpec((row_tile,), lambda f, r: (r,)),
-            pl.BlockSpec((row_tile,), lambda f, r: (r,)),
-            pl.BlockSpec((row_tile,), lambda f, r: (r,)),
+            pl.BlockSpec((2 * s, 1), lambda f, r: (0, 0)),
+            pl.BlockSpec((feat_tile, rt), lambda f, r: (f, r)),
+            pl.BlockSpec((4, rt), lambda f, r: (0, r)),
+            pl.BlockSpec((1, rt), lambda f, r: (0, r)),
         ],
-        out_specs=pl.BlockSpec(
-            (n_nodes, feat_tile, n_bins, 2), lambda f, r: (0, f, 0, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((n_nodes, m_p, n_bins, 2), jnp.float32),
+        out_specs=[block, block],
+        out_shape=[jax.ShapeDtypeStruct(slab.shape, jnp.int32), slab],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(bins_p, g_p, h_p, pos_p)
-    return out[:, :m]
+    )(nodes, bins_t, w, pos)
+    scale = jnp.repeat(jnp.stack([scale_g, scale_h]), s)[:, None]
+    return _fixed_point_join(q_sum, lo_sum, scale)
+
+
+def slab_to_nodes(slab: jax.Array, n_build: int, m: int, n_bins: int) -> jax.Array:
+    """``(2S, m_p * B_p)`` slab -> ``(S, m, n_bins, 2)`` node histograms."""
+    m_p = slab.shape[1] // round_up(n_bins, LANES)
+    hist = slab.reshape(2, n_build, m_p, -1)[:, :, :m, :n_bins]
+    return hist.transpose(1, 2, 3, 0)
 
 
 @functools.partial(
@@ -184,7 +196,7 @@ def build_histogram_nodes(
     build_nodes: jax.Array,  # (n_build,) int32 global build-node ids, all >= 0
     n_bins: int,
     *,
-    row_tile: int = 256,
+    row_tile: int = 1024,
     feat_tile: int = 8,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -193,42 +205,49 @@ def build_histogram_nodes(
     ``out[s]`` is the (m, n_bins, 2) gradient histogram of global node
     ``build_nodes[s]``. Rows whose position is not in ``build_nodes`` — frozen
     leaves, derive-set siblings, rows at other heap nodes — contribute to no
-    bin; the window masking and node_map compaction the two-launch path did
-    on the host side happen inside the kernel (a broadcast compare against
-    the node-id vector), so one launch replaces lookup + scatter.
+    bin; the window masking and node_map compaction happen inside the kernel
+    (a broadcast compare against the node-id vector), so one launch replaces
+    lookup + scatter.
     """
-    interpret = resolve_interpret(interpret)
-    n_rows, m = bins.shape
-    n_build = build_nodes.shape[0]
-    r_pad = -n_rows % row_tile
-    f_pad = -m % feat_tile
-    n_rows_p, m_p = n_rows + r_pad, m + f_pad
+    parts = []
+    for i in range(0, build_nodes.shape[0], _MAX_SLOTS):
+        nodes = build_nodes[i : i + _MAX_SLOTS]
+        slab = build_histogram_slab(
+            bins, g, h, positions, nodes, n_bins,
+            row_tile=row_tile, feat_tile=feat_tile, interpret=interpret,
+        )
+        parts.append(slab_to_nodes(slab, nodes.shape[0], bins.shape[1], n_bins))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
-    bins_p = _pad_to(_pad_to(bins.astype(jnp.int32), n_rows_p, 0, MISSING_BIN), m_p, 1, MISSING_BIN)
-    g_p = _pad_to(g.astype(jnp.float32), n_rows_p, 0, 0.0)
-    h_p = _pad_to(h.astype(jnp.float32), n_rows_p, 0, 0.0)
-    pos_p = _pad_to(positions.astype(jnp.int32), n_rows_p, 0, -1)
-    nodes = build_nodes.astype(jnp.int32)
 
-    grid = (m_p // feat_tile, n_rows_p // row_tile)
-    out = pl.pallas_call(
-        functools.partial(_fused_hist_kernel, n_bins=n_bins),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n_build,), lambda f, r: (0,)),
-            pl.BlockSpec((row_tile, feat_tile), lambda f, r: (r, f)),
-            pl.BlockSpec((row_tile,), lambda f, r: (r,)),
-            pl.BlockSpec((row_tile,), lambda f, r: (r,)),
-            pl.BlockSpec((row_tile,), lambda f, r: (r,)),
-        ],
-        out_specs=pl.BlockSpec(
-            (n_build, feat_tile, n_bins, 2), lambda f, r: (0, f, 0, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((n_build, m_p, n_bins, 2), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((n_build, feat_tile, n_bins, 2), jnp.float32)],
-        interpret=interpret,
-    )(nodes, bins_p, g_p, h_p, pos_p)
-    return out[:, :m]
+@functools.partial(
+    jax.jit,
+    static_argnames=("n_nodes", "n_bins", "row_tile", "feat_tile", "interpret"),
+)
+def build_histogram(
+    bins: jax.Array,  # (n_rows, m) int32 (uint8 ok; cast below)
+    g: jax.Array,
+    h: jax.Array,
+    positions: jax.Array,  # (n_rows,) int32 level-local node ids; < 0 = inactive
+    n_nodes: int,
+    n_bins: int,
+    node_map: jax.Array | None = None,  # (level_nodes,) int32 -> build slot or -1
+    *,
+    row_tile: int = 1024,
+    feat_tile: int = 8,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Level-local histogram of ``n_nodes`` slots on the fused kernel.
+
+    With ``node_map`` (histogram subtraction) positions are first compacted
+    to build slots; rows at derive nodes drop to -1 and match no slot.
+    """
+    if node_map is not None:
+        positions = apply_node_map(positions, node_map)
+    return build_histogram_nodes(
+        bins, g, h, positions, jnp.arange(n_nodes, dtype=jnp.int32), n_bins,
+        row_tile=row_tile, feat_tile=feat_tile, interpret=interpret,
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("n_bins",))
@@ -265,56 +284,57 @@ def build_histogram_nodes_host(
     one BLAS dot. Without it, rows are processed in fixed ``row_chunk``
     blocks under `lax.scan`, bounding the one-hot working set to
     ``row_chunk * m * n_bins`` floats. Both paths are deterministic
-    call-to-call, but their f32 accumulation groupings differ — a builder
-    must pick one path for a whole fit (they already sum pages/chunks in
-    path-specific order, same as the paged-vs-in-core split)."""
+    call-to-call. Like the kernel, they split g and h into exactly summed
+    integer parts and small remainders, so each bin is its exact sum
+    rounded about once, and both paths agree with the kernel and the oracle
+    bit for bit on nearly every bin."""
     n_rows, m = bins.shape
     s = build_nodes.shape[0]
     nodes = build_nodes.astype(jnp.int32)
+    # one dot sums this many rows; their integer parts must stay below 2^24
+    dot_rows = n_rows if bin_oh is not None else row_chunk
+    q_bits = 24 - max(dot_rows - 1, 1).bit_length()
+    qg, lo_g, scale_g = _fixed_point_split(g.astype(jnp.float32), n_rows, q_bits)
+    qh, lo_h, scale_h = _fixed_point_split(h.astype(jnp.float32), n_rows, q_bits)
+    cols = jnp.stack([qg, qh, lo_g, lo_h], axis=1)  # (n_rows, 4)
+
+    def contract(pos, cols, oh):
+        """(4S, F*B): integer parts of g, h, then remainders of g, h."""
+        slot_oh = (pos[:, None] == nodes[None, :]).astype(jnp.float32)  # (R, S)
+        wm = (slot_oh[:, None, :] * cols[:, :, None]).reshape(pos.shape[0], 4 * s)
+        hist = jax.lax.dot_general(
+            wm, oh, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        return hist[: 2 * s].astype(jnp.int32), hist[2 * s :]
 
     if bin_oh is not None:
         # precomputed one-hot: one full-height BLAS dot, no chunking (the
         # scan's slice/concat overhead would dominate the S-scaled dot)
-        slot_oh = (positions.astype(jnp.int32)[:, None] == nodes[None, :]).astype(jnp.float32)
-        wm = jnp.concatenate(
-            [slot_oh * g.astype(jnp.float32)[:, None],
-             slot_oh * h.astype(jnp.float32)[:, None]],
-            axis=1,
+        q_sum, lo_sum = contract(positions.astype(jnp.int32), cols, bin_oh)
+    else:
+        pad = -n_rows % row_chunk
+        # pad rows match no node (pos -1 vs non-negative ids) and no bin
+        bins_p = jnp.pad(
+            bins.astype(jnp.int32), ((0, pad), (0, 0)), constant_values=MISSING_BIN
         )
-        acc = jax.lax.dot_general(
-            wm, bin_oh, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        bin_iota = jnp.arange(n_bins, dtype=jnp.int32)
+        oh_p = (bins_p[..., None] == bin_iota).astype(jnp.float32).reshape(
+            n_rows + pad, m * n_bins
         )
-        return acc.reshape(2, s, m, n_bins).transpose(1, 2, 3, 0)
+        pos_p = jnp.pad(positions.astype(jnp.int32), (0, pad), constant_values=-1)
+        n_chunks = (n_rows + pad) // row_chunk
 
-    pad = -n_rows % row_chunk
-    # pad rows match no node (pos -1 vs non-negative ids) and no bin
-    bins_p = jnp.pad(bins.astype(jnp.int32), ((0, pad), (0, 0)), constant_values=MISSING_BIN)
-    bin_iota = jnp.arange(n_bins, dtype=jnp.int32)
-    oh_p = (bins_p[..., None] == bin_iota).astype(jnp.float32).reshape(
-        n_rows + pad, m * n_bins
-    )
-    g_p = jnp.pad(g.astype(jnp.float32), (0, pad))
-    h_p = jnp.pad(h.astype(jnp.float32), (0, pad))
-    pos_p = jnp.pad(positions.astype(jnp.int32), (0, pad), constant_values=-1)
-    n_chunks = (n_rows + pad) // row_chunk
+        def body(acc, xs):
+            q, lo = contract(*xs)
+            return (acc[0] + q, acc[1] + lo), None
 
-    def body(acc, xs):
-        oh_c, g_c, h_c, pos_c = xs
-        slot_oh = (pos_c[:, None] == nodes[None, :]).astype(jnp.float32)  # (R, S)
-        wm = jnp.concatenate([slot_oh * g_c[:, None], slot_oh * h_c[:, None]], axis=1)
-        hist = jax.lax.dot_general(
-            wm,
-            oh_c,
-            (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (2S, F*B)
-        return acc + hist, None
-
-    xs = (
-        oh_p.reshape(n_chunks, row_chunk, m * n_bins),
-        g_p.reshape(n_chunks, row_chunk),
-        h_p.reshape(n_chunks, row_chunk),
-        pos_p.reshape(n_chunks, row_chunk),
-    )
-    acc, _ = jax.lax.scan(body, jnp.zeros((2 * s, m * n_bins), jnp.float32), xs)
+        xs = (
+            pos_p.reshape(n_chunks, row_chunk),
+            jnp.pad(cols, ((0, pad), (0, 0))).reshape(n_chunks, row_chunk, 4),
+            oh_p.reshape(n_chunks, row_chunk, m * n_bins),
+        )
+        zeros = jnp.zeros((2 * s, m * n_bins), jnp.int32)
+        (q_sum, lo_sum), _ = jax.lax.scan(body, (zeros, zeros.astype(jnp.float32)), xs)
+    scale = jnp.repeat(jnp.stack([scale_g, scale_h]), s)[:, None]
+    acc = _fixed_point_join(q_sum, lo_sum, scale)
     return acc.reshape(2, s, m, n_bins).transpose(1, 2, 3, 0)

@@ -8,7 +8,6 @@ Every op takes ``impl`` in {"auto", "pallas", "ref"}:
 """
 from __future__ import annotations
 
-import os
 from typing import Iterable, Mapping
 
 import jax
@@ -23,11 +22,8 @@ from repro.kernels._backend import on_tpu as _on_tpu
 
 MISSING_BIN = _ref.MISSING_BIN
 
-_FORCE = os.environ.get("REPRO_KERNEL_IMPL", "")  # optional global override
-
 
 def _resolve(impl: str) -> str:
-    impl = _FORCE or impl
     if impl == "auto":
         return "pallas" if _on_tpu() else "ref"
     if impl not in ("pallas", "ref"):
@@ -74,7 +70,7 @@ def build_histogram_nodes(
     oracle paths ignore it."""
     if _resolve(impl) == "pallas":
         return _histogram.build_histogram_nodes(bins, g, h, positions, build_nodes, n_bins)
-    if (_FORCE or impl) == "auto":
+    if impl == "auto":
         # off-TPU fast path: jnp mirror of the kernel's one-hot contraction.
         # Its cost scales with the build-set size, so subtraction pays off-TPU
         # too; the scatter oracle's cost is row-dominated and mode-independent.
@@ -94,7 +90,7 @@ def prepare_bin_onehot(bins, n_bins: int, impl: str = "auto", cap_bytes: int = 2
     chunks; each is deterministic, but their f32 groupings differ in final
     ulps — use one consistently per fit (the in-core builder decides once
     per tree, before the level loop)."""
-    if _resolve(impl) == "pallas" or (_FORCE or impl) != "auto":
+    if _resolve(impl) == "pallas" or impl != "auto":
         return None
     if bins.shape[0] * bins.shape[1] * n_bins * 4 > cap_bytes:
         return None

@@ -3,11 +3,13 @@
 CUDA implementations radix-partition row indices with warp ballots; on TPU we
 keep an explicit per-row position array (complete-tree node ids) and update it
 vectorially. Per-node attribute gathers (split feature/bin, default direction,
-leaf flag) and the per-row "value of my split feature" gather are both
-expressed as one-hot contractions, which lower to MXU/VPU ops instead of
-serialized dynamic gathers.
+leaf flag) are one one-hot MXU contraction, and the per-row "value of my split
+feature" gather is a masked sublane sum, instead of serialized dynamic
+gathers. Rows lie along lanes: bins are read feature-major, ``(m, n_rows)``,
+and positions as a ``(1, n_rows)`` row.
 
-new_pos = 2*pos + 1 + go_right;   retired rows (leaf or pos<0) -> -1.
+new_pos = 2*pos + 1 + go_right; rows at a leaf keep their position and
+retired rows (pos < 0) stay -1.
 """
 from __future__ import annotations
 
@@ -17,46 +19,65 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels._backend import resolve_interpret
+from repro.kernels._backend import LANES, resolve_interpret, round_up
 
 MISSING_BIN = 255
 
 
-def _partition_kernel(
-    bins_ref, pos_ref, feature_ref, sbin_ref, dleft_ref, leaf_ref, out_ref, *, n_nodes: int
-):
-    bins = bins_ref[...]  # (R, m) int32
-    pos = pos_ref[...]  # (R,) int32
-    feature = feature_ref[...]  # (N,) int32
-    sbin = sbin_ref[...]  # (N,) int32
-    dleft = dleft_ref[...]  # (N,) int32 (0/1)
-    leaf = leaf_ref[...]  # (N,) int32 (0/1)
-    R, m = bins.shape
+def node_attr_rows(*attrs: jax.Array) -> jax.Array:
+    """Stack per-node attributes into an ``(8, N_p)`` f32 gather table.
 
-    node_iota = jax.lax.broadcasted_iota(jnp.int32, (R, n_nodes), 1)
-    node_oh = (pos[:, None] == node_iota).astype(jnp.float32)  # (R, N)
+    Row ``k`` is ``attrs[k]`` (small ints and 0/1 flags, exact in f32), the
+    remaining rows are zero, and ``N_p`` is the node count rounded up to 128.
+    Padding nodes are never reached: positions only move to real children.
+    """
+    n = attrs[0].shape[-1]
+    rows = [a.astype(jnp.float32) for a in attrs]
+    table = jnp.stack(rows + [jnp.zeros_like(rows[0])] * (8 - len(rows)), axis=-2)
+    pad = [(0, 0)] * (table.ndim - 1) + [(0, round_up(n, LANES) - n)]
+    return jnp.pad(table, pad)
 
-    def gather_node(attr):
-        return jax.lax.dot_general(
-            node_oh, attr.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
 
-    f_idx = gather_node(feature).astype(jnp.int32)  # (R,)
-    s_val = gather_node(sbin).astype(jnp.int32)
-    d_val = gather_node(dleft) > 0.5
-    l_val = gather_node(leaf) > 0.5
+def gather_nodes(table: jax.Array, pos: jax.Array) -> jax.Array:
+    """``table[:, pos]`` as a one-hot MXU contraction: (8, N_p) x (1, R) -> (8, R).
 
-    feat_iota = jax.lax.broadcasted_iota(jnp.int32, (R, m), 1)
-    f_oh = (f_idx[:, None] == feat_iota).astype(jnp.float32)  # (R, m)
-    bval = jnp.sum(f_oh * bins.astype(jnp.float32), axis=1).astype(jnp.int32)
+    HIGHEST precision keeps the f32 operand unrounded, so ids up to 2^24
+    come back exact; a position matching no node (-1) gathers zeros.
+    """
+    onehot = jax.lax.broadcasted_iota(jnp.int32, (table.shape[1], pos.shape[1]), 0) == pos
+    return jax.lax.dot_general(
+        table, onehot.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
 
-    active = pos >= 0
+
+def route(bins: jax.Array, pos: jax.Array, attrs: jax.Array) -> jax.Array:
+    """One descent step for a (1, R) row of positions over feature-major bins.
+
+    ``attrs`` is ``gather_nodes`` output: feature, split_bin, default_left,
+    is_leaf in rows 0-3. Rows at a leaf keep their position; others move to
+    ``2p+1`` (left) or ``2p+2`` (right). Booleans combine with ``&``/``|``:
+    Mosaic cannot select between two boolean vectors.
+    """
+    f_idx = attrs[0:1].astype(jnp.int32)
+    s_bin = attrs[1:2].astype(jnp.int32)
+    d_left = attrs[2:3] > 0.5
+    leaf = attrs[3:4] > 0.5
+    feat_iota = jax.lax.broadcasted_iota(jnp.int32, bins.shape, 0)
+    bval = jnp.sum(jnp.where(feat_iota == f_idx, bins, 0), axis=0, keepdims=True)
     missing = bval == MISSING_BIN
-    go_left = jnp.where(missing, d_val, bval <= s_val)
-    child = 2 * pos + 1 + jnp.where(go_left, 0, 1)
-    # rows at a leaf keep their position; inactive (padded) rows stay -1
-    out_ref[...] = jnp.where(active, jnp.where(l_val, pos, child), -1).astype(jnp.int32)
+    go_left = (missing & d_left) | (~missing & (bval <= s_bin))
+    child = 2 * pos + 2 - go_left.astype(jnp.int32)
+    return jnp.where(leaf, pos, child)
+
+
+def _partition_kernel(bins_ref, pos_ref, attrs_ref, out_ref):
+    bins = bins_ref[...]  # (m, R) int32
+    pos = pos_ref[...]  # (1, R) int32
+    new_pos = route(bins, pos, gather_nodes(attrs_ref[...], pos))
+    # inactive (retired or padded) rows stay -1
+    out_ref[...] = jnp.where(pos >= 0, new_pos, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("row_tile", "interpret"))
@@ -68,37 +89,33 @@ def partition_rows(
     default_left: jax.Array,  # (n_nodes,) bool
     is_leaf: jax.Array,  # (n_nodes,) bool
     *,
-    row_tile: int = 256,
+    row_tile: int = 512,
     interpret: bool | None = None,
 ) -> jax.Array:
     interpret = resolve_interpret(interpret)
     n_rows, m = bins.shape
-    n_nodes = feature.shape[0]
-    r_pad = -n_rows % row_tile
-    bins_p = jnp.pad(bins.astype(jnp.int32), ((0, r_pad), (0, 0)), constant_values=MISSING_BIN)
-    pos_p = jnp.pad(positions.astype(jnp.int32), (0, r_pad), constant_values=-1)
-
-    grid = ((n_rows + r_pad) // row_tile,)
-    out = pl.pallas_call(
-        functools.partial(_partition_kernel, n_nodes=n_nodes),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((row_tile, m), lambda r: (r, 0)),
-            pl.BlockSpec((row_tile,), lambda r: (r,)),
-            pl.BlockSpec((n_nodes,), lambda r: (0,)),
-            pl.BlockSpec((n_nodes,), lambda r: (0,)),
-            pl.BlockSpec((n_nodes,), lambda r: (0,)),
-            pl.BlockSpec((n_nodes,), lambda r: (0,)),
-        ],
-        out_specs=pl.BlockSpec((row_tile,), lambda r: (r,)),
-        out_shape=jax.ShapeDtypeStruct((n_rows + r_pad,), jnp.int32),
-        interpret=interpret,
-    )(
-        bins_p,
-        pos_p,
-        feature.astype(jnp.int32),
-        split_bin.astype(jnp.int32),
-        default_left.astype(jnp.int32),
-        is_leaf.astype(jnp.int32),
+    rt = min(row_tile, round_up(max(n_rows, 1), LANES))
+    n_rows_p = round_up(max(n_rows, 1), rt)
+    # feature-major so a (features, rows) block is sublanes x lanes
+    bins_t = jnp.pad(
+        bins.astype(jnp.int32).T, ((0, 0), (0, n_rows_p - n_rows)),
+        constant_values=MISSING_BIN,
     )
-    return out[:n_rows]
+    pos_p = jnp.pad(
+        positions.astype(jnp.int32), (0, n_rows_p - n_rows), constant_values=-1
+    )[None, :]
+    attrs = node_attr_rows(feature, split_bin, default_left, is_leaf)
+
+    out = pl.pallas_call(
+        _partition_kernel,
+        grid=(n_rows_p // rt,),
+        in_specs=[
+            pl.BlockSpec((m, rt), lambda r: (0, r)),
+            pl.BlockSpec((1, rt), lambda r: (0, r)),
+            pl.BlockSpec(attrs.shape, lambda r: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, rt), lambda r: (0, r)),
+        out_shape=jax.ShapeDtypeStruct((1, n_rows_p), jnp.int32),
+        interpret=interpret,
+    )(bins_t, pos_p, attrs)
+    return out[0, :n_rows]

@@ -5,7 +5,7 @@ Covers the PR's three moving parts end to end:
     the jnp oracle) agree across ragged shapes, non-contiguous build sets,
     MISSING bins, and inactive rows — and the fused path reproduces the old
     window-mask + node_map two-launch result bit-for-bit on the oracle.
-  - `_pad_to` regression: tile-padding rows/features contribute to NO
+  - tile-padding regression: padding rows/features contribute to NO
     (node, bin) cell for non-multiple-of-tile shapes.
   - batched lossguide pops (`TreeParams.pop_batch`): several frontier leaves
     share one partition pass and one histogram launch, and the grown tree is
@@ -141,9 +141,9 @@ def test_fused_oracle_equals_windowed_node_map_path_bitwise():
 
 @pytest.mark.parametrize("n,m", [(1, 1), (255, 3), (257, 9), (300, 17)])
 def test_tile_padding_contributes_to_no_bin(n, m):
-    """Regression for `_pad_to` fills: with shapes that are NOT multiples of
+    """Regression for tile-padding fills: with shapes that are NOT multiples of
     the (row, feature) tiles, the kernel pads rows and features. Pad rows
-    carry pos=-1 (matches no build node) and bin=MISSING (matches no bin
+    carry pos=-1 (matches no build node) and bin -1 (matches no bin
     column), so a build node with zero real rows must come out exactly zero —
     any fill leak lands in (slot 0, bin 0) and breaks this."""
     n_bins = 8
@@ -162,7 +162,7 @@ def test_tile_padding_contributes_to_no_bin(n, m):
     want = np.asarray(ref.build_histogram_nodes(bins, g, h, pos, nodes, n_bins))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
 
-    # the windowed kernel path pads through the same `_pad_to` helper
+    # the windowed path runs the same kernel behind a node_map remap
     from repro.kernels.histogram import build_histogram as windowed_pl
 
     got_w = np.asarray(windowed_pl(bins, g, h, pos, 2, n_bins, interpret=True))

@@ -127,3 +127,33 @@ def test_leaf_weight_formula():
 
     w = leaf_weight(jnp.asarray([6.0]), jnp.asarray([2.0]), reg_lambda=1.0)
     assert np.isclose(float(w[0]), -2.0)  # -6 / (2 + 1)
+
+
+@pytest.mark.parametrize("grow_policy", ["depthwise", "lossguide"])
+def test_leaf_weights_come_from_the_rows_at_each_leaf(grow_policy):
+    """A leaf's weight is -G/(H+lambda) over the rows that end at it, to f32
+    rounding. The split search's running sums (node - cumsum of bins) carry
+    an ulp of the ancestors' larger sums into every leaf below them, which
+    is far coarser for a small leaf."""
+    rng = np.random.default_rng(3)
+    n = 8192
+    X = rng.normal(size=(n, 5)).astype(np.float32)
+    prob = 1 / (1 + np.exp(-rng.normal(0, 0.5, n) - X[:, 0]))
+    y = rng.random(n) < prob
+    g = (prob - y).astype(np.float32)  # logistic gradients of a noisy model
+    h = (prob * (1 - prob)).astype(np.float32)
+    ell = create_ellpack_inmemory(X, max_bin=64)
+    bins = jnp.asarray(ell.single_page().bins.astype(np.int32))
+    bv = bin_valid_from_cuts(ell.cuts, 64)
+    tp = TreeParams(max_depth=6, grow_policy=grow_policy)
+    res = grow_tree(bins, jnp.asarray(g), jnp.asarray(h), 64, bv, tp,
+                    ell.cuts.values, ell.cuts.ptrs)
+    pos = np.asarray(res.positions)
+    n_total = tp.n_total_nodes
+    G = np.bincount(pos, g.astype(np.float64), n_total)
+    H = np.bincount(pos, h.astype(np.float64), n_total)
+    reached = np.bincount(pos, minlength=n_total) > 0
+    got = np.asarray(res.tree.leaf_value, np.float64)
+    np.testing.assert_allclose(got[reached], -G[reached] / (H[reached] + 1.0),
+                               rtol=3e-7, atol=0)
+    assert not got[~reached].any()
