@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import objectives as obj_lib
 from repro.core.histcache import HistogramStore
 from repro.core.policy import ExecutionDecision, ExecutionPolicy, sampling_requested
@@ -41,6 +42,7 @@ from repro.core.tree import (
     stack_trees,
 )
 from repro.data.pages import TransferStats, fsync_dir
+from repro.tracing import span
 
 Array = jax.Array
 
@@ -294,15 +296,16 @@ class GradientBooster:
 
         p = self.params
         self._packed_forest = None  # forest is about to change
-        dm = as_dmatrix(data, y, max_bin=p.max_bin, cuts=cuts)
-        decision = self.policy.decide(dm, p)
-        self.decision_ = decision
-        self.cuts = dm.cuts
-        if decision.mode == "in_core":
-            return self._fit_in_core(dm, eval_set, eval_metric, verbose, start_iteration)
-        return self._fit_external(
-            dm, decision, eval_set, eval_metric, verbose, start_iteration
-        )
+        with span(tracing.FIT):
+            dm = as_dmatrix(data, y, max_bin=p.max_bin, cuts=cuts)
+            decision = self.policy.decide(dm, p)
+            self.decision_ = decision
+            self.cuts = dm.cuts
+            if decision.mode == "in_core":
+                return self._fit_in_core(dm, eval_set, eval_metric, verbose, start_iteration)
+            return self._fit_external(
+                dm, decision, eval_set, eval_metric, verbose, start_iteration
+            )
 
     # ------------------------------------------------------- in-core engine
     def _fit_in_core(
@@ -314,89 +317,96 @@ class GradientBooster:
                 f"start_iteration={start_iteration} but the booster holds "
                 f"{len(self.trees)} trees; resume with start_iteration == len(trees)"
             )
-        if start_iteration == 0:
-            # fresh ledger: stats cover exactly this fit() call; in-core fits
-            # get their own TransferStats so histogram spill/fetch traffic is
-            # still observable (self.stats)
-            self.stats = TransferStats()
-            self.hist_cache = self._make_hist_store(self.stats)
-        else:
-            # resumed boosting keeps the store (and its accumulated ledger)
-            # but must not record into a detached private sink
-            if self.stats is None:
+        with span(tracing.PREPARE):
+            if start_iteration == 0:
+                # fresh ledger: stats cover exactly this fit() call; in-core fits
+                # get their own TransferStats so histogram spill/fetch traffic is
+                # still observable (self.stats)
                 self.stats = TransferStats()
-            self.hist_cache.transfer_stats = self.stats
-        labels = dm.require_labels()
-        n_bins = dm.n_bins
-        bin_valid = bin_valid_from_cuts(dm.cuts, n_bins)
-        bins = self._stage_in_core(dm.single_page_bins())
-        labels_j = jnp.asarray(labels)
+                self.hist_cache = self._make_hist_store(self.stats)
+            else:
+                # resumed boosting keeps the store (and its accumulated ledger)
+                # but must not record into a detached private sink
+                if self.stats is None:
+                    self.stats = TransferStats()
+                self.hist_cache.transfer_stats = self.stats
+            labels = dm.require_labels()
+            n_bins = dm.n_bins
+            bin_valid = bin_valid_from_cuts(dm.cuts, n_bins)
+            bins = self._stage_in_core(dm.single_page_bins())
+            labels_j = jnp.asarray(labels)
 
-        if start_iteration == 0:
-            self.base_margin_ = (
-                p.base_score if p.base_score is not None else self.objective.base_margin(labels)
-            )
-        margin = jnp.full(labels.shape[0], self.base_margin_, jnp.float32)
-        for tree in self.trees:  # resumed run: replay the restored forest
-            margin = margin + p.learning_rate * predict_tree_bins(tree, bins, p.max_depth)
-
-        eval_bins = eval_labels = None
-        eval_margin = None
-        if eval_set is not None:
-            from repro.core.ellpack import bin_batch
-
-            eval_bins = jnp.asarray(bin_batch(eval_set[0], dm.cuts).astype(np.int32))
-            eval_labels = np.asarray(eval_set[1], dtype=np.float32)
-            eval_margin = jnp.full(eval_labels.shape[0], self.base_margin_, jnp.float32)
-            for tree in self.trees:
-                eval_margin = eval_margin + p.learning_rate * predict_tree_bins(
-                    tree, eval_bins, p.max_depth
+            if start_iteration == 0:
+                self.base_margin_ = (
+                    p.base_score if p.base_score is not None else self.objective.base_margin(labels)
                 )
-        metric_name = self._metric_name(eval_metric)
+            margin = jnp.full(labels.shape[0], self.base_margin_, jnp.float32)
+            for tree in self.trees:  # resumed run: replay the restored forest
+                margin = margin + p.learning_rate * predict_tree_bins(tree, bins, p.max_depth)
+
+            eval_bins = eval_labels = None
+            eval_margin = None
+            if eval_set is not None:
+                from repro.core.ellpack import bin_batch
+
+                eval_bins = jnp.asarray(bin_batch(eval_set[0], dm.cuts).astype(np.int32))
+                eval_labels = np.asarray(eval_set[1], dtype=np.float32)
+                eval_margin = jnp.full(eval_labels.shape[0], self.base_margin_, jnp.float32)
+                for tree in self.trees:
+                    eval_margin = eval_margin + p.learning_rate * predict_tree_bins(
+                        tree, eval_bins, p.max_depth
+                    )
+            metric_name = self._metric_name(eval_metric)
 
         tp = p.tree_params()
         t0 = time.perf_counter()
         best_metric, best_iter = None, -1
         for it in range(start_iteration, p.n_estimators):
-            g, h = self.objective.grad_hess(margin, labels_j)
-            self._rng, k = jax.random.split(self._rng)
-            mask, w = sample(k, g, h, p.sampling)
-            scale = jnp.where(mask, w, 0.0)
-            res = grow_tree(
-                bins,
-                g * scale,
-                h * scale,
-                n_bins,
-                bin_valid,
-                tp,
-                cut_values=dm.cuts.values,
-                cut_ptrs=dm.cuts.ptrs,
-                impl=p.kernel_impl,
-                hist_cache=self.hist_cache,
+            with span(tracing.ROUND, round=it):
+                with span(tracing.GRAD, round=it):
+                    g, h = self.objective.grad_hess(margin, labels_j)
+                    self._rng, k = jax.random.split(self._rng)
+                    mask, w = sample(k, g, h, p.sampling)
+                    scale = jnp.where(mask, w, 0.0)
+                with span(tracing.GROW, round=it):
+                    res = grow_tree(
+                        bins,
+                        g * scale,
+                        h * scale,
+                        n_bins,
+                        bin_valid,
+                        tp,
+                        cut_values=dm.cuts.values,
+                        cut_ptrs=dm.cuts.ptrs,
+                        impl=p.kernel_impl,
+                        hist_cache=self.hist_cache,
+                    )
+                self.trees.append(res.tree)
+                with span(tracing.MARGINS, round=it):
+                    margin = margin + p.learning_rate * res.tree.leaf_value[res.positions]
+                if eval_bins is None:
+                    continue
+                with span(tracing.EVAL, round=it):
+                    pred = predict_tree_bins(res.tree, eval_bins, tp.max_depth)
+                    eval_margin = eval_margin + p.learning_rate * pred
+                    val = self._eval(metric_name, eval_labels, eval_margin)
+                    self.eval_history.append(
+                        EvalRecord(it, metric_name, val, time.perf_counter() - t0)
+                    )
+            if verbose:
+                print(f"[{it}] {metric_name}={val:.6f}")
+            better = (
+                best_metric is None
+                or (metric_name in ("auc", "accuracy") and val > best_metric)
+                or (metric_name not in ("auc", "accuracy") and val < best_metric)
             )
-            self.trees.append(res.tree)
-            margin = margin + p.learning_rate * res.tree.leaf_value[res.positions]
-            if eval_bins is not None:
-                pred = predict_tree_bins(res.tree, eval_bins, tp.max_depth)
-                eval_margin = eval_margin + p.learning_rate * pred
-                val = self._eval(metric_name, eval_labels, eval_margin)
-                self.eval_history.append(
-                    EvalRecord(it, metric_name, val, time.perf_counter() - t0)
-                )
-                if verbose:
-                    print(f"[{it}] {metric_name}={val:.6f}")
-                better = (
-                    best_metric is None
-                    or (metric_name in ("auc", "accuracy") and val > best_metric)
-                    or (metric_name not in ("auc", "accuracy") and val < best_metric)
-                )
-                if better:
-                    best_metric, best_iter = val, it
-                elif (
-                    p.early_stopping_rounds
-                    and it - best_iter >= p.early_stopping_rounds
-                ):
-                    break
+            if better:
+                best_metric, best_iter = val, it
+            elif (
+                p.early_stopping_rounds
+                and it - best_iter >= p.early_stopping_rounds
+            ):
+                break
         self.best_iteration_ = best_iter if best_iter >= 0 else len(self.trees) - 1
         return self
 
@@ -441,38 +451,40 @@ class GradientBooster:
         from repro.pipeline import DevicePageCache
 
         p, pol = self.params, self.policy
-        labels = dm.require_labels()
-        pages = dm.page_set()
-        self.pages = pages
-        self.stats = pages.stats
-        # fresh ledger unless resuming mid-boosting (keep the run's totals);
-        # histogram spills/fetches land in the page set's TransferStats so one
-        # ledger carries all device-boundary traffic — resumed stores are
-        # rewired to it (their __init__ sink is a detached placeholder)
-        if start_iteration == 0:
-            self.hist_cache = self._make_hist_store(pages.stats)
-        else:
-            self.hist_cache.transfer_stats = pages.stats
-        self.labels_ = labels
-        n_bins = dm.n_bins
-        bin_valid = bin_valid_from_cuts(dm.cuts, n_bins)
-        labels_j = jnp.asarray(labels)
+        with span(tracing.PREPARE):
+            labels = dm.require_labels()
+            pages = dm.page_set()
+            self.pages = pages
+            self.stats = pages.stats
+            # fresh ledger unless resuming mid-boosting (keep the run's totals);
+            # histogram spills/fetches land in the page set's TransferStats so one
+            # ledger carries all device-boundary traffic — resumed stores are
+            # rewired to it (their __init__ sink is a detached placeholder)
+            if start_iteration == 0:
+                self.hist_cache = self._make_hist_store(pages.stats)
+            else:
+                self.hist_cache.transfer_stats = pages.stats
+            self.labels_ = labels
+            n_bins = dm.n_bins
+            bin_valid = bin_valid_from_cuts(dm.cuts, n_bins)
+            labels_j = jnp.asarray(labels)
 
-        if self.margins_ is None:
-            self.base_margin_ = (
-                p.base_score if p.base_score is not None else self.objective.base_margin(labels)
-            )
-            self.margins_ = np.full(pages.n_rows, self.base_margin_, np.float32)
+            if self.margins_ is None:
+                self.base_margin_ = (
+                    p.base_score if p.base_score is not None else self.objective.base_margin(labels)
+                )
+                self.margins_ = np.full(pages.n_rows, self.base_margin_, np.float32)
 
-        eval_bins = eval_labels = eval_margin = None
-        if eval_set is not None:
-            eval_bins = jnp.asarray(bin_batch(eval_set[0], dm.cuts).astype(np.int32))
-            eval_labels = np.asarray(eval_set[1], np.float32)
-            eval_margin = jnp.full(eval_labels.shape[0], self.base_margin_, jnp.float32)
-            md = p.max_depth
-            for t in self.trees:  # resumed run: rebuild eval margins
-                eval_margin = eval_margin + p.learning_rate * predict_tree_bins(t, eval_bins, md)
-        metric_name = self._metric_name(eval_metric)
+            eval_bins = eval_labels = eval_margin = None
+            if eval_set is not None:
+                eval_bins = jnp.asarray(bin_batch(eval_set[0], dm.cuts).astype(np.int32))
+                eval_labels = np.asarray(eval_set[1], np.float32)
+                eval_margin = jnp.full(eval_labels.shape[0], self.base_margin_, jnp.float32)
+                md = p.max_depth
+                for t in self.trees:  # resumed run: rebuild eval margins
+                    pred = predict_tree_bins(t, eval_bins, md)
+                    eval_margin = eval_margin + p.learning_rate * pred
+            metric_name = self._metric_name(eval_metric)
 
         tp = p.tree_params()
         use_sampling = decision.mode == "sampled"
@@ -492,25 +504,30 @@ class GradientBooster:
         self._device_cache = DevicePageCache(cache_pages) if cache_pages > 0 else None
         t0 = time.perf_counter()
         for it in range(start_iteration, p.n_estimators):
-            g, h = self.objective.grad_hess(jnp.asarray(self.margins_), labels_j)
-            self._rng, k = jax.random.split(self._rng)
-            if use_sampling:
-                res = self._build_tree_sampled(
-                    k, g, h, n_bins, bin_valid, tp, dm.cuts, sampling_cfg
-                )
-            else:
-                res = self._build_tree_streaming(g, h, n_bins, bin_valid, tp, dm.cuts)
-            self.trees.append(res.tree)
-            self._update_margins(res, tp)
-            if eval_bins is not None:
-                pred = predict_tree_bins(res.tree, eval_bins, tp.max_depth)
-                eval_margin = eval_margin + p.learning_rate * pred
-                val = self._eval(metric_name, eval_labels, eval_margin)
-                self.eval_history.append(
-                    EvalRecord(it, metric_name, val, time.perf_counter() - t0)
-                )
-                if verbose:
-                    print(f"[{it}] {metric_name}={val:.6f}")
+            with span(tracing.ROUND, round=it):
+                with span(tracing.GRAD, round=it):
+                    g, h = self.objective.grad_hess(jnp.asarray(self.margins_), labels_j)
+                    self._rng, k = jax.random.split(self._rng)
+                with span(tracing.GROW, round=it):
+                    if use_sampling:
+                        res = self._build_tree_sampled(
+                            k, g, h, n_bins, bin_valid, tp, dm.cuts, sampling_cfg
+                        )
+                    else:
+                        res = self._build_tree_streaming(g, h, n_bins, bin_valid, tp, dm.cuts)
+                self.trees.append(res.tree)
+                with span(tracing.MARGINS, round=it):
+                    self._update_margins(res, tp)
+                if eval_bins is not None:
+                    with span(tracing.EVAL, round=it):
+                        pred = predict_tree_bins(res.tree, eval_bins, tp.max_depth)
+                        eval_margin = eval_margin + p.learning_rate * pred
+                        val = self._eval(metric_name, eval_labels, eval_margin)
+                        self.eval_history.append(
+                            EvalRecord(it, metric_name, val, time.perf_counter() - t0)
+                        )
+            if verbose and eval_bins is not None:
+                print(f"[{it}] {metric_name}={val:.6f}")
             if (
                 pol.checkpoint_every
                 and pol.checkpoint_dir
@@ -579,18 +596,17 @@ class GradientBooster:
     def _build_tree_streaming(self, g, h, n_bins, bin_valid, tp, cuts) -> TreeBuildResult:
         from repro.core.outofcore import build_tree_paged
 
-        pages = self.pages
-        extents = pages.page_extents
+        extents = self.pages.page_extents
         tree, positions = build_tree_paged(
             self._stream, extents, g, h, n_bins, bin_valid, tp,
             cuts.values, cuts.ptrs, impl=self.params.kernel_impl,
             hist_cache=self.hist_cache, page_skipping=self.policy.page_skipping,
         )
         # final positions point at leaves: margin update without re-streaming
-        pos_full = np.empty(pages.n_rows, np.int32)
-        for i, (ro, nr) in enumerate(extents):
-            pos_full[ro : ro + nr] = np.asarray(positions[i])
-        return TreeBuildResult(tree=tree, positions=jnp.asarray(pos_full))
+        # (pages cover the rows in order; `_update_margins` copies them back)
+        return TreeBuildResult(
+            tree=tree, positions=jnp.concatenate([positions[i] for i in range(len(extents))])
+        )
 
     # -------------------------------------------------------- margin update
     def _update_margins(self, res: TreeBuildResult, tp) -> None:

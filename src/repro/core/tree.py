@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.histcache import (
     HistogramCache,
     LevelPlan,
@@ -64,6 +65,7 @@ from repro.core.histcache import (
 )
 from repro.core.split import LevelSplits, SplitParams, evaluate_splits, leaf_weight
 from repro.kernels import ops
+from repro.tracing import span
 
 Array = jax.Array
 
@@ -246,52 +248,57 @@ def grow_tree_generic(
     node_h = jnp.zeros(n_total, jnp.float32).at[0].set(total_h)
 
     for depth in range(max_depth):
-        offset = 2**depth - 1
-        count = 2**depth
-        plan = cache.plan(count, level_counts)
-        built = hist_fn(offset, count, plan)
-        hist = cache.expand(plan, built)
-        lvl_g = jax.lax.dynamic_slice(node_g, (offset,), (count,))
-        lvl_h = jax.lax.dynamic_slice(node_h, (offset,), (count,))
-        splits: LevelSplits = evaluate_splits(hist, lvl_g, lvl_h, bin_valid, params.split)
+        with span(tracing.LEVEL, depth=depth):
+            offset = 2**depth - 1
+            count = 2**depth
+            with span(tracing.HIST):
+                plan = cache.plan(count, level_counts)
+                built = hist_fn(offset, count, plan)
+                hist = cache.expand(plan, built)
+            with span(tracing.SPLIT):
+                lvl_g = jax.lax.dynamic_slice(node_g, (offset,), (count,))
+                lvl_h = jax.lax.dynamic_slice(node_h, (offset,), (count,))
+                splits: LevelSplits = evaluate_splits(hist, lvl_g, lvl_h, bin_valid, params.split)
 
-        # only nodes that are still growable (parent split) may split
-        growable = (
-            ~jax.lax.dynamic_slice(is_leaf, (offset,), (count,))
-            if depth
-            else jnp.ones(count, bool)
-        )
-        do_split = splits.should_split & growable
+                # only nodes that are still growable (parent split) may split
+                growable = (
+                    ~jax.lax.dynamic_slice(is_leaf, (offset,), (count,))
+                    if depth
+                    else jnp.ones(count, bool)
+                )
+                do_split = splits.should_split & growable
 
-        idx = offset + jnp.arange(count)
-        feature = feature.at[idx].set(jnp.where(do_split, splits.feature, 0))
-        split_bin = split_bin.at[idx].set(jnp.where(do_split, splits.split_bin, 0))
-        default_left = default_left.at[idx].set(splits.default_left & do_split)
-        is_leaf = is_leaf.at[idx].set(~do_split)
+                idx = offset + jnp.arange(count)
+                feature = feature.at[idx].set(jnp.where(do_split, splits.feature, 0))
+                split_bin = split_bin.at[idx].set(jnp.where(do_split, splits.split_bin, 0))
+                default_left = default_left.at[idx].set(splits.default_left & do_split)
+                is_leaf = is_leaf.at[idx].set(~do_split)
 
-        left_idx, right_idx = 2 * idx + 1, 2 * idx + 2
-        node_g = node_g.at[left_idx].set(jnp.where(do_split, splits.left_g, 0.0))
-        node_h = node_h.at[left_idx].set(jnp.where(do_split, splits.left_h, 0.0))
-        node_g = node_g.at[right_idx].set(jnp.where(do_split, splits.right_g, 0.0))
-        node_h = node_h.at[right_idx].set(jnp.where(do_split, splits.right_h, 0.0))
-        # children start growable iff parent split
-        is_leaf = is_leaf.at[left_idx].set(~do_split)
-        is_leaf = is_leaf.at[right_idx].set(~do_split)
+                left_idx, right_idx = 2 * idx + 1, 2 * idx + 2
+                node_g = node_g.at[left_idx].set(jnp.where(do_split, splits.left_g, 0.0))
+                node_h = node_h.at[left_idx].set(jnp.where(do_split, splits.left_h, 0.0))
+                node_g = node_g.at[right_idx].set(jnp.where(do_split, splits.right_g, 0.0))
+                node_h = node_h.at[right_idx].set(jnp.where(do_split, splits.right_h, 0.0))
+                # children start growable iff parent split
+                is_leaf = is_leaf.at[left_idx].set(~do_split)
+                is_leaf = is_leaf.at[right_idx].set(~do_split)
 
-        # counts feed the next level's build/derive plan; skip the bincount
-        # when no histogram follows (last level) or subtraction is off
-        count_level = (
-            (2 ** (depth + 1) - 1, 2 ** (depth + 1))
-            if cache.enabled and depth + 1 < max_depth
-            else None
-        )
-        level_counts = partition_fn(
-            feature, split_bin, default_left, is_leaf, count_level
-        )
+            # counts feed the next level's build/derive plan; skip the bincount
+            # when no histogram follows (last level) or subtraction is off
+            count_level = (
+                (2 ** (depth + 1) - 1, 2 ** (depth + 1))
+                if cache.enabled and depth + 1 < max_depth
+                else None
+            )
+            with span(tracing.PARTITION):
+                level_counts = partition_fn(
+                    feature, split_bin, default_left, is_leaf, count_level
+                )
 
     # the last level's nodes are all leaves
     is_leaf = is_leaf.at[2**max_depth - 1:].set(True)
-    leaf_value = leaf_values(is_leaf, *leaf_sums_fn(), params.split.reg_lambda)
+    with span(tracing.LEAF_SUMS):
+        leaf_value = leaf_values(is_leaf, *leaf_sums_fn(), params.split.reg_lambda)
     split_value = _finalize_split_values(feature, split_bin, is_leaf, cut_values, cut_ptrs)
 
     return TreeArrays(
@@ -410,89 +417,102 @@ def grow_tree_lossguide_generic(
 
     n_leaves = 1
     if eff_depth >= 1 and max_leaves >= 2:
-        root_hist = hist_fn(
-            0, 1,
-            LevelPlan(
-                node_map=None, n_build=1, count=1,
-                build_nodes=jnp.zeros(1, jnp.int32),
-            ),
-        )
-        cache.put_node(0, root_hist[0])
-        push_candidates(0, root_hist, node_g[:1], node_h[:1])
+        with span(tracing.LEVEL, pop=0):
+            with span(tracing.HIST):
+                root_hist = hist_fn(
+                    0, 1,
+                    LevelPlan(
+                        node_map=None, n_build=1, count=1,
+                        build_nodes=jnp.zeros(1, jnp.int32),
+                    ),
+                )
+                cache.put_node(0, root_hist[0])
+            with span(tracing.SPLIT):
+                push_candidates(0, root_hist, node_g[:1], node_h[:1])
 
     pop_batch = max(1, params.pop_batch)
+    pops = 0
     while frontier and n_leaves < max_leaves:
-        # pop up to pop_batch frontier leaves; their splits are written
-        # together so ONE repartition pass moves every popped node's rows and
-        # (when any is expandable) ONE histogram pass covers all their child
-        # windows — out-of-core, that is one PageStream pass per batch
-        # instead of one per pop
-        batch: list[tuple[int, bool]] = []
-        while frontier and len(batch) < pop_batch and n_leaves < max_leaves:
-            _, node, cand = heapq.heappop(frontier)
-            left, right = 2 * node + 1, 2 * node + 2
-            feature = feature.at[node].set(cand.feature)
-            split_bin = split_bin.at[node].set(cand.split_bin)
-            default_left = default_left.at[node].set(cand.default_left)
-            is_leaf = is_leaf.at[node].set(False)
-            node_g = node_g.at[left].set(cand.left_g)
-            node_h = node_h.at[left].set(cand.left_h)
-            node_g = node_g.at[right].set(cand.right_g)
-            node_h = node_h.at[right].set(cand.right_h)
-            n_leaves += 1
-            # children sit at depth(node) + 1 == (node+1).bit_length(); they
-            # can only split if their own children still fit under eff_depth
-            expandable = (node + 1).bit_length() < eff_depth and n_leaves < max_leaves
-            batch.append((node, expandable))
+        pops += 1
+        with span(tracing.LEVEL, pop=pops):
+            # pop up to pop_batch frontier leaves; their splits are written
+            # together so ONE repartition pass moves every popped node's rows and
+            # (when any is expandable) ONE histogram pass covers all their child
+            # windows — out-of-core, that is one PageStream pass per batch
+            # instead of one per pop
+            with span(tracing.SPLIT):
+                batch: list[tuple[int, bool]] = []
+                while frontier and len(batch) < pop_batch and n_leaves < max_leaves:
+                    _, node, cand = heapq.heappop(frontier)
+                    left, right = 2 * node + 1, 2 * node + 2
+                    feature = feature.at[node].set(cand.feature)
+                    split_bin = split_bin.at[node].set(cand.split_bin)
+                    default_left = default_left.at[node].set(cand.default_left)
+                    is_leaf = is_leaf.at[node].set(False)
+                    node_g = node_g.at[left].set(cand.left_g)
+                    node_h = node_h.at[left].set(cand.left_h)
+                    node_g = node_g.at[right].set(cand.right_g)
+                    node_h = node_h.at[right].set(cand.right_h)
+                    n_leaves += 1
+                    # children sit at depth(node) + 1 == (node+1).bit_length(); they
+                    # can only split if their own children still fit under eff_depth
+                    expandable = (node + 1).bit_length() < eff_depth and n_leaves < max_leaves
+                    batch.append((node, expandable))
 
-        # parents sorted ascending: the batch plan's slot order then follows
-        # global node order, deterministically across builders
-        parents = sorted(node for node, expandable in batch if expandable)
-        for node, expandable in batch:
-            if not expandable:
-                cache.discard_node(node)
+                # parents sorted ascending: the batch plan's slot order then follows
+                # global node order, deterministically across builders
+                parents = sorted(node for node, expandable in batch if expandable)
+                for node, expandable in batch:
+                    if not expandable:
+                        cache.discard_node(node)
 
-        # per-node repartition: only the popped nodes' rows move (all other
-        # nodes are leaves, so their rows stay frozen); the child row counts
-        # feed the build/derive choice
-        if parents and cache.enabled:
-            count_window = (
-                (2 * parents[0] + 1, 2)
-                if len(parents) == 1
-                else jnp.asarray(
-                    [2 * p + 1 + c for p in parents for c in (0, 1)], jnp.int32
+            # per-node repartition: only the popped nodes' rows move (all other
+            # nodes are leaves, so their rows stay frozen); the child row counts
+            # feed the build/derive choice
+            if parents and cache.enabled:
+                count_window = (
+                    (2 * parents[0] + 1, 2)
+                    if len(parents) == 1
+                    else jnp.asarray(
+                        [2 * p + 1 + c for p in parents for c in (0, 1)], jnp.int32
+                    )
                 )
-            )
-        else:
-            count_window = None
-        counts = partition_fn(feature, split_bin, default_left, is_leaf, count_window)
+            else:
+                count_window = None
+            with span(tracing.PARTITION):
+                counts = partition_fn(feature, split_bin, default_left, is_leaf, count_window)
 
-        if len(parents) == 1:
-            # single pop: exactly the strictly-best-first per-node path
-            node = parents[0]
-            left = 2 * node + 1
-            plan = cache.plan_node(node, counts)
-            built = hist_fn(left, 2, plan)
-            child_hist = cache.expand_node(node, plan, built)
-            push_candidates(left, child_hist, node_g[left:left + 2], node_h[left:left + 2])
-        elif parents:
-            lo = 2 * parents[0] + 1
-            span = 2 * parents[-1] + 2 - lo + 1
-            plan = cache.plan_nodes(parents, counts)
-            built = hist_fn(lo, span, plan)
-            child_hist = cache.expand_nodes(parents, plan, built)
-            for i, node in enumerate(parents):
+            if len(parents) == 1:
+                # single pop: exactly the strictly-best-first per-node path
+                node = parents[0]
                 left = 2 * node + 1
-                push_candidates(
-                    left, child_hist[2 * i:2 * i + 2],
-                    node_g[left:left + 2], node_h[left:left + 2],
-                )
+                with span(tracing.HIST):
+                    plan = cache.plan_node(node, counts)
+                    built = hist_fn(left, 2, plan)
+                    child_hist = cache.expand_node(node, plan, built)
+                with span(tracing.SPLIT):
+                    push_candidates(left, child_hist, node_g[left:left + 2], node_h[left:left + 2])
+            elif parents:
+                lo = 2 * parents[0] + 1
+                span_nodes = 2 * parents[-1] + 2 - lo + 1
+                with span(tracing.HIST):
+                    plan = cache.plan_nodes(parents, counts)
+                    built = hist_fn(lo, span_nodes, plan)
+                    child_hist = cache.expand_nodes(parents, plan, built)
+                with span(tracing.SPLIT):
+                    for i, node in enumerate(parents):
+                        left = 2 * node + 1
+                        push_candidates(
+                            left, child_hist[2 * i:2 * i + 2],
+                            node_g[left:left + 2], node_h[left:left + 2],
+                        )
 
     # budget exhausted: pending frontier nodes stay leaves
     for _, node, _ in frontier:
         cache.discard_node(node)
 
-    leaf_value = leaf_values(is_leaf, *leaf_sums_fn(), params.split.reg_lambda)
+    with span(tracing.LEAF_SUMS):
+        leaf_value = leaf_values(is_leaf, *leaf_sums_fn(), params.split.reg_lambda)
     split_value = _finalize_split_values(feature, split_bin, is_leaf, cut_values, cut_ptrs)
 
     return TreeArrays(

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.ellpack import (
     DEFAULT_PAGE_BYTES,
     EllpackMatrix,
@@ -44,6 +45,7 @@ from repro.core.ellpack import (
 from repro.core.quantile import HistogramCuts, QuantileSketch
 from repro.data.pages import PageStore, TransferStats
 from repro.pipeline import DevicePageCache, PageStream
+from repro.tracing import span
 
 Array = jax.Array
 
@@ -140,16 +142,17 @@ class PageSet:
         from repro.compress import make_transport
 
         transport = make_transport(codec)
-        arr = _bins_to_host_array(page)
-        t0 = time.perf_counter()
-        if transport is not None:
-            wire, wire_meta = transport.encode(arr)
-            out = transport.decode(_put_bins(wire), wire_meta)
-            wire_nbytes = wire.nbytes
-        else:
-            out = _put_bins(arr)
-            wire_nbytes = arr.nbytes
-        dt = time.perf_counter() - t0
+        with span(tracing.PAGE_STAGE):  # one compacted page, no index
+            arr = _bins_to_host_array(page)
+            t0 = time.perf_counter()
+            if transport is not None:
+                wire, wire_meta = transport.encode(arr)
+                out = transport.decode(_put_bins(wire), wire_meta)
+                wire_nbytes = wire.nbytes
+            else:
+                out = _put_bins(arr)
+                wire_nbytes = arr.nbytes
+            dt = time.perf_counter() - t0
         self.stats.host_to_device_bytes += wire_nbytes
         self.stats.logical_bytes += arr.nbytes
         self.stats.wire_bytes += wire_nbytes

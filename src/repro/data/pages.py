@@ -32,14 +32,15 @@ import json
 import os
 import queue
 import threading
-import time
 import zlib
 from typing import Callable, Iterable, Iterator
 
 import numpy as np
 
+from repro import tracing
 from repro.fault import inject as fault_inject
 from repro.fault.retry import RetryPolicy
+from repro.tracing import span
 
 try:
     import zstandard as _zstd
@@ -54,7 +55,6 @@ class TransferStats:
     host_to_device_bytes: int = 0
     device_to_host_bytes: int = 0
     page_loads: int = 0
-    load_seconds: float = 0.0
     # --- streaming-overlap accounting (filled by repro.pipeline.PageStream) ---
     # fetch/stage/compute are attributed where the work happens (fetch in the
     # prefetcher thread, stage + compute in the consumer thread), so their sum
@@ -130,7 +130,6 @@ class TransferStats:
         self.host_to_device_bytes = 0
         self.device_to_host_bytes = 0
         self.page_loads = 0
-        self.load_seconds = 0.0
         self.stream_fetch_seconds = 0.0
         self.stream_stage_seconds = 0.0
         self.stream_compute_seconds = 0.0
@@ -299,38 +298,37 @@ class PageStore:
         return idx
 
     def read_page(self, idx: int) -> dict[str, np.ndarray]:
-        fault_inject.fire("page_store.read_page", index=idx)
-        t0 = time.perf_counter()
-        with open(self._path(idx), "rb") as fh:
-            blob = fh.read()
-        entry = self._meta["pages"][idx] if idx < len(self._meta["pages"]) else {}
-        want = entry.get("crc32")  # pre-durability manifests have no CRC
-        if want is not None:
-            got = zlib.crc32(blob)
-            if got != want:
-                raise PageCorruptError(idx, self._path(idx), want, got)
-        # decode with the codec the *entry* was written with — legacy
-        # (pre-codec) manifests have no "codec" field and decode as raw, so
-        # old caches reopen bit-for-bit
-        codec_name = entry.get("codec", "raw")
-        try:
-            fault_inject.fire("page_store.decode", index=idx, codec=codec_name)
-            out = _decode(blob)
-            codec_meta = entry.get("codec_meta") or {}
-            if codec_meta:
-                from repro.compress import get_codec
+        with span(tracing.PAGE_FETCH, page=idx):
+            fault_inject.fire("page_store.read_page", index=idx)
+            with open(self._path(idx), "rb") as fh:
+                blob = fh.read()
+            entry = self._meta["pages"][idx] if idx < len(self._meta["pages"]) else {}
+            want = entry.get("crc32")  # pre-durability manifests have no CRC
+            if want is not None:
+                got = zlib.crc32(blob)
+                if got != want:
+                    raise PageCorruptError(idx, self._path(idx), want, got)
+            # decode with the codec the *entry* was written with — legacy
+            # (pre-codec) manifests have no "codec" field and decode as raw, so
+            # old caches reopen bit-for-bit
+            codec_name = entry.get("codec", "raw")
+            try:
+                fault_inject.fire("page_store.decode", index=idx, codec=codec_name)
+                out = _decode(blob)
+                codec_meta = entry.get("codec_meta") or {}
+                if codec_meta:
+                    from repro.compress import get_codec
 
-                codec = get_codec(codec_name)
-                for key, cmeta in codec_meta.items():
-                    out[key] = codec.decode(out[key], cmeta)
-        except PageCorruptError:
-            raise
-        except Exception as err:
-            raise PageDecodeError(idx, self._path(idx), codec_name, err) from err
-        self.stats.disk_read_bytes += len(blob)
-        self.stats.page_loads += 1
-        self.stats.load_seconds += time.perf_counter() - t0
-        return out
+                    codec = get_codec(codec_name)
+                    for key, cmeta in codec_meta.items():
+                        out[key] = codec.decode(out[key], cmeta)
+            except PageCorruptError:
+                raise
+            except Exception as err:
+                raise PageDecodeError(idx, self._path(idx), codec_name, err) from err
+            self.stats.disk_read_bytes += len(blob)
+            self.stats.page_loads += 1
+            return out
 
     def page_meta(self, idx: int) -> dict:
         return self._meta["pages"][idx]
@@ -383,13 +381,12 @@ class Prefetcher:
                 self._queue.put((idx, e))
                 continue
             self._queue.put((idx, page))
-        self._queue.put((-1, None))
 
     def __iter__(self) -> Iterator[tuple[int, dict]]:
-        while True:
-            idx, item = self._queue.get()
-            if idx == -1:
-                return
+        # the worker puts exactly one result per index, in order
+        for idx in self._indices:
+            with span(tracing.PAGE_WAIT, page=idx):
+                _, item = self._queue.get()
             if isinstance(item, PageCorruptError):
                 raise item  # already the actionable error; don't bury it
             if isinstance(item, Exception):

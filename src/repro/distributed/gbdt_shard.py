@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
+from repro import tracing
 from repro.core.histcache import (
     HistogramStore,
     expand_level,
@@ -67,6 +68,7 @@ from repro.core.tree import (
     leaf_values,
 )
 from repro.kernels import ops, ref
+from repro.tracing import span
 
 Array = jax.Array
 
@@ -766,29 +768,35 @@ def fit_sharded(
     row_sharding = NamedSharding(mesh, P(cfg.data_axes))
     t0 = time.perf_counter()
     for it in range(params.n_estimators):
-        g, h = booster.objective.grad_hess(margin, labels_j)
-        booster._rng, k = jax.random.split(booster._rng)
-        mask, w = sample(k, g, h, params.sampling)
-        scale = jnp.where(mask, w, 0.0)
-        tree, positions = grow_tree_distributed(
-            mesh, bins, g * scale, h * scale, n_bins, bin_valid,
-            params.tree_params(), cfg, dm.cuts.values, dm.cuts.ptrs,
-            transfer_stats=booster.stats,
-        )
-        booster.trees.append(tree)
-        # the leaf table is replicated and positions are row-sharded: the
-        # gather keeps the rows' sharding
-        leaf = tree.leaf_value.at[positions].get(out_sharding=row_sharding)
-        margin = margin + params.learning_rate * leaf
-        if eval_bins is not None:
-            pred = predict_tree_bins(tree, eval_bins, tp.max_depth)
-            eval_margin = eval_margin + params.learning_rate * pred
-            val = booster._eval(metric_name, eval_labels, eval_margin)
-            booster.eval_history.append(
-                EvalRecord(it, metric_name, val, time.perf_counter() - t0)
-            )
-            if verbose:
-                print(f"[{it}] {metric_name}={val:.6f}")
+        with span(tracing.ROUND, round=it):
+            with span(tracing.GRAD, round=it):
+                g, h = booster.objective.grad_hess(margin, labels_j)
+                booster._rng, k = jax.random.split(booster._rng)
+                mask, w = sample(k, g, h, params.sampling)
+                scale = jnp.where(mask, w, 0.0)
+            with span(tracing.GROW, round=it):
+                tree, positions = grow_tree_distributed(
+                    mesh, bins, g * scale, h * scale, n_bins, bin_valid,
+                    params.tree_params(), cfg, dm.cuts.values, dm.cuts.ptrs,
+                    transfer_stats=booster.stats,
+                )
+            booster.trees.append(tree)
+            with span(tracing.MARGINS, round=it):
+                # the leaf table is replicated and positions are row-sharded: the
+                # gather keeps the rows' sharding
+                leaf = tree.leaf_value.at[positions].get(out_sharding=row_sharding)
+                margin = margin + params.learning_rate * leaf
+            if eval_bins is None:
+                continue
+            with span(tracing.EVAL, round=it):
+                pred = predict_tree_bins(tree, eval_bins, tp.max_depth)
+                eval_margin = eval_margin + params.learning_rate * pred
+                val = booster._eval(metric_name, eval_labels, eval_margin)
+                booster.eval_history.append(
+                    EvalRecord(it, metric_name, val, time.perf_counter() - t0)
+                )
+        if verbose:
+            print(f"[{it}] {metric_name}={val:.6f}")
     return booster
 
 

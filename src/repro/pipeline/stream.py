@@ -36,9 +36,11 @@ from typing import Any, Callable, Iterable, Iterator, NamedTuple, Sequence
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.data.pages import GLOBAL_STATS, PageStore, Prefetcher, TransferStats
 from repro.fault.retry import RetryPolicy
 from repro.pipeline.cache import DevicePageCache
+from repro.tracing import span
 
 
 class StreamedPage(NamedTuple):
@@ -198,31 +200,32 @@ class PageStream:
 
     # -------------------------------------------------------------- device pass
     def _stage(self, idx: int, host: Any) -> StreamedPage:
-        key = (self.cache_tag, idx)
-        if self.cache is not None:
-            entry = self.cache.lookup(key)
-            if entry is not None:
-                dev, nbytes = entry
-                self.stats.cache_hits += 1
-                self.stats.cache_hit_bytes += nbytes  # host bytes the hit saved
-                return StreamedPage(idx, host, dev)
-            self.stats.cache_misses += 1
-        arr = self._to_array(host)
-        t0 = time.perf_counter()
-        if self.transport is not None:
-            wire, wire_meta = self.transport.encode(arr)
-            dev = self.transport.decode(self._put(wire), wire_meta)
-            wire_nbytes = wire.nbytes
-        else:
-            dev = self._put(arr)
-            wire_nbytes = arr.nbytes
-        self.stats.stream_stage_seconds += time.perf_counter() - t0
-        self.stats.host_to_device_bytes += wire_nbytes
-        self.stats.logical_bytes += arr.nbytes
-        self.stats.wire_bytes += wire_nbytes
-        if self.cache is not None:
-            self.cache.put(key, dev, wire_nbytes, pinned=self.cache_pin)
-        return StreamedPage(idx, host, dev)
+        with span(tracing.PAGE_STAGE, page=idx):
+            key = (self.cache_tag, idx)
+            if self.cache is not None:
+                entry = self.cache.lookup(key)
+                if entry is not None:
+                    dev, nbytes = entry
+                    self.stats.cache_hits += 1
+                    self.stats.cache_hit_bytes += nbytes  # host bytes the hit saved
+                    return StreamedPage(idx, host, dev)
+                self.stats.cache_misses += 1
+            arr = self._to_array(host)
+            t0 = time.perf_counter()
+            if self.transport is not None:
+                wire, wire_meta = self.transport.encode(arr)
+                dev = self.transport.decode(self._put(wire), wire_meta)
+                wire_nbytes = wire.nbytes
+            else:
+                dev = self._put(arr)
+                wire_nbytes = arr.nbytes
+            self.stats.stream_stage_seconds += time.perf_counter() - t0
+            self.stats.host_to_device_bytes += wire_nbytes
+            self.stats.logical_bytes += arr.nbytes
+            self.stats.wire_bytes += wire_nbytes
+            if self.cache is not None:
+                self.cache.put(key, dev, wire_nbytes, pinned=self.cache_pin)
+            return StreamedPage(idx, host, dev)
 
     def __iter__(self) -> Iterator[StreamedPage]:
         stats = self.stats

@@ -56,12 +56,14 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core import objectives as obj_lib
 from repro.core.memory import DeviceMemoryModel
 from repro.data.pages import TransferStats
 from repro.pipeline import DevicePageCache, PageStream
 from repro.serve.batcher import ServeStats
-from repro.serve.forest import PackedForest
+from repro.serve.forest import REQUEST_IDS, PackedForest
+from repro.tracing import span
 
 # pack_page stages 6 f32 planes per node (serve.forest._PAGE_FIELDS)
 _CHUNK_NODE_BYTES = 6 * 4
@@ -505,6 +507,11 @@ class ForestServer:
     # ----------------------------------------------------------- prediction
     def predict_margin(self, data) -> np.ndarray:
         """Margins for raw feature rows (ndarray) or any DMatrix."""
+        request = next(REQUEST_IDS)
+        with span(tracing.REQUEST, request=request):
+            return self._predict(data, request)
+
+    def _predict(self, data, request: int) -> np.ndarray:
         if hasattr(data, "page_set"):  # DMatrix: stream its pages
             extents = data.page_set().page_extents
             worst = max((nr for _, nr in extents), default=0) or 1
@@ -538,7 +545,7 @@ class ForestServer:
             forest, batch_rows, self.model, self.trees_per_chunk
         )
         if chunk is None:
-            return forest.predict_margin(X, impl=self.impl)
+            return forest._predict_raw(X, self.impl, request)
         from repro.core.ellpack import bin_batch
         from repro.kernels import ops
 
@@ -560,18 +567,21 @@ class ForestServer:
                 pin=self.pin_chunks is not False,
             )
             _pin_prologue(forest, chunk, plan.n_pinned, self.stats, transport, cache)
-        bins = jnp.asarray(bin_batch(X, forest.cuts).astype(np.int32))
-        margin = jnp.full(X.shape[0], forest.base_margin, jnp.float32)
-        for fp in _forest_stream(
-            forest, chunk, self.stats, transport=transport, cache=cache
-        ):
-            arrays = _chunk_arrays(fp.device)
-            margin = ops.predict_forest(
-                bins,
-                arrays["feature"], arrays["split_bin"], arrays["default_left"],
-                arrays["is_leaf"], arrays["leaf_value"],
-                forest.max_depth, forest.learning_rate, margin, impl=self.impl,
-            )
+        with span(tracing.BIN, request=request):
+            host_bins = bin_batch(X, forest.cuts).astype(np.int32)
+        with span(tracing.LAUNCH, request=request):
+            bins = jnp.asarray(host_bins)
+            margin = jnp.full(X.shape[0], forest.base_margin, jnp.float32)
+            for fp in _forest_stream(
+                forest, chunk, self.stats, transport=transport, cache=cache
+            ):
+                arrays = _chunk_arrays(fp.device)
+                margin = ops.predict_forest(
+                    bins,
+                    arrays["feature"], arrays["split_bin"], arrays["default_left"],
+                    arrays["is_leaf"], arrays["leaf_value"],
+                    forest.max_depth, forest.learning_rate, margin, impl=self.impl,
+                )
         if self.serve_stats is not None:
             h_post, m_post = (
                 cache.tag_counts("forest") if cache is not None else (0, 0)
@@ -580,7 +590,8 @@ class ForestServer:
                 h_post - h_pre, m_post - m_pre,
                 self.stats.host_to_device_bytes - h2d0,
             )
-        return np.asarray(margin)
+        with span(tracing.FETCH, request=request):
+            return np.asarray(margin)
 
     def predict(self, data, output_margin: bool = False) -> np.ndarray:
         margin = self.predict_margin(data)

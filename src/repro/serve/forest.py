@@ -19,16 +19,22 @@ shape `repro.pipeline.PageStream` stages.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.quantile import HistogramCuts
 from repro.core.tree import TreeArrays
 from repro.kernels import ops
+from repro.tracing import span
 
 Array = jax.Array
+
+# process-wide ids of scoring requests: the ``request`` of their serve.* spans
+REQUEST_IDS = itertools.count()
 
 # pack_page row layout: one f32 plane per tree-array field, in this order
 _PAGE_FIELDS = ("feature", "split_bin", "split_value", "default_left", "is_leaf", "leaf_value")
@@ -115,12 +121,23 @@ class PackedForest:
 
     def predict_margin(self, X: np.ndarray, impl: str = "auto") -> np.ndarray:
         """Raw-feature front door: quantize with the forest's cuts, then fuse."""
+        request = next(REQUEST_IDS)
+        with span(tracing.REQUEST, request=request):
+            return self._predict_raw(X, impl, request)
+
+    def _predict_raw(self, X: np.ndarray, impl: str, request: int) -> np.ndarray:
+        """`predict_margin`'s body, inside a ``serve.request`` span the
+        caller opened for ``request``: host binning, one launch, the copy back."""
         if self.cuts is None:
             raise ValueError("PackedForest has no cuts; predict from bins instead")
         from repro.core.ellpack import bin_batch
 
-        bins = jnp.asarray(bin_batch(np.asarray(X), self.cuts).astype(np.int32))
-        return np.asarray(self.predict_margin_bins(bins, impl=impl))
+        with span(tracing.BIN, request=request):
+            host_bins = bin_batch(np.asarray(X), self.cuts).astype(np.int32)
+        with span(tracing.LAUNCH, request=request):
+            margin = self.predict_margin_bins(jnp.asarray(host_bins), impl=impl)
+        with span(tracing.FETCH, request=request):
+            return np.asarray(margin)
 
     def predict_margin_per_tree(self, bins: Array) -> Array:
         """The per-tree reference loop the fused kernel must match bit-for-bit
