@@ -10,23 +10,39 @@ MXU matmuls, one per feature:
     W[k, r] = [pos_r == node_k] * h_r     for k >= S   (hessian rows)
 
 Each g and h is split exactly into an integer part ``q / scale`` and a small
-remainder (`_fixed_point_split`). The integer parts sum exactly, in f32 on
-the MXU within a row tile and in int32 across tiles, so a bin comes out as
-its exact sum rounded once, whatever the order of the rows, and builders
-that add the rows in another order (pages, shards, the XLA scatter oracle)
-see the same bins and choose the same splits.
+remainder ``lo`` (`_fixed_point_split`). The integer parts sum exactly, in
+f32 on the MXU within a row tile and in int32 across tiles, so a bin comes
+out as its exact sum rounded about once, whatever the order of the rows, and
+builders that add the rows in another order (pages, shards, the XLA scatter
+oracle) see the same bins and choose the same splits.
+
+The MXU multiplies bf16 exactly and sums in f32, so the kernel hands it bf16
+terms that sum to each weight exactly (`_bf16_terms`): two for ``q``
+(``|q| <= 2^16``), three for ``lo``. Per row tile it stacks them as the rows
+of one bf16 ``W``, ten blocks of ``S_b`` slots (the build set rounded up to
+8, so 10 S_b rows, a multiple of 16), and runs ONE product per feature:
+
+    part = W @ onehot(bin_f)^T                  (10 S_b, B), f32 sums
+    q_sum += int32(part[q term 1]) + int32(part[q term 2])
+    lo_sum += part[lo term 1] + (part[lo term 2] + part[lo term 3])
+
+A row tile's integer terms sum below 2^24, exact in f32. That is one MXU pass
+per (feature, row tile); two f32 products at ``Precision.HIGHEST`` cost six
+bf16 passes each, twelve in all, most of them multiplying the one-hot's zero
+low parts.
 
 The kernel reads bins feature-major, ``(m, n_rows)``, so that a (features,
 rows) block is (8, R): sublanes by lanes, as the TPU tiling wants. It writes
-the ``(2S, m*B)`` slabs the contraction produces; `build_histogram_nodes`
-reshapes them to the ``(S, m, B, 2)`` layout every caller reads. The grid
-tiles (features, rows); rows are the innermost (sequential) grid dim, so the
-output blocks stay in VMEM and accumulate across row tiles.
+``(2 S_b, m*B)`` slabs; `build_histogram_slab` drops the padding slots and
+`build_histogram_nodes` reshapes to the ``(S, m, B, 2)`` layout every caller
+reads. The grid tiles (features, rows); rows are the innermost (sequential)
+grid dim, so the output blocks stay in VMEM and accumulate across row tiles.
 
-VMEM per grid step (R=1024, Ft=8, B=256, S=128): bin one-hot (B, R) f32 =
-1 MiB, weights 2 x (2S, R) f32 = 2 MiB, output blocks 2 x (2S, Ft*B) =
-4 MiB, double-buffered: about 20 MiB, over the default scoped limit of
-16 MiB, hence `_VMEM_LIMIT`. Larger build sets run in chunks of `_MAX_SLOTS`.
+VMEM per grid step (R=1024, Ft=8, B=256, S=128): bin one-hot (B, R) bf16 =
+0.5 MiB; W (10S, R), formed in f32 (5 MiB) and cast to bf16 (2.5 MiB); one
+product (10S, B) f32 = 1.25 MiB; output blocks 2 x (2S, Ft*B) = 4 MiB,
+double-buffered: about 17 MiB, over the default scoped limit of 16 MiB, hence
+`_VMEM_LIMIT`. Larger build sets run in chunks of `_MAX_SLOTS`.
 """
 from __future__ import annotations
 
@@ -64,52 +80,124 @@ def _fixed_point_split(
 
 def _fixed_point_join(q_sum: jax.Array, lo_sum: jax.Array, scale: jax.Array) -> jax.Array:
     """``q_sum / scale + lo_sum`` for ``|q_sum| < 2^30``, rounded about once:
-    the high part of ``q_sum`` converts to f32 exactly, and the low part is
-    small enough that adding it to ``lo_sum`` first costs no precision."""
-    q_hi = (q_sum >> 6) << 6
-    q_lo = (q_sum - q_hi).astype(jnp.float32)
-    return q_hi.astype(jnp.float32) / scale + (q_lo / scale + lo_sum)
+    the high part of ``q_sum`` (a multiple of 64, toward zero) converts to
+    f32 exactly, and the low part, of the same sign and below 64, is small
+    enough that adding it to ``lo_sum`` first costs no precision. A sum
+    below 64 is rounded once, and no small negative sum cancels."""
+    q_lo = jax.lax.rem(q_sum, 64)
+    q_hi = q_sum - q_lo
+    return q_hi.astype(jnp.float32) / scale + (q_lo.astype(jnp.float32) / scale + lo_sum)
+
+
+def _bf16_terms(x: jax.Array, n_terms: int) -> list[jax.Array]:
+    """Split f32 ``x`` into ``n_terms`` values that are each exact in bf16
+    (kept as f32) and sum to ``x``: each term is the bf16 rounding of what
+    the terms before it left. Two terms hold any integer ``|x| <= 2^16``
+    exactly, three any normal f32 whose terms stay normal."""
+    terms = []
+    for _ in range(n_terms - 1):
+        t = x.astype(jnp.bfloat16).astype(jnp.float32)
+        terms.append(t)
+        x = x - t
+    return terms + [x.astype(jnp.bfloat16).astype(jnp.float32)]
 
 
 def _hist_kernel(nodes_ref, bins_ref, w_ref, pos_ref, q_out, lo_out, *, n_bins: int):
-    """One (feature tile, row tile) step: ``out += W @ onehot(bins)^T``, for
-    the integer parts of g and h (exact, in int32) and for the remainders."""
+    """One (feature tile, row tile) step: ``out += W @ onehot(bins)^T``, one
+    bf16 product per feature whose row sums are recombined into the exact
+    integer parts of g and h (int32) and the sums of their remainders."""
     bins = bins_ref[...]  # (Ft, R) int32; missing and padding are -1
     pos = pos_ref[...]  # (1, R) int32 global node ids; padding is -1
-    nodes = nodes_ref[...]  # (2S, 1) int32: the build set, twice
+    nodes = nodes_ref[...]  # (S_b, 1) int32: the build set, padded with -1
     w = w_ref[...]  # (4, R) f32: q_g, q_h, lo_g, lo_h
-    two_s = nodes.shape[0]
+    s_b = nodes.shape[0]
     ft, r = bins.shape
 
-    # rows of W: the g-weighted slot one-hot, then the h-weighted one; pad
-    # rows carry pos -1 and match no build node (ids are all >= 0)
-    hit = nodes == pos  # (2S, R)
-    grad_rows = jax.lax.broadcasted_iota(jnp.int32, (two_s, 1), 0) < two_s // 2
-    wq = jnp.where(hit, jnp.where(grad_rows, w[0:1], w[1:2]), 0.0)
-    wlo = jnp.where(hit, jnp.where(grad_rows, w[2:3], w[3:4]), 0.0)
+    # W's rows, in blocks of S_b slots: the two bf16 terms of q_g and of q_h,
+    # then the three of lo_g and of lo_h. Padding rows weigh 0; padding
+    # slots (-1) also match inactive rows, and their sums are dropped.
+    hit = nodes == pos  # (S_b, R)
+    q_terms = [_bf16_terms(w[c : c + 1], 2) for c in (0, 1)]
+    lo_terms = [_bf16_terms(w[c : c + 1], 3) for c in (2, 3)]
+    rows = [t[j] for j in range(2) for t in q_terms]
+    rows += [t[j] for j in range(3) for t in lo_terms]
+    lhs = jnp.concatenate([jnp.where(hit, t, 0.0) for t in rows]).astype(jnp.bfloat16)
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         q_out[...] = jnp.zeros_like(q_out)
         lo_out[...] = jnp.zeros_like(lo_out)
 
+    two = 2 * s_b  # rows of one term: g slots, then h slots
     bin_iota = jax.lax.broadcasted_iota(jnp.int32, (n_bins, r), 0)
     contract = (((1,), (1,)), ((), ()))  # rows
     for f in range(ft):
-        onehot = (bins[f : f + 1, :] == bin_iota).astype(jnp.float32)  # (B, R)
-        # HIGHEST keeps f32 operands unrounded on the MXU; the integer parts
-        # of one row tile sum below 2^24, so their f32 sum is exact
-        part_q = jax.lax.dot_general(
-            wq, onehot, contract, precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32,
-        )
-        part_lo = jax.lax.dot_general(
-            wlo, onehot, contract, precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32,
-        )
+        onehot = (bins[f : f + 1, :] == bin_iota).astype(jnp.bfloat16)  # (B, R)
+        # bf16 operands are exact here and the MXU sums them in f32: each
+        # term of the integer parts sums to an integer below 2^24 over a row
+        # tile, so its f32 sum is exact
+        part = jax.lax.dot_general(
+            lhs, onehot, contract, preferred_element_type=jnp.float32
+        )  # (10 S_b, B)
         cols = slice(f * n_bins, (f + 1) * n_bins)
-        q_out[:, cols] += part_q.astype(jnp.int32)
-        lo_out[:, cols] += part_lo
+        q_out[:, cols] += part[:two].astype(jnp.int32) + part[two : 2 * two].astype(jnp.int32)
+        lo_out[:, cols] += part[2 * two : 3 * two] + (
+            part[3 * two : 4 * two] + part[4 * two :]
+        )
+
+
+def _histogram_sums(
+    bins, g, h, positions, build_nodes, n_bins, row_tile, feat_tile, interpret
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The kernel's sums before the join: ``(q_sum, lo_sum, scale)``, each
+    with ``2 S_b`` rows (``S_b``: the build set rounded up to 8 slots), the
+    gradient slots then the hessian slots. ``q_sum`` is the exact int32 sum
+    of the integer parts, ``lo_sum`` the f32 sum of the remainders."""
+    interpret = resolve_interpret(interpret)
+    n_rows, m = bins.shape
+    s = build_nodes.shape[0]
+    s_b = round_up(s, 8)  # f32 sublanes: W's term blocks stay tile-aligned
+    b_p = round_up(n_bins, LANES)
+    rt = min(row_tile, round_up(max(n_rows, 1), LANES))
+    n_rows_p, m_p = round_up(max(n_rows, 1), rt), round_up(m, feat_tile)
+
+    # feature-major bins; missing values and padding become -1, which
+    # matches no bin, so the kernel needs no validity mask
+    bins_i = bins.astype(jnp.int32)
+    bins_t = jnp.pad(
+        jnp.where(bins_i == MISSING_BIN, -1, bins_i).T,
+        ((0, m_p - m), (0, n_rows_p - n_rows)),
+        constant_values=-1,
+    )
+    # a row tile's integer parts stay exact in f32, and each splits into
+    # two bf16 terms
+    q_bits = min(24 - (rt - 1).bit_length(), 16)
+    qg, lo_g, scale_g = _fixed_point_split(g.astype(jnp.float32), n_rows, q_bits)
+    qh, lo_h, scale_h = _fixed_point_split(h.astype(jnp.float32), n_rows, q_bits)
+    w = jnp.pad(jnp.stack([qg, qh, lo_g, lo_h]), ((0, 0), (0, n_rows_p - n_rows)))
+    pos = jnp.pad(
+        positions.astype(jnp.int32), (0, n_rows_p - n_rows), constant_values=-1
+    )[None, :]
+    # padding slots hold -1 and may gather inactive rows: their sums are dropped
+    nodes = jnp.pad(build_nodes.astype(jnp.int32), (0, s_b - s), constant_values=-1)
+    slab = jax.ShapeDtypeStruct((2 * s_b, m_p * b_p), jnp.float32)
+    block = pl.BlockSpec((2 * s_b, feat_tile * b_p), lambda f, r: (0, f))
+    q_sum, lo_sum = pl.pallas_call(
+        functools.partial(_hist_kernel, n_bins=b_p),
+        grid=(m_p // feat_tile, n_rows_p // rt),
+        in_specs=[
+            pl.BlockSpec((s_b, 1), lambda f, r: (0, 0)),
+            pl.BlockSpec((feat_tile, rt), lambda f, r: (f, r)),
+            pl.BlockSpec((4, rt), lambda f, r: (0, r)),
+            pl.BlockSpec((1, rt), lambda f, r: (0, r)),
+        ],
+        out_specs=[block, block],
+        out_shape=[jax.ShapeDtypeStruct(slab.shape, jnp.int32), slab],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(nodes[:, None], bins_t, w, pos)
+    scale = jnp.repeat(jnp.stack([scale_g, scale_h]), s_b)[:, None]
+    return q_sum, lo_sum, scale
 
 
 @functools.partial(
@@ -135,47 +223,12 @@ def build_histogram_slab(
     rounded up to 128; the padding columns are zero. Each bin is its exact
     sum rounded to f32 (to within an ulp), whatever the order of the rows.
     """
-    interpret = resolve_interpret(interpret)
-    n_rows, m = bins.shape
     s = build_nodes.shape[0]
-    b_p = round_up(n_bins, LANES)
-    rt = min(row_tile, round_up(max(n_rows, 1), LANES))
-    n_rows_p, m_p = round_up(max(n_rows, 1), rt), round_up(m, feat_tile)
-
-    # feature-major bins; missing values and padding become -1, which
-    # matches no bin, so the kernel needs no validity mask
-    bins_i = bins.astype(jnp.int32)
-    bins_t = jnp.pad(
-        jnp.where(bins_i == MISSING_BIN, -1, bins_i).T,
-        ((0, m_p - m), (0, n_rows_p - n_rows)),
-        constant_values=-1,
+    q_sum, lo_sum, scale = _histogram_sums(
+        bins, g, h, positions, build_nodes, n_bins, row_tile, feat_tile, interpret
     )
-    q_bits = 24 - (rt - 1).bit_length()  # a row tile's integer parts stay exact
-    qg, lo_g, scale_g = _fixed_point_split(g.astype(jnp.float32), n_rows, q_bits)
-    qh, lo_h, scale_h = _fixed_point_split(h.astype(jnp.float32), n_rows, q_bits)
-    w = jnp.pad(jnp.stack([qg, qh, lo_g, lo_h]), ((0, 0), (0, n_rows_p - n_rows)))
-    pos = jnp.pad(
-        positions.astype(jnp.int32), (0, n_rows_p - n_rows), constant_values=-1
-    )[None, :]
-    nodes = jnp.concatenate([build_nodes, build_nodes]).astype(jnp.int32)[:, None]
-    slab = jax.ShapeDtypeStruct((2 * s, m_p * b_p), jnp.float32)
-    block = pl.BlockSpec((2 * s, feat_tile * b_p), lambda f, r: (0, f))
-    q_sum, lo_sum = pl.pallas_call(
-        functools.partial(_hist_kernel, n_bins=b_p),
-        grid=(m_p // feat_tile, n_rows_p // rt),
-        in_specs=[
-            pl.BlockSpec((2 * s, 1), lambda f, r: (0, 0)),
-            pl.BlockSpec((feat_tile, rt), lambda f, r: (f, r)),
-            pl.BlockSpec((4, rt), lambda f, r: (0, r)),
-            pl.BlockSpec((1, rt), lambda f, r: (0, r)),
-        ],
-        out_specs=[block, block],
-        out_shape=[jax.ShapeDtypeStruct(slab.shape, jnp.int32), slab],
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret,
-    )(nodes, bins_t, w, pos)
-    scale = jnp.repeat(jnp.stack([scale_g, scale_h]), s)[:, None]
-    return _fixed_point_join(q_sum, lo_sum, scale)
+    slab = _fixed_point_join(q_sum, lo_sum, scale)
+    return slab.reshape(2, -1, slab.shape[1])[:, :s].reshape(2 * s, -1)
 
 
 def slab_to_nodes(slab: jax.Array, n_build: int, m: int, n_bins: int) -> jax.Array:

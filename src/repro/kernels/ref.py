@@ -33,10 +33,11 @@ def scatter_sum(flat: jax.Array, w: jax.Array, size: int, max_terms: int) -> jax
     # the barrier keeps XLA from folding the sums below into this scatter,
     # which would add every small term onto a large running value
     lo_sum = jax.lax.optimization_barrier(jnp.zeros(size, jnp.float32).at[flat].add(lo))
-    # |q_sum| < 2^30: its high part converts to f32 exactly, and its low
-    # part is small enough to join lo_sum first at no cost in precision
-    q_hi = (q_sum >> 6) << 6
-    q_lo = q_sum - q_hi
+    # |q_sum| < 2^30: its high part (a multiple of 64, toward zero) converts
+    # to f32 exactly, and its low part, of the same sign, is small enough to
+    # join lo_sum first at no cost in precision
+    q_lo = jax.lax.rem(q_sum, 64)
+    q_hi = q_sum - q_lo
     return q_hi.astype(jnp.float32) / scale + (q_lo.astype(jnp.float32) / scale + lo_sum)
 
 

@@ -193,3 +193,140 @@ def test_histogram_slab_layout_matches_oracle(m, n_bins, n_build):
     assert not cells[:, :, m:, :].any() and not cells[:, :, :, n_bins:].any()
     got = np.asarray(build_histogram_nodes(bins, g, h, pos, nodes, n_bins, interpret=True))
     np.testing.assert_array_equal(got, cells[:, :, :m, :n_bins].transpose(1, 2, 3, 0))
+
+
+# ------------------------------------------- exact bf16 terms of the kernel
+
+
+def test_bf16_terms_rebuild_every_input():
+    """Two bf16 terms rebuild every integer part (``|q| <= 2^16``, so also
+    ``|q| = 2^k``) and three every remainder: zero, negatives, tiny and huge
+    magnitudes, full 24-bit significands."""
+    from repro.kernels.histogram import _bf16_terms
+
+    rng = np.random.default_rng(0)
+    q = np.arange(-(2**16), 2**16 + 1, dtype=np.float32)
+    mant = np.concatenate([rng.random(4000) + 1, [1.0, 2 - 2.0**-23, 1 + 2.0**-23]])
+    mags = np.ldexp(mant, rng.integers(-100, 101, mant.size))
+    lo = np.concatenate([[0.0], mags, -mags]).astype(np.float32)
+    for x, n_terms in ((q, 2), (lo, 3)):
+        terms = [np.asarray(t) for t in _bf16_terms(jnp.asarray(x), n_terms)]
+        for t in terms:
+            np.testing.assert_array_equal(t, t.astype(jnp.bfloat16).astype(np.float32))
+        np.testing.assert_array_equal(np.sum(np.array(terms, np.float64), axis=0), x)
+
+
+def _adversarial_tiles(n_build, seed, n_bins=16, m=2):
+    """Four row tiles of 1024 rows, each tile's rows all in one bin of one
+    build node. Tile 0 holds the largest |g| and |h| in every row, so each
+    integer part is 2^k and the tile's sum reaches the 2^24 cap; the other
+    tiles span 2^-20 to 2^20, g with both signs."""
+    rng = np.random.default_rng(seed)
+    n = 4 * 1024
+    tile = np.arange(n) // 1024
+    nodes = np.arange(5, 5 + 2 * n_build, 2, dtype=np.int32)  # not contiguous
+    pos = nodes[(3 * tile) % n_build]
+    bins = (tile[:, None] * 5 + np.arange(m)[None, :] * 3) % n_bins
+    top = np.float32((1 - 2.0**-24) * 2.0**20)
+    g = (2.0 ** rng.uniform(-20, 20, n) * rng.choice([-1, 1], n)).astype(np.float32)
+    h = (2.0 ** rng.uniform(-20, 20, n)).astype(np.float32)
+    g[tile == 0], h[tile == 0] = top, top
+    return bins.astype(np.int32), g, h, pos, nodes, n_bins
+
+
+@pytest.mark.parametrize("n_build", [1, 3, 64, 128])
+def test_histogram_integer_parts_exact(n_build):
+    """The kernel's integer-part sums are the exact int64 sums, bit for bit,
+    with 10 S rows that are sometimes not a multiple of 16 (S = 1, 3)."""
+    from repro.kernels.histogram import _fixed_point_split, _histogram_sums
+
+    bins, g, h, pos, nodes, n_bins = _adversarial_tiles(n_build, seed=n_build)
+    q_sum, _, _ = _histogram_sums(
+        *(jnp.asarray(v) for v in (bins, g, h, pos, nodes)), n_bins, 1024, 8, True
+    )
+    q_sum = np.asarray(q_sum)
+    s_b = q_sum.shape[0] // 2
+    slot = np.searchsorted(nodes, pos)
+    for k, w in enumerate((g, h)):
+        q = np.asarray(_fixed_point_split(jnp.asarray(w), w.size, 14)[0]).astype(np.int64)
+        assert np.abs(q).max() == 2**14
+        for f in range(bins.shape[1]):
+            want = np.zeros((n_build, n_bins), np.int64)
+            np.add.at(want, (slot, bins[:, f]), q)
+            got = q_sum[k * s_b : k * s_b + n_build, f * 128 : f * 128 + n_bins]
+            np.testing.assert_array_equal(got, want)
+    assert np.abs(q_sum).max() >= 2**24
+
+
+@pytest.mark.parametrize("n_build", [1, 3, 64, 128])
+def test_histogram_adversarial_bins_within_an_ulp(n_build):
+    """After the join, every bin is within an f32 ulp of the float64 sum of
+    its rows' g or h."""
+    from repro.kernels.histogram import build_histogram_nodes
+
+    bins, g, h, pos, nodes, n_bins = _adversarial_tiles(n_build, seed=n_build)
+    got = np.asarray(build_histogram_nodes(
+        *(jnp.asarray(v) for v in (bins, g, h, pos, nodes)), n_bins, interpret=True
+    ), np.float64)
+    slot = np.searchsorted(nodes, pos)
+    for k, w in enumerate((g, h)):
+        for f in range(bins.shape[1]):
+            want = np.zeros((n_build, n_bins))
+            np.add.at(want, (slot, bins[:, f]), w.astype(np.float64))
+            ulp = np.spacing(np.abs(want).astype(np.float32)).astype(np.float64)
+            assert np.all(np.abs(got[:, f, :, k] - want) <= ulp)
+
+
+def test_fixed_point_join_rounds_small_sums_once():
+    """An integer-part sum below 64, of either sign, joins its remainder sum
+    in one rounding, so a bin of one row is that row's value; larger sums
+    stay within an ulp. The kernel and the scatter oracle join alike."""
+    from repro.kernels.histogram import _fixed_point_join
+
+    rng = np.random.default_rng(3)
+    scale = np.float32(2.0**-6)
+    q = np.concatenate([np.arange(-200, 201), rng.integers(-(2**29), 2**29, 400)])
+    lo = (rng.uniform(-0.5, 0.5, q.size) / scale).astype(np.float32)
+    got = np.asarray(_fixed_point_join(
+        jnp.asarray(q, jnp.int32), jnp.asarray(lo), jnp.float32(scale)
+    ), np.float64)
+    want = q / np.float64(scale) + lo
+    err = np.abs(got - want)
+    small = np.abs(q) < 64
+    np.testing.assert_array_equal(got[small], want[small].astype(np.float32))
+    assert np.all(err <= np.spacing(np.abs(want).astype(np.float32)))
+    w = jnp.asarray(np.float32([-41.83049, 2.0**20]))
+    one_row = ref.scatter_sum(jnp.arange(2), w, 2, 2)
+    np.testing.assert_array_equal(np.asarray(one_row), np.asarray(w))
+
+
+def _kernel_dots(jaxpr, in_kernel=False):
+    """Every ``dot_general`` inside a ``pallas_call`` of ``jaxpr``."""
+    for eqn in jaxpr.eqns:
+        inside = in_kernel or eqn.primitive.name == "pallas_call"
+        if in_kernel and eqn.primitive.name == "dot_general":
+            yield eqn
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)  # a ClosedJaxpr's Jaxpr
+                if hasattr(sub, "eqns"):
+                    yield from _kernel_dots(sub, inside)
+
+
+@pytest.mark.parametrize("n_build", [1, 64])
+def test_histogram_kernel_runs_one_bf16_product_per_feature(n_build):
+    """No product in the kernel takes f32 operands or runs at
+    ``Precision.HIGHEST`` (six bf16 MXU passes each): one bf16 product per
+    feature and row tile."""
+    from repro.kernels.histogram import build_histogram_slab
+
+    bins, g, h, pos = _hist_inputs(2048, 11, 255, n_build, seed=4)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: build_histogram_slab(*a, 255, interpret=False)
+    )(bins, g, h, pos, jnp.arange(n_build, dtype=jnp.int32))
+    dots = list(_kernel_dots(jaxpr.jaxpr))
+    assert len(dots) == 8  # one per feature of a feature tile
+    for eqn in dots:
+        assert [v.aval.dtype for v in eqn.invars] == [jnp.bfloat16] * 2
+        assert jax.lax.Precision.HIGHEST not in (eqn.params["precision"] or ())
+        assert eqn.params["preferred_element_type"] == jnp.float32
