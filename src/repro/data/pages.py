@@ -95,6 +95,12 @@ class TransferStats:
     # that exhausted their attempt budget and surfaced the error
     io_retries: int = 0
     io_giveups: int = 0
+    # --- cross-shard collective ledger (filled by repro.distributed) ---
+    # bytes each shard passes into the distributed tree program's collectives
+    # (histogram AllReduce, row counts, leaf and root sums, split candidates),
+    # counted from the operands' static shapes when the program traces and
+    # added once per tree; with a narrowed grad_transport, the narrowed bytes
+    collective_bytes: int = 0
 
     @property
     def cache_hit_rate(self) -> float:
@@ -146,6 +152,7 @@ class TransferStats:
         self.wire_bytes = 0
         self.io_retries = 0
         self.io_giveups = 0
+        self.collective_bytes = 0
 
 
 GLOBAL_STATS = TransferStats()

@@ -28,6 +28,15 @@ Distributed-optimization tricks:
 Everything here is shard_map-first: `make_gbdt_step_fn` returns a jit-able
 function over a Mesh, used both for real execution and the multi-pod dry-run.
 
+Compile once: `grow_tree_distributed` keeps one compiled depthwise tree
+program per (mesh, resolved TreeParams, DistConfig, n_bins, input shapes and
+dtypes), so every tree of a `fit_sharded` fit, and every later fit with the
+same arguments, runs the same executable. The bytes each shard passes into
+that program's collectives (histogram psums, row counts, leaf and root sums,
+feature-parallel candidates and routing; the narrowed bytes under a bf16 or
+f16 ``grad_transport``) are counted from the operands' static shapes when it
+traces, and `TransferStats.collective_bytes` gains them once per tree.
+
 Out-of-core + distributed (`grow_tree_distributed_paged`): ELLPACK pages
 stream through `repro.pipeline.PageStream` with a *sharded* device put, so
 each staged page lands row-sharded over the data axes and the per-page
@@ -168,9 +177,28 @@ def check_feature_parallel_lossguide(tp: TreeParams, cfg: DistConfig) -> None:
         )
 
 
-def _psum_hist(hist: Array, cfg: DistConfig) -> Array:
+class _Collectives:
+    """The cross-shard collectives of one traced program, and the bytes each
+    shard passes into them, summed from the operands' static shapes."""
+
+    def __init__(self) -> None:
+        self.nbytes = 0
+
+    def _count(self, x) -> None:
+        self.nbytes += sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(x))
+
+    def psum(self, x, axes):
+        self._count(x)
+        return jax.lax.psum(x, axes)
+
+    def all_gather(self, x, axis):
+        self._count(x)
+        return jax.lax.all_gather(x, axis)
+
+
+def _psum_hist(hist: Array, cfg: DistConfig, psum=jax.lax.psum) -> Array:
     q = cfg.grad_quantizer
-    out = jax.lax.psum(q.psum_cast(hist), cfg.data_axes)
+    out = psum(q.psum_cast(hist), cfg.data_axes)
     return q.psum_restore(out)
 
 
@@ -180,7 +208,7 @@ def _feature_shard_info(cfg: DistConfig):
     return cfg.feature_axis
 
 
-def _global_best(splits, local_m: int, cfg: DistConfig):
+def _global_best(splits, local_m: int, cfg: DistConfig, coll: _Collectives):
     """All-gather per-shard best candidates over the feature axis and arg-max.
 
     Returns per-node global (gain, feature, bin, default_left, child sums).
@@ -200,7 +228,7 @@ def _global_best(splits, local_m: int, cfg: DistConfig):
         ],
         axis=0,
     )  # (8, n_nodes)
-    allc = jax.lax.all_gather(cand, ax)  # (n_shards, 8, n_nodes)
+    allc = coll.all_gather(cand, ax)  # (n_shards, 8, n_nodes)
     best_shard = jnp.argmax(allc[:, 0, :], axis=0)  # (n_nodes,)
     picked = jnp.take_along_axis(allc, best_shard[None, None, :], axis=0)[0]
     return picked  # (8, n_nodes)
@@ -216,8 +244,11 @@ def _grow_tree_local(
     cfg: DistConfig,
     cut_values: Array | None,  # (total_cuts,) for raw thresholds (global)
     cut_ptrs: Array | None,
+    coll: _Collectives,
 ) -> tuple[TreeArrays, Array]:
-    """The shard-local body run under shard_map. Returns (tree, positions)."""
+    """The shard-local body run under shard_map. Returns (tree, positions).
+    Every cross-shard collective goes through ``coll``, which counts its
+    bytes."""
     n_total = tp.n_total_nodes
     max_depth = tp.max_depth
     local_rows, local_m = bins.shape
@@ -226,8 +257,8 @@ def _grow_tree_local(
     split_bin = jnp.zeros(n_total, jnp.int32)
     default_left = jnp.zeros(n_total, bool)
     is_leaf = jnp.ones(n_total, bool)
-    total_g = jax.lax.psum(jnp.sum(g), cfg.data_axes)
-    total_h = jax.lax.psum(jnp.sum(h), cfg.data_axes)
+    total_g = coll.psum(jnp.sum(g), cfg.data_axes)
+    total_h = coll.psum(jnp.sum(h), cfg.data_axes)
     node_g = jnp.zeros(n_total, jnp.float32).at[0].set(total_g)
     node_h = jnp.zeros(n_total, jnp.float32).at[0].set(total_h)
     positions = jnp.zeros(local_rows, jnp.int32)
@@ -252,13 +283,13 @@ def _grow_tree_local(
                 bins, g, h, level_pos, count // 2, n_bins,
                 node_map=node_map, impl=cfg.kernel_impl,
             )
-            built = _psum_hist(built_local, cfg)  # the paper's AllReduce, halved
+            built = _psum_hist(built_local, cfg, coll.psum)  # the paper's AllReduce, halved
             hist = expand_level(prev_hist, built, build_left)
         else:
             hist_local = ops.build_histogram(
                 bins, g, h, level_pos, count, n_bins, impl=cfg.kernel_impl
             )
-            hist = _psum_hist(hist_local, cfg)  # the paper's AllReduce
+            hist = _psum_hist(hist_local, cfg, coll.psum)  # the paper's AllReduce
         prev_hist = hist
 
         lvl_g = jax.lax.dynamic_slice(node_g, (offset,), (count,))
@@ -266,7 +297,7 @@ def _grow_tree_local(
         splits = evaluate_splits(hist, lvl_g, lvl_h, bin_valid, tp.split)
 
         if cfg.feature_axis is not None:
-            picked = _global_best(splits, local_m, cfg)
+            picked = _global_best(splits, local_m, cfg, coll)
             s_gain = picked[0]
             s_feature = picked[1].astype(jnp.int32)
             s_bin = picked[2].astype(jnp.int32)
@@ -317,7 +348,7 @@ def _grow_tree_local(
             bval = jnp.take_along_axis(bins, jnp.clip(lf, 0, local_m - 1)[:, None], axis=1)[:, 0]
             missing = bval == ref.MISSING_BIN
             go_left_local = jnp.where(missing, default_left[safe], bval <= split_bin[safe])
-            go_left = jax.lax.psum(
+            go_left = coll.psum(
                 jnp.where(owner, go_left_local.astype(jnp.int32), 0), cfg.feature_axis
             ) > 0
             child = 2 * positions + 1 + jnp.where(go_left, 0, 1)
@@ -330,14 +361,14 @@ def _grow_tree_local(
         # (identical on every shard, and to the single-device builder's)
         if cfg.hist_subtraction and tp.hist_subtraction and depth + 1 < max_depth:
             noff, ncnt = 2 ** (depth + 1) - 1, 2 ** (depth + 1)
-            level_counts = jax.lax.psum(
+            level_counts = coll.psum(
                 level_row_counts(positions, noff, ncnt), cfg.data_axes
             )
 
     # the last level's nodes are all leaves; their weights come from the
     # rows that end there, summed over the data shards
     is_leaf = is_leaf.at[2**max_depth - 1:].set(True)
-    sums = jax.lax.psum(node_grad_sums(positions, g, h, n_total), cfg.data_axes)
+    sums = coll.psum(node_grad_sums(positions, g, h, n_total), cfg.data_axes)
     leaf_value = leaf_values(is_leaf, *sums, tp.split.reg_lambda)
 
     if cut_values is not None and cut_ptrs is not None:
@@ -517,7 +548,7 @@ def make_gbdt_step_fn(
             scale = jnp.where(mask, w, 0.0)
             g, h = g * scale, h * scale
         tree, positions = _grow_tree_local(
-            bins, g, h, n_bins, bin_valid, tp, cfg, cut_values, cut_ptrs
+            bins, g, h, n_bins, bin_valid, tp, cfg, cut_values, cut_ptrs, _Collectives()
         )
         new_margin = margin + learning_rate * tree.leaf_value[positions]
         return new_margin, tree
@@ -530,6 +561,46 @@ def make_gbdt_step_fn(
         out_specs=(vec_spec, rep),
     )
     return jax.jit(shard_fn)
+
+
+@dataclasses.dataclass
+class _TreeProgram:
+    """One compiled depthwise SPMD tree program and the bytes each shard
+    passes into its collectives per call (set when it first traces)."""
+
+    fn: Callable
+    collective_bytes: int = 0
+
+
+@functools.lru_cache(maxsize=32)
+def _tree_program(
+    mesh: Mesh, tp: TreeParams, cfg: DistConfig, n_bins: int, avals: tuple
+) -> _TreeProgram:
+    """The tree program for one (mesh, TreeParams, DistConfig, n_bins, input
+    shapes and dtypes): built once and reused by every later call with the
+    same arguments, so a fit compiles it once and later fits load nothing."""
+    del avals  # part of the cache key only: one program, one shape
+    row_spec = P(cfg.data_axes, cfg.feature_axis)
+    vec_spec = P(cfg.data_axes)
+    rep = P()
+    program = _TreeProgram(fn=None)
+
+    def body(bins, g, h, bin_valid, cut_values, cut_ptrs):
+        coll = _Collectives()
+        out = _grow_tree_local(
+            bins, g, h, n_bins, bin_valid, tp, cfg, cut_values, cut_ptrs, coll
+        )
+        program.collective_bytes = coll.nbytes
+        return out
+
+    bv_spec = P(cfg.feature_axis) if cfg.feature_axis else rep
+    program.fn = jax.jit(_shard_map(
+        body,
+        mesh=mesh,
+        in_specs=(row_spec, vec_spec, vec_spec, bv_spec, rep, rep),
+        out_specs=(rep, vec_spec),
+    ))
+    return program
 
 
 def grow_tree_distributed(
@@ -547,10 +618,19 @@ def grow_tree_distributed(
 ):
     """Build one tree with rows/features sharded over the mesh.
 
-    ``transfer_stats`` is the `TransferStats` sink for the host-driven
+    The depthwise build is one SPMD program (`_grow_tree_local` under
+    shard_map), compiled once per (mesh, resolved TreeParams, DistConfig,
+    n_bins, input shapes and dtypes) and reused by every later tree with the
+    same arguments. Each call adds the bytes each shard passes into that
+    program's collectives (histogram psums, row counts, leaf and root sums,
+    feature-parallel candidates and routing) to
+    ``transfer_stats.collective_bytes``; they are counted from the operands'
+    static shapes when the program traces, so counting syncs nothing.
+
+    ``transfer_stats`` is also the `TransferStats` sink for the host-driven
     lossguide build's histogram spill/fetch traffic (see
-    ``DistConfig.hist_budget_bytes``); the in-SPMD depthwise build never
-    spills, so it ignores the sink.
+    ``DistConfig.hist_budget_bytes``); its per-pop collectives are not
+    counted.
     """
     tp = cfg.resolve_tree_params(tp)
     check_feature_parallel_lossguide(tp, cfg)
@@ -559,23 +639,15 @@ def grow_tree_distributed(
             mesh, bins, g, h, n_bins, bin_valid, tp, cfg, cut_values, cut_ptrs,
             transfer_stats=transfer_stats,
         )
-    row_spec = P(cfg.data_axes, cfg.feature_axis)
-    vec_spec = P(cfg.data_axes)
-    rep = P()
     cut_values = jnp.zeros(1, jnp.float32) if cut_values is None else jnp.asarray(cut_values)
     cut_ptrs = jnp.zeros(1, jnp.int32) if cut_ptrs is None else jnp.asarray(cut_ptrs)
-
-    def body(bins, g, h, bin_valid, cut_values, cut_ptrs):
-        return _grow_tree_local(bins, g, h, n_bins, bin_valid, tp, cfg, cut_values, cut_ptrs)
-
-    bv_spec = P(cfg.feature_axis) if cfg.feature_axis else rep
-    fn = _shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(row_spec, vec_spec, vec_spec, bv_spec, rep, rep),
-        out_specs=(rep, vec_spec),
-    )
-    return jax.jit(fn)(bins, g, h, bin_valid, cut_values, cut_ptrs)
+    args = (bins, g, h, bin_valid, cut_values, cut_ptrs)
+    avals = tuple((a.shape, str(a.dtype)) for a in args)
+    program = _tree_program(mesh, tp, cfg, n_bins, avals)
+    out = program.fn(*args)
+    if transfer_stats is not None:
+        transfer_stats.collective_bytes += program.collective_bytes
+    return out
 
 
 def sharded_page_put(mesh: Mesh, cfg: DistConfig) -> Callable[[np.ndarray], Array]:
@@ -656,6 +728,12 @@ def grow_tree_distributed_paged(
     return tree, pos_full
 
 
+def _held_by(device, x: Array) -> Array:
+    """The copy of replicated ``x`` that ``device`` holds, as an array of that
+    device alone (a `jax.device_put` would keep the mesh in its type)."""
+    return next(s.data for s in x.addressable_shards if s.device == device)
+
+
 def fit_sharded(
     mesh: Mesh,
     data,
@@ -682,9 +760,14 @@ def fit_sharded(
     get_params all work).
 
     The quantized matrix is staged once, row-sharded over ``cfg.data_axes``
-    (features over ``cfg.feature_axis`` when set); each boosting round builds
-    one tree via `grow_tree_distributed` (histogram psum = the paper's §2.2
-    AllReduce) and updates the replicated margin from the sharded positions.
+    (features over ``cfg.feature_axis`` when set), and so are the labels and
+    the starting margin: the gradients, the sampling scale and the margin
+    update of every round stay on the shards that hold their rows. Each
+    round builds one tree with `grow_tree_distributed`, whose SPMD program
+    (histogram psum = the paper's §2.2 AllReduce) is compiled once for the
+    whole fit; the bytes each shard passes into its collectives add up in
+    ``booster.stats.collective_bytes``. The eval rows and their margins live
+    on the mesh's first device, where each tree is copied to score them.
     """
     from repro.core.booster import BoosterParams, GradientBooster, bin_valid_from_cuts
     from repro.core.policy import ExecutionPolicy
@@ -745,27 +828,32 @@ def fit_sharded(
     booster.stats.host_to_device_bytes += wire_nbytes
     booster.stats.logical_bytes += host_bins.nbytes
     booster.stats.wire_bytes += wire_nbytes
-    labels_j = jnp.asarray(labels)
+    row_sharding = NamedSharding(mesh, P(cfg.data_axes))
+    labels_j = jax.device_put(labels, row_sharding)
     booster.base_margin_ = (
         params.base_score
         if params.base_score is not None
         else booster.objective.base_margin(labels)
     )
-    margin = jnp.full(labels.shape[0], booster.base_margin_, jnp.float32)
+    margin = jnp.full(labels.shape[0], booster.base_margin_, jnp.float32, device=row_sharding)
+    cut_values = jax.device_put(dm.cuts.values, NamedSharding(mesh, P()))
+    cut_ptrs = jax.device_put(dm.cuts.ptrs, NamedSharding(mesh, P()))
 
     eval_bins = eval_labels = eval_margin = None
+    eval_device = mesh.devices.flat[0]
     if eval_set is not None:
         from repro.core.ellpack import bin_batch
 
-        eval_bins = jnp.asarray(bin_batch(eval_set[0], dm.cuts).astype(np.int32))
+        eval_bins = jax.device_put(bin_batch(eval_set[0], dm.cuts).astype(np.int32), eval_device)
         eval_labels = np.asarray(eval_set[1], np.float32)
-        eval_margin = jnp.full(eval_labels.shape[0], booster.base_margin_, jnp.float32)
+        eval_margin = jnp.full(
+            eval_labels.shape[0], booster.base_margin_, jnp.float32, device=eval_device
+        )
     metric_name = booster._metric_name(eval_metric)
 
     from repro.core.booster import EvalRecord
     from repro.core.tree import predict_tree_bins
 
-    row_sharding = NamedSharding(mesh, P(cfg.data_axes))
     t0 = time.perf_counter()
     for it in range(params.n_estimators):
         with span(tracing.ROUND, round=it):
@@ -776,9 +864,8 @@ def fit_sharded(
                 scale = jnp.where(mask, w, 0.0)
             with span(tracing.GROW, round=it):
                 tree, positions = grow_tree_distributed(
-                    mesh, bins, g * scale, h * scale, n_bins, bin_valid,
-                    params.tree_params(), cfg, dm.cuts.values, dm.cuts.ptrs,
-                    transfer_stats=booster.stats,
+                    mesh, bins, g * scale, h * scale, n_bins, bin_valid, tp, cfg,
+                    cut_values, cut_ptrs, transfer_stats=booster.stats,
                 )
             booster.trees.append(tree)
             with span(tracing.MARGINS, round=it):
@@ -789,7 +876,10 @@ def fit_sharded(
             if eval_bins is None:
                 continue
             with span(tracing.EVAL, round=it):
-                pred = predict_tree_bins(tree, eval_bins, tp.max_depth)
+                pred = predict_tree_bins(
+                    jax.tree.map(functools.partial(_held_by, eval_device), tree),
+                    eval_bins, tp.max_depth,
+                )
                 eval_margin = eval_margin + params.learning_rate * pred
                 val = booster._eval(metric_name, eval_labels, eval_margin)
                 booster.eval_history.append(

@@ -104,3 +104,44 @@ def test_bin_values_compiles(one_chip, m):
         one_chip,
         ((ROWS, m), jnp.float32), ((m, N_BINS + 1), jnp.float32), ((m,), jnp.int32),
     )
+
+
+@pytest.fixture(scope="module")
+def four_chips(one_chip):
+    """The four chips of the described ``v5e:2x2`` host (the environment and
+    the compile cache as ``one_chip`` leaves them)."""
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices
+
+
+def test_sharded_tree_program_compiles(four_chips, monkeypatch):
+    """`fit_sharded`'s depthwise tree program on a ("data",) mesh of the four
+    chips, 2^21 HIGGS rows: the Mosaic kernels inside the SPMD program, one
+    histogram all-reduce a level (the root's shares one with the root's g and
+    h, the leaf sums take one, the row counts one after each level but the
+    last: 16 in all), and the bytes `TransferStats.collective_bytes` adds a
+    tree: 128 built node histograms x 28 x 256 bins x (g, h) x 4 B, 254 int32
+    row counts, 2 x 511 leaf sums and the root's 2 sums."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.tree import TreeParams
+    from repro.distributed import DistConfig, gbdt_shard
+    from repro.kernels import _backend
+
+    monkeypatch.setattr(_backend, "on_tpu", lambda: True)  # compiled, not interpreted
+    mesh = Mesh(np.array(four_chips), ("data",), axis_types=(AxisType.Explicit,))
+    rows, m, n_bins = 2**21, 28, N_BINS + 1
+    avals = (((rows, m), "int32"), ((rows,), "float32"), ((rows,), "float32"),
+             ((m, n_bins), "bool"), ((m * n_bins,), "float32"), ((m + 1,), "int32"))
+    program = gbdt_shard._tree_program(
+        mesh, TreeParams(max_depth=DEPTH), DistConfig(kernel_impl="pallas"), n_bins, avals
+    )
+    specs = (P("data", None), P("data"), P("data"), P(), P(), P())
+    args = [jax.ShapeDtypeStruct(s, jnp.dtype(d), sharding=NamedSharding(mesh, spec))
+            for (s, d), spec in zip(avals, specs)]
+    text = program.fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the compiled program"
+    assert text.count(" all-reduce(") == 2 * DEPTH
+    assert program.collective_bytes == 128 * m * n_bins * 2 * 4 + 254 * 4 + 2 * 511 * 4 + 2 * 4
