@@ -23,19 +23,34 @@ exponential backoff through `repro.fault.RetryPolicy` (attempts/aborts in
 ``TransferStats.io_retries`` / ``io_giveups``), and both store and prefetcher
 fire `repro.fault.inject` sites so chaos tests can plant deterministic I/O
 failures.
+
+Read path: one read and one CRC serve each page. `read_page` reads the file
+with `readinto` into a fresh buffer of its own (no `bytes` copy; the syscall
+releases the GIL) and runs `zlib.crc32` over it once. An uncompressed
+(``RAW0``) blob with a manifest CRC is then decoded as views: each ``.npy``
+member is found through the zip's central directory and local header and
+returned as `np.frombuffer` over the checked buffer (``TransferStats.
+direct_page_reads``). zipfile's per-member CRC-32 does not run there: the
+manifest CRC already covers every byte of the blob. zstd (``ZST0``) blobs,
+manifests without a CRC (where the member CRC is the only check), deflated
+members and any zip layout the view parse does not handle go through
+`np.load` as before.
 """
 from __future__ import annotations
 
 import dataclasses
 import io
 import json
+import math
 import os
 import queue
+import struct
 import threading
 import zlib
 from typing import Callable, Iterable, Iterator
 
 import numpy as np
+from numpy.lib import format as npy_format
 
 from repro import tracing
 from repro.fault import inject as fault_inject
@@ -101,6 +116,10 @@ class TransferStats:
     # counted from the operands' static shapes when the program traces and
     # added once per tree; with a narrowed grad_transport, the narrowed bytes
     collective_bytes: int = 0
+    # --- page read path (filled by PageStore.read_page) ---
+    # pages decoded as views over their CRC-checked read buffer; equals
+    # page_loads on uncompressed CRC'd stores, 0 on zstd and CRC-less ones
+    direct_page_reads: int = 0
 
     @property
     def cache_hit_rate(self) -> float:
@@ -153,9 +172,14 @@ class TransferStats:
         self.io_retries = 0
         self.io_giveups = 0
         self.collective_bytes = 0
+        self.direct_page_reads = 0
 
 
 GLOBAL_STATS = TransferStats()
+
+
+_RAW_TAG = b"RAW0"
+_ZSTD_TAG = b"ZST0"
 
 
 def _encode(arrays: dict[str, np.ndarray], compress: bool) -> bytes:
@@ -163,18 +187,121 @@ def _encode(arrays: dict[str, np.ndarray], compress: bool) -> bytes:
     np.savez(buf, **arrays)
     raw = buf.getvalue()
     if compress and _zstd is not None:
-        return b"ZST0" + _zstd.ZstdCompressor(level=1).compress(raw)
-    return b"RAW0" + raw
+        return _ZSTD_TAG + _zstd.ZstdCompressor(level=1).compress(raw)
+    return _RAW_TAG + raw
 
 
-def _decode(blob: bytes) -> dict[str, np.ndarray]:
-    tag, body = blob[:4], blob[4:]
-    if tag == b"ZST0":
+def _decode(blob) -> dict[str, np.ndarray]:
+    """Any blob through `np.load` (zipfile checks each member's CRC-32)."""
+    blob = memoryview(blob)
+    tag, body = bytes(blob[:4]), blob[4:]
+    if tag == _ZSTD_TAG:
         if _zstd is None:
             raise RuntimeError("zstd page but zstandard not installed")
         body = _zstd.ZstdDecompressor().decompress(body)
     data = np.load(io.BytesIO(body))
     return {k: data[k] for k in data.files}
+
+
+def _read_file(path: str) -> np.ndarray:
+    """The whole file in one fresh buffer, filled by `readinto`: no `bytes`
+    object, and the read syscall releases the GIL. Every read gets its own
+    buffer, since the views `_decode_views` returns over it may still be
+    staged to the device while the next page is read."""
+    with open(path, "rb", buffering=0) as fh:
+        buf = np.empty(os.fstat(fh.fileno()).st_size, np.uint8)
+        view = memoryview(buf)
+        n = 0
+        while n < len(buf):
+            got = fh.readinto(view[n:])
+            if not got:
+                break
+            n += got
+    return buf[:n]
+
+
+# the zip records `np.savez` writes (PKWARE APPNOTE 4.3.7, 4.3.12, 4.3.16)
+_LOCAL = struct.Struct("<4s5H3L2H")  # local file header
+_CENTRAL = struct.Struct("<4s6H3L5H2L")  # central directory entry
+_END = struct.Struct("<4s4H2LH")  # end of central directory
+_ZIP_STORED = 0
+_UTF8_NAME = 0x800
+_ENCRYPTED = 0x1
+_ZIP64_MARK = 0xFFFFFFFF
+
+
+def _decode_views(buf: np.ndarray) -> dict[str, np.ndarray] | None:
+    """The ``.npy`` members of a ``RAW0`` blob as views over ``buf``.
+
+    Members are found through the zip's central directory and each one's
+    local header, read straight from ``buf``: the body is never copied.
+    Returns None for a layout left to `_decode` (zip64 end records, an
+    archive comment, a deflated or encrypted member, a member that is not
+    ``.npy``, an ``.npy`` format other than 1.0); raises ValueError where
+    the directory contradicts itself or the blob.
+    """
+    mv = memoryview(buf)
+    base = len(_RAW_TAG)
+    end_at = len(mv) - _END.size
+    if end_at < base:
+        raise ValueError(f"blob of {len(mv)} bytes holds no zip directory")
+    sig, disk, cd_disk, n_here, n_members, cd_size, cd_offset, comment = _END.unpack_from(mv, end_at)
+    if sig != b"PK\x05\x06" or comment or _ZIP64_MARK in (cd_size, cd_offset) or n_members == 0xFFFF:
+        return None
+    if disk or cd_disk or n_here != n_members or base + cd_offset + cd_size != end_at:
+        raise ValueError("zip end record disagrees with the blob")
+    cd_start = base + cd_offset
+    out: dict[str, np.ndarray] = {}
+    at = cd_start
+    for _ in range(n_members):
+        (sig, _, _, flags, method, _, _, _, size, _, n_name, n_extra, n_comment,
+         _, _, _, local) = _CENTRAL.unpack_from(mv, at)
+        if sig != b"PK\x01\x02":
+            raise ValueError(f"no central directory entry at zip offset {at - base}")
+        name_bytes = bytes(mv[at + _CENTRAL.size:at + _CENTRAL.size + n_name])
+        at += _CENTRAL.size + n_name + n_extra + n_comment
+        if at > end_at:
+            raise ValueError("central directory runs past its end")
+        if method != _ZIP_STORED or flags & _ENCRYPTED or _ZIP64_MARK in (size, local):
+            return None
+        name = name_bytes.decode("utf-8" if flags & _UTF8_NAME else "cp437")
+        if not name.endswith(".npy"):
+            return None
+        head = base + local
+        if head + _LOCAL.size > cd_start:
+            raise ValueError(f"member {name!r}: local header past the directory")
+        sig, _, _, l_method, _, _, _, _, _, l_name, l_extra = _LOCAL.unpack_from(mv, head)
+        name_at = head + _LOCAL.size
+        if sig != b"PK\x03\x04" or l_method != method or bytes(mv[name_at:name_at + l_name]) != name_bytes:
+            raise ValueError(f"member {name!r}: local header disagrees with the directory")
+        # the local extra field holds the zip64 sizes np.savez writes
+        # (force_zip64); the directory entry's sizes are the real ones
+        start = name_at + l_name + l_extra
+        if start + size > cd_start:
+            raise ValueError(f"member {name!r} runs past the directory")
+        arr = _npy_view(buf, start, start + size)
+        if arr is None:
+            return None
+        out[name[:-4]] = arr
+    return out
+
+
+def _npy_view(buf: np.ndarray, start: int, stop: int) -> np.ndarray | None:
+    """The array of the ``.npy`` file at ``buf[start:stop]``, as a view;
+    None for a format version other than the 1.0 `np.save` writes."""
+    magic = bytes(buf[start:min(stop, start + 10)])
+    if len(magic) < 10 or magic[:6] != npy_format.MAGIC_PREFIX:
+        raise ValueError("member is not an .npy file")
+    if magic[6:8] != b"\x01\x00":
+        return None
+    data_at = start + 10 + int.from_bytes(magic[8:10], "little")
+    header = io.BytesIO(bytes(buf[start + 8:data_at]))
+    shape, fortran_order, dtype = npy_format.read_array_header_1_0(header)
+    count = math.prod(shape)
+    if data_at + count * dtype.itemsize != stop:
+        raise ValueError(f".npy member holds {stop - data_at} bytes, its header says {shape} {dtype}")
+    arr = np.frombuffer(buf, dtype=dtype, count=count, offset=data_at)
+    return arr.reshape(shape[::-1]).T if fortran_order else arr.reshape(shape)
 
 
 class PageCorruptError(OSError):
@@ -305,10 +432,21 @@ class PageStore:
         return idx
 
     def read_page(self, idx: int) -> dict[str, np.ndarray]:
+        """Page ``idx``'s arrays, after one read and one CRC32 check.
+
+        The file is read once into a buffer of its own and checked once
+        against the manifest's CRC32 (`PageCorruptError` on a mismatch).
+        An uncompressed blob with a manifest CRC decodes as views over that
+        buffer (`_decode_views`; ``stats.direct_page_reads``): zipfile's
+        member CRC would only re-check bytes the manifest CRC has covered.
+        A zstd blob, a manifest entry without a CRC (its member CRCs are
+        then the only check), or a layout the view parse leaves alone goes
+        through `np.load`. Codec decode follows as written in the entry; any
+        decode failure raises `PageDecodeError` naming the page.
+        """
         with span(tracing.PAGE_FETCH, page=idx):
             fault_inject.fire("page_store.read_page", index=idx)
-            with open(self._path(idx), "rb") as fh:
-                blob = fh.read()
+            blob = _read_file(self._path(idx))
             entry = self._meta["pages"][idx] if idx < len(self._meta["pages"]) else {}
             want = entry.get("crc32")  # pre-durability manifests have no CRC
             if want is not None:
@@ -321,7 +459,12 @@ class PageStore:
             codec_name = entry.get("codec", "raw")
             try:
                 fault_inject.fire("page_store.decode", index=idx, codec=codec_name)
-                out = _decode(blob)
+                out = None
+                if want is not None and bytes(blob[:4]) == _RAW_TAG:
+                    out = _decode_views(blob)
+                direct = out is not None
+                if out is None:
+                    out = _decode(blob)
                 codec_meta = entry.get("codec_meta") or {}
                 if codec_meta:
                     from repro.compress import get_codec
@@ -335,6 +478,7 @@ class PageStore:
                 raise PageDecodeError(idx, self._path(idx), codec_name, err) from err
             self.stats.disk_read_bytes += len(blob)
             self.stats.page_loads += 1
+            self.stats.direct_page_reads += direct
             return out
 
     def page_meta(self, idx: int) -> dict:
