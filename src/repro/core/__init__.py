@@ -1,5 +1,5 @@
 """Core GBDT library: the paper's contribution (out-of-core gradient boosting)."""
-from repro.core.booster import BoosterParams, GradientBooster, train_in_core
+from repro.core.booster import BoosterParams, GradientBooster
 from repro.core.ellpack import (
     DEFAULT_PAGE_BYTES,
     MISSING_BIN,
@@ -13,7 +13,7 @@ from repro.core.ellpack import (
 from repro.core.histcache import HistCacheStats, HistogramCache, HistogramStore, LevelPlan
 from repro.core.memory import DeviceMemoryModel
 from repro.core.objectives import LOGISTIC, SQUARED_ERROR, get_objective
-from repro.core.outofcore import ExternalGradientBooster, build_tree_paged
+from repro.core.outofcore import build_tree_paged
 from repro.core.policy import ExecutionDecision, ExecutionPolicy
 from repro.core.quantile import HistogramCuts, QuantileSketch, sketch_dense
 from repro.core.sampling import SamplingConfig, estimate_mvs_lambda, mvs_threshold, sample
@@ -34,8 +34,6 @@ from repro.core.tree import (
 __all__ = [
     "BoosterParams",
     "GradientBooster",
-    "train_in_core",
-    "ExternalGradientBooster",
     "DEFAULT_PAGE_BYTES",
     "MISSING_BIN",
     "EllpackMatrix",

@@ -5,17 +5,14 @@ machinery: the user calls ``fit`` with DMatrix-shaped data and the library
 decides — via `ExecutionPolicy` and the Table-1 byte model — whether the data
 trains in-core (whole ELLPACK matrix resident, Alg. 1 per round), out-of-core
 (PageStream passes per tree level, Alg. 6), or out-of-core with gradient-based
-sampling (compacted page, Alg. 7). All three engines live here behind one
-``fit``; `repro.core.outofcore.ExternalGradientBooster` survives only as a
-deprecated alias.
-
-Sampling in-core is applied as a gradient mask — numerically identical to
-compact-and-build (the histogram only sees sampled rows' gradients) while
-keeping shapes static.
+sampling (compacted page, Alg. 7). One round loop, `GradientBooster._boost`,
+trains every mode; what differs lives in a small engine (`_InCoreEngine`,
+`_PagedEngine`, and `repro.distributed.fit_sharded`'s sharded engine).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 import shutil
@@ -199,12 +196,10 @@ class GradientBooster:
         self.hist_cache = self._make_hist_store()
         self._rng = jax.random.PRNGKey(params.seed)
         self.decision_: ExecutionDecision | None = None
-        # external-mode state (filled when the decision routes off-device)
-        self.pages = None  # PageSet of the last external fit
-        self.stats = None  # its TransferStats
-        self.labels_: np.ndarray | None = None
+        self.stats = None  # the last fit's TransferStats
+        # out-of-core state: the last fit's PageSet and its host margins
+        self.pages = None
         self.margins_: np.ndarray | None = None
-        self._device_cache = None
         self._packed_forest = None  # serving-tier cache (see packed_forest)
 
     def _make_hist_store(self, transfer_stats=None) -> HistogramStore:
@@ -290,335 +285,120 @@ class GradientBooster:
         """Train on a DMatrix, raw arrays, or a batch source.
 
         The `ExecutionPolicy` picks the engine; the decision (mode, sampling
-        fraction, byte model, reason) lands on ``self.decision_``.
+        fraction, byte model, reason) lands on ``self.decision_``. A fit with
+        ``start_iteration == len(self.trees)`` continues the forest (see
+        `resume`), quantizing raw data with the forest's cuts.
         """
         from repro.data.dmatrix import as_dmatrix
 
         p = self.params
         self._packed_forest = None  # forest is about to change
+        if cuts is None and start_iteration:
+            cuts = self.cuts
         with span(tracing.FIT):
             dm = as_dmatrix(data, y, max_bin=p.max_bin, cuts=cuts)
             decision = self.policy.decide(dm, p)
             self.decision_ = decision
             self.cuts = dm.cuts
             if decision.mode == "in_core":
-                return self._fit_in_core(dm, eval_set, eval_metric, verbose, start_iteration)
-            return self._fit_external(
-                dm, decision, eval_set, eval_metric, verbose, start_iteration
-            )
+                engine = functools.partial(_InCoreEngine, self, dm)
+            else:
+                sampling = None
+                if decision.mode == "sampled":
+                    # the params' fraction, else the policy's: the paper's MVS
+                    # default at the largest f whose compacted page fits
+                    sampling = p.sampling if sampling_requested(p.sampling) else (
+                        SamplingConfig(method="mvs", f=decision.sampling_f or 0.5)
+                    )
+                engine = functools.partial(_PagedEngine, self, dm, sampling=sampling)
+            return self._boost(engine, dm, eval_set, eval_metric, verbose, start_iteration)
 
-    # ------------------------------------------------------- in-core engine
-    def _fit_in_core(
-        self, dm, eval_set, eval_metric, verbose, start_iteration=0
+    def _boost(
+        self, make_engine, dm, eval_set, eval_metric, verbose, start_iteration
     ) -> "GradientBooster":
-        p = self.params
+        """The boosting rounds, for every engine.
+
+        ``make_engine(labels, fresh)`` prepares an `_Engine`; the loop owns
+        the rest: the base margin, the replay of a restored forest, the eval
+        set, each round's key and span nest, `EvalRecord`, ``verbose``, early
+        stopping and checkpoints. ``t0`` is taken after all preparation, right
+        before the first round.
+        """
+        p, pol = self.params, self.policy
         if start_iteration and len(self.trees) != start_iteration:
             raise ValueError(
                 f"start_iteration={start_iteration} but the booster holds "
                 f"{len(self.trees)} trees; resume with start_iteration == len(trees)"
             )
+        fresh = start_iteration == 0
         with span(tracing.PREPARE):
-            if start_iteration == 0:
-                # fresh ledger: stats cover exactly this fit() call; in-core fits
-                # get their own TransferStats so histogram spill/fetch traffic is
-                # still observable (self.stats)
-                self.stats = TransferStats()
-                self.hist_cache = self._make_hist_store(self.stats)
-            else:
-                # resumed boosting keeps the store (and its accumulated ledger)
-                # but must not record into a detached private sink
-                if self.stats is None:
-                    self.stats = TransferStats()
-                self.hist_cache.transfer_stats = self.stats
             labels = dm.require_labels()
-            n_bins = dm.n_bins
-            bin_valid = bin_valid_from_cuts(dm.cuts, n_bins)
-            bins = self._stage_in_core(dm.single_page_bins())
-            labels_j = jnp.asarray(labels)
-
-            if start_iteration == 0:
+            if fresh:
                 self.base_margin_ = (
                     p.base_score if p.base_score is not None else self.objective.base_margin(labels)
                 )
-            margin = jnp.full(labels.shape[0], self.base_margin_, jnp.float32)
-            for tree in self.trees:  # resumed run: replay the restored forest
-                margin = margin + p.learning_rate * predict_tree_bins(tree, bins, p.max_depth)
-
-            eval_bins = eval_labels = None
-            eval_margin = None
+            engine = make_engine(labels, fresh)
+            engine.replay(self.trees)
             if eval_set is not None:
                 from repro.core.ellpack import bin_batch
 
-                eval_bins = jnp.asarray(bin_batch(eval_set[0], dm.cuts).astype(np.int32))
-                eval_labels = np.asarray(eval_set[1], dtype=np.float32)
-                eval_margin = jnp.full(eval_labels.shape[0], self.base_margin_, jnp.float32)
+                device = engine.eval_device
+                eval_bins = jax.device_put(bin_batch(eval_set[0], dm.cuts).astype(np.int32), device)
+                eval_labels = np.asarray(eval_set[1], np.float32)
+                eval_margin = jnp.full(
+                    eval_labels.shape[0], self.base_margin_, jnp.float32, device=device
+                )
+
+                def add_tree(margin, tree):
+                    pred = predict_tree_bins(engine.eval_tree(tree), eval_bins, p.max_depth)
+                    return margin + p.learning_rate * pred
+
                 for tree in self.trees:
-                    eval_margin = eval_margin + p.learning_rate * predict_tree_bins(
-                        tree, eval_bins, p.max_depth
-                    )
+                    eval_margin = add_tree(eval_margin, tree)
             metric_name = self._metric_name(eval_metric)
 
-        tp = p.tree_params()
         t0 = time.perf_counter()
         best_metric, best_iter = None, -1
         for it in range(start_iteration, p.n_estimators):
             with span(tracing.ROUND, round=it):
                 with span(tracing.GRAD, round=it):
-                    g, h = self.objective.grad_hess(margin, labels_j)
-                    self._rng, k = jax.random.split(self._rng)
-                    mask, w = sample(k, g, h, p.sampling)
-                    scale = jnp.where(mask, w, 0.0)
+                    g, h = self.objective.grad_hess(engine.margin(), engine.labels)
+                    self._rng, key = jax.random.split(self._rng)
                 with span(tracing.GROW, round=it):
-                    res = grow_tree(
-                        bins,
-                        g * scale,
-                        h * scale,
-                        n_bins,
-                        bin_valid,
-                        tp,
-                        cut_values=dm.cuts.values,
-                        cut_ptrs=dm.cuts.ptrs,
-                        impl=p.kernel_impl,
-                        hist_cache=self.hist_cache,
-                    )
-                self.trees.append(res.tree)
+                    tree, positions = engine.grow(key, g, h)
+                self.trees.append(tree)
                 with span(tracing.MARGINS, round=it):
-                    margin = margin + p.learning_rate * res.tree.leaf_value[res.positions]
-                if eval_bins is None:
-                    continue
-                with span(tracing.EVAL, round=it):
-                    pred = predict_tree_bins(res.tree, eval_bins, tp.max_depth)
-                    eval_margin = eval_margin + p.learning_rate * pred
-                    val = self._eval(metric_name, eval_labels, eval_margin)
-                    self.eval_history.append(
-                        EvalRecord(it, metric_name, val, time.perf_counter() - t0)
-                    )
-            if verbose:
-                print(f"[{it}] {metric_name}={val:.6f}")
-            better = (
-                best_metric is None
-                or (metric_name in ("auc", "accuracy") and val > best_metric)
-                or (metric_name not in ("auc", "accuracy") and val < best_metric)
-            )
-            if better:
-                best_metric, best_iter = val, it
-            elif (
-                p.early_stopping_rounds
-                and it - best_iter >= p.early_stopping_rounds
-            ):
-                break
-        self.best_iteration_ = best_iter if best_iter >= 0 else len(self.trees) - 1
-        return self
-
-    def _stage_in_core(self, host_bins: np.ndarray):
-        """Stage the whole quantized matrix, through the policy's page codec
-        when it is device-decodable (only the packed wire payload crosses;
-        the decoded int32 bins are identical either way)."""
-        from repro.compress import make_transport
-
-        transport = make_transport(self.policy.page_codec)
-        if transport is None:
-            bins = jnp.asarray(host_bins.astype(np.int32))
-            if self.stats is not None:
-                self.stats.logical_bytes += host_bins.nbytes
-                self.stats.wire_bytes += host_bins.nbytes
-                self.stats.host_to_device_bytes += host_bins.nbytes
-            return bins
-        wire, wire_meta = transport.encode(np.ascontiguousarray(host_bins))
-        bins = transport.decode(jnp.asarray(wire), wire_meta)
-        if self.stats is not None:
-            self.stats.logical_bytes += host_bins.nbytes
-            self.stats.wire_bytes += wire.nbytes
-            self.stats.host_to_device_bytes += wire.nbytes
-        return bins
-
-    # ----------------------------------------------------- external engines
-    def _stream(self, indices=None, staging_depth: int | None = None):
-        """One `PageStream` pass over the last external fit's page set."""
-        return self.pages.stream(
-            prefetch_depth=self.policy.prefetch_depth,
-            staging_depth=staging_depth or self.policy.staging_depth,
-            cache=self._device_cache,
-            indices=indices,
-            retry=self.policy.retry,
-            codec=self.policy.page_codec,
-        )
-
-    def _fit_external(
-        self, dm, decision, eval_set, eval_metric, verbose, start_iteration
-    ) -> "GradientBooster":
-        from repro.core.ellpack import bin_batch
-        from repro.pipeline import DevicePageCache
-
-        p, pol = self.params, self.policy
-        with span(tracing.PREPARE):
-            labels = dm.require_labels()
-            pages = dm.page_set()
-            self.pages = pages
-            self.stats = pages.stats
-            # fresh ledger unless resuming mid-boosting (keep the run's totals);
-            # histogram spills/fetches land in the page set's TransferStats so one
-            # ledger carries all device-boundary traffic — resumed stores are
-            # rewired to it (their __init__ sink is a detached placeholder)
-            if start_iteration == 0:
-                self.hist_cache = self._make_hist_store(pages.stats)
-            else:
-                self.hist_cache.transfer_stats = pages.stats
-            self.labels_ = labels
-            n_bins = dm.n_bins
-            bin_valid = bin_valid_from_cuts(dm.cuts, n_bins)
-            labels_j = jnp.asarray(labels)
-
-            if self.margins_ is None:
-                self.base_margin_ = (
-                    p.base_score if p.base_score is not None else self.objective.base_margin(labels)
-                )
-                self.margins_ = np.full(pages.n_rows, self.base_margin_, np.float32)
-
-            eval_bins = eval_labels = eval_margin = None
-            if eval_set is not None:
-                eval_bins = jnp.asarray(bin_batch(eval_set[0], dm.cuts).astype(np.int32))
-                eval_labels = np.asarray(eval_set[1], np.float32)
-                eval_margin = jnp.full(eval_labels.shape[0], self.base_margin_, jnp.float32)
-                md = p.max_depth
-                for t in self.trees:  # resumed run: rebuild eval margins
-                    pred = predict_tree_bins(t, eval_bins, md)
-                    eval_margin = eval_margin + p.learning_rate * pred
-            metric_name = self._metric_name(eval_metric)
-
-        tp = p.tree_params()
-        use_sampling = decision.mode == "sampled"
-        sampling_cfg = p.sampling
-        if use_sampling and not sampling_requested(p.sampling):
-            # policy-chosen fraction: the paper's MVS default at the largest
-            # f whose compacted page fits the budget
-            sampling_cfg = SamplingConfig(method="mvs", f=decision.sampling_f or 0.5)
-        cache_pages = pol.device_cache_pages
-        if cache_pages is None:
-            # auto: cache only when the whole page set fits (a sequential LRU
-            # scan over more pages than capacity evicts every page right
-            # before its reuse — zero hits), and only on the f<1 fast path
-            # where pages are revisited once per iteration.
-            fits = pages.n_pages <= 8
-            cache_pages = pages.n_pages if (use_sampling and fits) else 0
-        self._device_cache = DevicePageCache(cache_pages) if cache_pages > 0 else None
-        t0 = time.perf_counter()
-        for it in range(start_iteration, p.n_estimators):
-            with span(tracing.ROUND, round=it):
-                with span(tracing.GRAD, round=it):
-                    g, h = self.objective.grad_hess(jnp.asarray(self.margins_), labels_j)
-                    self._rng, k = jax.random.split(self._rng)
-                with span(tracing.GROW, round=it):
-                    if use_sampling:
-                        res = self._build_tree_sampled(
-                            k, g, h, n_bins, bin_valid, tp, dm.cuts, sampling_cfg
-                        )
-                    else:
-                        res = self._build_tree_streaming(g, h, n_bins, bin_valid, tp, dm.cuts)
-                self.trees.append(res.tree)
-                with span(tracing.MARGINS, round=it):
-                    self._update_margins(res, tp)
-                if eval_bins is not None:
+                    engine.update(tree, positions)
+                if eval_set is not None:
                     with span(tracing.EVAL, round=it):
-                        pred = predict_tree_bins(res.tree, eval_bins, tp.max_depth)
-                        eval_margin = eval_margin + p.learning_rate * pred
+                        eval_margin = add_tree(eval_margin, tree)
                         val = self._eval(metric_name, eval_labels, eval_margin)
                         self.eval_history.append(
                             EvalRecord(it, metric_name, val, time.perf_counter() - t0)
                         )
-            if verbose and eval_bins is not None:
-                print(f"[{it}] {metric_name}={val:.6f}")
-            if (
-                pol.checkpoint_every
-                and pol.checkpoint_dir
-                and (it + 1) % pol.checkpoint_every == 0
-            ):
+            if pol.checkpoint_every and pol.checkpoint_dir and (it + 1) % pol.checkpoint_every == 0:
                 self.save(pol.checkpoint_dir)
+            if eval_set is None:
+                continue
+            if verbose:
+                print(f"[{it}] {metric_name}={val:.6f}")
+            higher = metric_name in ("auc", "accuracy")
+            if best_metric is None or (val > best_metric if higher else val < best_metric):
+                best_metric, best_iter = val, it
+            elif p.early_stopping_rounds and it - best_iter >= p.early_stopping_rounds:
+                break
+        self.best_iteration_ = best_iter if best_iter >= 0 else len(self.trees) - 1
         return self
 
-    # -------------------------------------------------- Alg. 7 (sampled path)
-    def _sampled_capacity(self, n_rows: int, sampling_cfg: SamplingConfig) -> int:
-        """Static compacted-page capacity: keeps jit shapes stable across
-        iterations (Bernoulli sampling varies the kept count slightly)."""
-        f = sampling_cfg.f if sampling_cfg.method != "goss" else (
-            sampling_cfg.goss_a + sampling_cfg.goss_b
-        )
-        cap = int(n_rows * min(1.0, f * 1.25)) + 256
-        return min(n_rows, -(-cap // 1024) * 1024)
-
-    def _build_tree_sampled(
-        self, key, g, h, n_bins, bin_valid, tp, cuts, sampling_cfg
-    ) -> TreeBuildResult:
-        p = self.params
-        mask, w = sample(key, g, h, sampling_cfg)
-        mask_np = np.asarray(mask)
-        sel = np.nonzero(mask_np)[0]
-        capacity = self._sampled_capacity(self.pages.n_rows, sampling_cfg)
-        if len(sel) > capacity:  # extreme tail: drop lowest-weight extras
-            sel = sel[:capacity]
-        gw = np.asarray(g * w)
-        hw = np.asarray(h * w)
-
-        # Compact: gather sampled rows from every page into one device page
-        # (host-side pass: the prefetcher overlaps disk reads, nothing staged)
-        chunks: list[np.ndarray] = []
-        for _, page in self._stream().iter_host():
-            lo = np.searchsorted(sel, page.row_offset, side="left")
-            hi = np.searchsorted(sel, page.row_offset + page.n_rows, side="left")
-            if hi > lo:
-                local = sel[lo:hi] - page.row_offset
-                chunks.append(page.bins[local])
-        bins_np = np.concatenate(chunks, axis=0) if chunks else np.zeros(
-            (0, self.pages.num_features), np.uint8
-        )
-        pad = capacity - bins_np.shape[0]
-        g_np = np.zeros(capacity, np.float32)
-        h_np = np.zeros(capacity, np.float32)
-        g_np[: len(sel)] = gw[sel]
-        h_np[: len(sel)] = hw[sel]
-        if pad:  # zero-gradient padding rows: no histogram contribution
-            bins_np = np.concatenate(
-                [bins_np, np.zeros((pad, bins_np.shape[1]), np.uint8)], axis=0
-            )
-        from repro.core.ellpack import EllpackPage
-
-        staged = EllpackPage(bins_np, 0)
-        bins_c = self.pages.stage(staged, codec=self.policy.page_codec)
-        res = grow_tree(
-            bins_c, jnp.asarray(g_np), jnp.asarray(h_np), n_bins, bin_valid, tp,
-            cut_values=cuts.values, cut_ptrs=cuts.ptrs,
-            impl=p.kernel_impl, hist_cache=self.hist_cache,
-        )
-        # positions only cover sampled rows -> margin update must stream pages
-        return TreeBuildResult(tree=res.tree, positions=None)
-
-    # ----------------------------------------------- Alg. 6 (streaming path)
-    def _build_tree_streaming(self, g, h, n_bins, bin_valid, tp, cuts) -> TreeBuildResult:
-        from repro.core.outofcore import build_tree_paged
-
-        extents = self.pages.page_extents
-        tree, positions = build_tree_paged(
-            self._stream, extents, g, h, n_bins, bin_valid, tp,
-            cuts.values, cuts.ptrs, impl=self.params.kernel_impl,
-            hist_cache=self.hist_cache, page_skipping=self.policy.page_skipping,
-        )
-        # final positions point at leaves: margin update without re-streaming
-        # (pages cover the rows in order; `_update_margins` copies them back)
-        return TreeBuildResult(
-            tree=tree, positions=jnp.concatenate([positions[i] for i in range(len(extents))])
-        )
-
-    # -------------------------------------------------------- margin update
-    def _update_margins(self, res: TreeBuildResult, tp) -> None:
-        lr = self.params.learning_rate
-        if res.positions is not None:  # streaming path: positions are leaves
-            leaf = np.asarray(res.tree.leaf_value)
-            self.margins_ += lr * leaf[np.asarray(res.positions)]
-            return
-        for sp in self._stream():
-            pred = predict_tree_bins(res.tree, sp.device, tp.max_depth)
-            sl = slice(sp.host.row_offset, sp.host.row_offset + sp.host.n_rows)
-            self.margins_[sl] += lr * np.asarray(pred)
+    def _use_stats(self, stats: TransferStats, fresh: bool) -> None:
+        """Make ``stats`` the fit's ledger. A fresh fit gets a histogram store
+        on it; a resumed one keeps its store (and the store's accumulated
+        ledger), rewired from the detached sink it was built with."""
+        self.stats = stats
+        if fresh:
+            self.hist_cache = self._make_hist_store(stats)
+        else:
+            self.hist_cache.transfer_stats = stats
 
     # ------------------------------------------------------------------ misc
     def _metric_name(self, eval_metric: str) -> str:
@@ -827,54 +607,247 @@ class GradientBooster:
         *,
         policy: ExecutionPolicy | None = None,
     ) -> "GradientBooster":
-        """Restart external-mode training from a checkpoint.
+        """Restart training from a checkpoint.
 
-        Reloads the forest + cuts, rebuilds the margin cache by streaming the
-        data's pages (a `PagedDMatrix` reopening the original cache directory
-        is the natural argument — no raw data needed), and returns a booster
-        ready for ``fit(data, start_iteration=len(trees))``. The checkpointed
-        cuts are authoritative: raw sources are (re)quantized WITH them, and a
+        Reloads the forest, cuts and key, and returns a booster ready for
+        ``fit(data, start_iteration=len(trees))``, whose preparation replays
+        the restored forest onto the margins (out of core by streaming the
+        data's pages: a `PagedDMatrix` reopening the original cache directory
+        is the natural argument, no raw data needed). The checkpointed cuts
+        are authoritative: that fit quantizes raw sources WITH them, and a
         pre-built DMatrix must carry bit-identical cuts — resuming onto pages
         binned with different thresholds would silently corrupt the model, so
-        that raises instead.
+        that raises here instead.
         """
-        from repro.data.dmatrix import DMatrix, as_dmatrix
+        from repro.data.dmatrix import DMatrix
 
         base = cls.load(checkpoint_path)
+        if isinstance(data, DMatrix) and not (
+            np.array_equal(data.cuts.values, base.cuts.values)
+            and np.array_equal(data.cuts.ptrs, base.cuts.ptrs)
+        ):
+            raise ValueError(
+                "DMatrix quantization differs from the checkpoint's cuts; "
+                "its pages were binned with different thresholds than the "
+                "restored trees split on. Reopen the original page cache "
+                "(PagedDMatrix) or resume from the raw source, which the "
+                "continued fit quantizes with the checkpoint's cuts."
+            )
         self = cls(base.params, policy=policy or ExecutionPolicy(mode="out_of_core"))
         self.trees = base.trees
+        self.cuts = base.cuts
         self.base_margin_ = base.base_margin_
         self._rng = base._rng
-        if isinstance(data, DMatrix):
-            dm = data
-            if not (
-                np.array_equal(dm.cuts.values, base.cuts.values)
-                and np.array_equal(dm.cuts.ptrs, base.cuts.ptrs)
-            ):
-                raise ValueError(
-                    "DMatrix quantization differs from the checkpoint's cuts; "
-                    "its pages were binned with different thresholds than the "
-                    "restored trees split on. Reopen the original page cache "
-                    "(PagedDMatrix) or rebuild the DMatrix from the raw source "
-                    "via resume(ckpt, source)."
-                )
-        else:
-            # quantize the source with the checkpointed cuts (no re-sketch)
-            dm = as_dmatrix(data, max_bin=base.params.max_bin, cuts=base.cuts)
-        self.cuts = base.cuts
-        self.pages = dm.page_set()
-        self.stats = self.pages.stats
-        self.margins_ = np.full(self.pages.n_rows, self.base_margin_, np.float32)
-        md = self.params.max_depth
-        for tree in self.trees:
-            for sp in self._stream():
-                pred = predict_tree_bins(tree, sp.device, md)
-                sl = slice(sp.host.row_offset, sp.host.row_offset + sp.host.n_rows)
-                self.margins_[sl] += self.params.learning_rate * np.asarray(pred)
         return self
 
 
-def train_in_core(
-    X: np.ndarray, y: np.ndarray, params: BoosterParams | None = None, **kw
-) -> GradientBooster:
-    return GradientBooster(params, policy=ExecutionPolicy(mode="in_core"), **kw).fit(X, y)
+class _Engine:
+    """What one training engine supplies to `GradientBooster._boost`.
+
+    The constructor is the preparation: it stages the data, picks the fit's
+    `TransferStats` sink and histogram store, and sets the margins to the
+    base margin. ``margin()`` is the margins the round's gradients read;
+    ``grow(key, g, h)`` grows one tree and returns ``(tree, positions)``,
+    ``positions`` being every row's leaf, or None where the engine does not
+    route every row; ``update(tree, positions)`` adds the tree to the
+    margins, from the bins when ``positions`` is None. The eval rows live on
+    ``eval_device`` (None: the default device) and are scored with
+    ``eval_tree(tree)``.
+    """
+
+    eval_device = None
+
+    def __init__(self, booster: GradientBooster, dm, tp: TreeParams | None = None):
+        self.booster = booster
+        self.params = booster.params
+        self.tp = tp or booster.params.tree_params()
+        self.cuts = dm.cuts
+        self.n_bins = dm.n_bins
+        self.bin_valid = bin_valid_from_cuts(dm.cuts, dm.n_bins)
+
+    def replay(self, trees: list[TreeArrays]) -> None:
+        """Add a restored forest to the margins."""
+        for tree in trees:
+            self.update(tree, None)
+
+    def eval_tree(self, tree: TreeArrays) -> TreeArrays:
+        return tree
+
+
+class _InCoreEngine(_Engine):
+    """The whole quantized matrix resident on the default device, Alg. 1 each
+    round; sampling is a gradient mask (numerically compact-and-build: the
+    histogram only sees the sampled rows' gradients) at static shapes."""
+
+    def __init__(self, booster: GradientBooster, dm, labels: np.ndarray, fresh: bool):
+        super().__init__(booster, dm)
+        # in-core fits keep their own TransferStats, so histogram spill/fetch
+        # traffic is still observable (booster.stats)
+        booster._use_stats(
+            TransferStats() if fresh or booster.stats is None else booster.stats, fresh
+        )
+        self.bins = self._stage(dm.single_page_bins())
+        self.labels = jnp.asarray(labels)
+        self._margin = jnp.full(labels.shape[0], booster.base_margin_, jnp.float32)
+
+    def _stage(self, host_bins: np.ndarray) -> Array:
+        """Stage the whole quantized matrix, through the policy's page codec
+        when it is device-decodable (only the packed wire payload crosses;
+        the decoded int32 bins are identical either way)."""
+        from repro.compress import make_transport
+
+        stats = self.booster.stats
+        transport = make_transport(self.booster.policy.page_codec)
+        if transport is None:
+            bins = jnp.asarray(host_bins.astype(np.int32))
+            wire_nbytes = host_bins.nbytes
+        else:
+            wire, wire_meta = transport.encode(np.ascontiguousarray(host_bins))
+            bins = transport.decode(jnp.asarray(wire), wire_meta)
+            wire_nbytes = wire.nbytes
+        stats.logical_bytes += host_bins.nbytes
+        stats.wire_bytes += wire_nbytes
+        stats.host_to_device_bytes += wire_nbytes
+        return bins
+
+    def margin(self) -> Array:
+        return self._margin
+
+    def grow(self, key, g, h) -> TreeBuildResult:
+        p = self.params
+        mask, w = sample(key, g, h, p.sampling)
+        scale = jnp.where(mask, w, 0.0)
+        return grow_tree(
+            self.bins, g * scale, h * scale, self.n_bins, self.bin_valid, self.tp,
+            cut_values=self.cuts.values, cut_ptrs=self.cuts.ptrs,
+            impl=p.kernel_impl, hist_cache=self.booster.hist_cache,
+        )
+
+    def update(self, tree: TreeArrays, positions) -> None:
+        if positions is None:
+            leaf = predict_tree_bins(tree, self.bins, self.tp.max_depth)
+        else:
+            leaf = tree.leaf_value[positions]
+        self._margin = self._margin + self.params.learning_rate * leaf
+
+
+class _PagedEngine(_Engine):
+    """The page set, streamed through `PageStream` pass after pass: Alg. 6
+    grows every tree over all pages; with ``sampling`` set, Alg. 7 grows it
+    over one compacted page of the sampled rows. The margins stay on the
+    host (``booster.margins_``)."""
+
+    def __init__(
+        self,
+        booster: GradientBooster,
+        dm,
+        labels: np.ndarray,
+        fresh: bool,
+        sampling: SamplingConfig | None = None,
+    ):
+        from repro.pipeline import DevicePageCache
+
+        super().__init__(booster, dm)
+        self.pages = booster.pages = dm.page_set()
+        # one ledger carries all device-boundary traffic: histogram spills
+        # and fetches land in the page set's TransferStats
+        booster._use_stats(self.pages.stats, fresh)
+        self.labels = jnp.asarray(labels)
+        booster.margins_ = np.full(self.pages.n_rows, booster.base_margin_, np.float32)
+        self.sampling = sampling
+        cache_pages = booster.policy.device_cache_pages
+        if cache_pages is None:
+            # auto: cache only when the whole page set fits (a sequential LRU
+            # scan over more pages than capacity evicts every page right
+            # before its reuse — zero hits), and only on the sampled path
+            # where pages are revisited once per iteration.
+            fits = self.pages.n_pages <= 8
+            cache_pages = self.pages.n_pages if (sampling is not None and fits) else 0
+        self.cache = DevicePageCache(cache_pages) if cache_pages > 0 else None
+
+    def stream(self, indices=None):
+        """One `PageStream` pass over the page set, or its ``indices``."""
+        pol = self.booster.policy
+        return self.pages.stream(
+            prefetch_depth=pol.prefetch_depth,
+            staging_depth=pol.staging_depth,
+            cache=self.cache,
+            indices=indices,
+            retry=pol.retry,
+            codec=pol.page_codec,
+        )
+
+    def margin(self) -> Array:
+        return jnp.asarray(self.booster.margins_)
+
+    def grow(self, key, g, h) -> tuple[TreeArrays, Array | None]:
+        from repro.core.outofcore import build_tree_paged
+
+        if self.sampling is None:
+            extents = self.pages.page_extents
+            tree, positions = build_tree_paged(
+                self.stream, extents, g, h, self.n_bins, self.bin_valid, self.tp,
+                self.cuts.values, self.cuts.ptrs, impl=self.params.kernel_impl,
+                hist_cache=self.booster.hist_cache,
+                page_skipping=self.booster.policy.page_skipping,
+            )
+            # the pages cover the rows in order: every row's leaf
+            return tree, jnp.concatenate([positions[i] for i in range(len(extents))])
+        return self._grow_sampled(key, g, h), None
+
+    def _grow_sampled(self, key, g, h) -> TreeArrays:
+        """Alg. 7: gather the sampled rows of every page into one compacted
+        device page and grow the tree over it. Its positions cover only the
+        sampled rows, so the margin update re-streams the pages."""
+        from repro.core.ellpack import EllpackPage
+
+        cfg = self.sampling
+        mask, w = sample(key, g, h, cfg)
+        sel = np.nonzero(np.asarray(mask))[0]
+        # a static capacity keeps jit shapes stable across rounds (Bernoulli
+        # sampling varies the kept count slightly)
+        f = cfg.goss_a + cfg.goss_b if cfg.method == "goss" else cfg.f
+        n_rows = self.pages.n_rows
+        cap = int(n_rows * min(1.0, f * 1.25)) + 256
+        capacity = min(n_rows, -(-cap // 1024) * 1024)
+        if len(sel) > capacity:  # extreme tail: drop lowest-weight extras
+            sel = sel[:capacity]
+        gw = np.asarray(g * w)
+        hw = np.asarray(h * w)
+
+        # host-side pass: the prefetcher overlaps disk reads, nothing staged
+        chunks: list[np.ndarray] = []
+        for _, page in self.stream().iter_host():
+            lo = np.searchsorted(sel, page.row_offset, side="left")
+            hi = np.searchsorted(sel, page.row_offset + page.n_rows, side="left")
+            if hi > lo:
+                chunks.append(page.bins[sel[lo:hi] - page.row_offset])
+        bins_np = np.concatenate(chunks, axis=0) if chunks else np.zeros(
+            (0, self.pages.num_features), np.uint8
+        )
+        pad = capacity - bins_np.shape[0]
+        g_np = np.zeros(capacity, np.float32)
+        h_np = np.zeros(capacity, np.float32)
+        g_np[: len(sel)] = gw[sel]
+        h_np[: len(sel)] = hw[sel]
+        if pad:  # zero-gradient padding rows: no histogram contribution
+            bins_np = np.concatenate(
+                [bins_np, np.zeros((pad, bins_np.shape[1]), np.uint8)], axis=0
+            )
+        bins_c = self.pages.stage(EllpackPage(bins_np, 0), codec=self.booster.policy.page_codec)
+        return grow_tree(
+            bins_c, jnp.asarray(g_np), jnp.asarray(h_np), self.n_bins, self.bin_valid,
+            self.tp, cut_values=self.cuts.values, cut_ptrs=self.cuts.ptrs,
+            impl=self.params.kernel_impl, hist_cache=self.booster.hist_cache,
+        ).tree
+
+    def update(self, tree: TreeArrays, positions) -> None:
+        lr = self.params.learning_rate
+        margins = self.booster.margins_
+        if positions is not None:
+            margins += lr * np.asarray(tree.leaf_value)[np.asarray(positions)]
+            return
+        for sp in self.stream():
+            pred = predict_tree_bins(tree, sp.device, self.tp.max_depth)
+            rows = slice(sp.host.row_offset, sp.host.row_offset + sp.host.n_rows)
+            margins[rows] += lr * np.asarray(pred)

@@ -1,38 +1,24 @@
-"""Out-of-core tree construction + the deprecated external-trainer alias.
+"""Out-of-core tree construction.
 
-The out-of-core training engines (Alg. 6 streaming, Alg. 7 sampled) live on
-the unified `GradientBooster` (`repro.core.booster`) and are selected by
+The out-of-core training engines (Alg. 6 streaming, Alg. 7 sampled) run in
+`GradientBooster`'s round loop (`repro.core.booster`) and are selected by
 `ExecutionPolicy`; the data side (sketch, paging, spill) lives on the DMatrix
 sources in `repro.data.dmatrix`. This module keeps what is genuinely about
-out-of-core *tree building*:
-
-  `build_tree_paged`          one tree over streamed pages (either growth
-                              policy), shared by the single-device streaming
-                              engine and the sharded distributed build —
-                              including per-node page skipping for lossguide
-                              passes (pages with no row in the popped node's
-                              window are never fetched or staged; the skips
-                              are recorded in `TransferStats.pages_skipped`);
-  `ExternalGradientBooster`   deprecated alias over the old front door
-                              (`(params, cache_dir=...)` + ``fit(source)``):
-                              forwards to `GradientBooster` with a forced
-                              out-of-core `ExecutionPolicy` and an
-                              `IterDMatrix` built from the source. Warns
-                              `FutureWarning` once per construction.
+out-of-core *tree building*: `build_tree_paged`, one tree over streamed pages
+(either growth policy), shared by the single-device streaming engine and the
+sharded distributed build — including per-node page skipping for lossguide
+passes (pages with no row in the popped node's window are never fetched or
+staged; the skips are recorded in `TransferStats.pages_skipped`).
 
 `PageSet` moved to `repro.data.dmatrix`; importing it from here still works.
 """
 from __future__ import annotations
 
 import inspect
-import warnings
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.core.booster import BoosterParams, GradientBooster
-from repro.core.ellpack import DEFAULT_PAGE_BYTES
 from repro.core.histcache import (
     HistogramCache,
     LevelPlan,
@@ -40,9 +26,7 @@ from repro.core.histcache import (
     node_grad_sums,
     node_row_counts,
 )
-from repro.core.policy import ExecutionPolicy
-from repro.core.tree import predict_tree_bins, tree_growth_driver
-from repro.data.pages import GLOBAL_STATS, TransferStats
+from repro.core.tree import tree_growth_driver
 from repro.kernels import ops
 
 Array = jax.Array
@@ -220,121 +204,3 @@ def build_tree_paged(
         bin_valid, tp, cut_values, cut_ptrs, hist_cache=hist_cache,
     )
     return tree, positions
-
-
-class ExternalGradientBooster(GradientBooster):
-    """Deprecated alias for external-memory training.
-
-    The unified surface is::
-
-        dm = IterDMatrix(source, max_bin=..., cache_dir=...)
-        GradientBooster(params, policy=ExecutionPolicy(mode="out_of_core")).fit(dm)
-
-    This class keeps the historical ``(params, cache_dir=...)`` constructor
-    and ``fit(source)`` signature working: it builds the `IterDMatrix` from
-    the source on first use (``preprocess``) and forwards to the unified
-    engine with a forced out-of-core policy (which promotes to the Alg. 7
-    sampled path when the booster's `SamplingConfig` requests sampling —
-    exactly the old behavior). Emits a `FutureWarning` once per construction.
-    """
-
-    def __init__(
-        self,
-        params: BoosterParams | None = None,
-        cache_dir: str | None = None,
-        page_bytes: int = DEFAULT_PAGE_BYTES,
-        prefetch_depth: int = 2,
-        staging_depth: int = 2,
-        compress_pages: bool = False,
-        stats: TransferStats | None = None,
-        checkpoint_every: int | None = None,
-        checkpoint_dir: str | None = None,
-        device_cache_pages: int | None = None,
-        **kwargs,
-    ):
-        warnings.warn(
-            "ExternalGradientBooster is deprecated: use GradientBooster with "
-            "ExecutionPolicy (e.g. GradientBooster(params, policy=ExecutionPolicy("
-            "mode='out_of_core')).fit(IterDMatrix(source, cache_dir=...)))",
-            FutureWarning,
-            stacklevel=2,
-        )
-        policy = ExecutionPolicy(
-            mode="out_of_core",
-            prefetch_depth=prefetch_depth,
-            staging_depth=staging_depth,
-            device_cache_pages=device_cache_pages,
-            checkpoint_every=checkpoint_every,
-            checkpoint_dir=checkpoint_dir,
-        )
-        super().__init__(params, policy=policy, **kwargs)
-        self.cache_dir = cache_dir
-        self.page_bytes = page_bytes
-        self.compress_pages = compress_pages
-        self.stats = stats or GLOBAL_STATS
-        self._dmatrix = None
-
-    # ------------------------------------------------------------ preprocess
-    def preprocess(self, source, cuts=None):
-        """Alg. 3 (incremental sketch) + Alg. 5 (external ELLPACK pages).
-        Explicit ``cuts`` pin the quantization (checkpoint resume) and skip
-        the sketch pass."""
-        from repro.data.dmatrix import IterDMatrix
-
-        self._dmatrix = IterDMatrix(
-            source,
-            max_bin=self.params.max_bin,
-            cuts=cuts,
-            cache_dir=self.cache_dir,
-            page_bytes=self.page_bytes,
-            compress=self.compress_pages,
-            stats=self.stats,
-        )
-        self.cuts = self._dmatrix.cuts
-        self.labels_ = self._dmatrix.labels
-        self.pages = self._dmatrix.page_set()
-        return self.pages
-
-    # ------------------------------------------------------------------ fit
-    def fit(
-        self,
-        source,
-        eval_set: tuple[np.ndarray, np.ndarray] | None = None,
-        eval_metric: str = "auto",
-        verbose: bool = False,
-        start_iteration: int = 0,
-    ) -> "ExternalGradientBooster":
-        if self._dmatrix is None:
-            self.preprocess(source)
-        return super().fit(
-            self._dmatrix,
-            eval_set=eval_set,
-            eval_metric=eval_metric,
-            verbose=verbose,
-            start_iteration=start_iteration,
-        )
-
-    # -------------------------------------------------------------- restart
-    @classmethod
-    def resume(
-        cls, checkpoint_path: str, source, cache_dir: str | None = None, **kw
-    ) -> "ExternalGradientBooster":
-        """Restart from a checkpoint: reload forest, rebuild margins by streaming."""
-        base = GradientBooster.load(checkpoint_path)
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", FutureWarning)  # resume implies the alias
-            self = cls(base.params, cache_dir=cache_dir, **kw)
-        self.trees = base.trees
-        self.base_margin_ = base.base_margin_
-        self._rng = base._rng
-        # rebuild pages + margin cache from the source, quantized with the
-        # checkpointed cuts (bit-exact thresholds, no re-sketch)
-        self.preprocess(source, cuts=base.cuts)
-        self.margins_ = np.full(self.pages.n_rows, self.base_margin_, np.float32)
-        md = self.params.max_depth
-        for tree in self.trees:
-            for sp in self._stream():
-                pred = predict_tree_bins(tree, sp.device, md)
-                sl = slice(sp.host.row_offset, sp.host.row_offset + sp.host.n_rows)
-                self.margins_[sl] += self.params.learning_rate * np.asarray(pred)
-        return self

@@ -117,7 +117,6 @@ def evaluate_splits(
     left_g = pick(jnp.where(use_dl, cum_g + miss_g[:, :, None], cum_g))
     left_h = pick(jnp.where(use_dl, cum_h + miss_h[:, :, None], cum_h))
 
-    should_split = jnp.isfinite(best_gain) & (best_gain > 0.0)
     return LevelSplits(
         gain=best_gain,
         feature=best_feature,
@@ -127,8 +126,13 @@ def evaluate_splits(
         left_h=left_h,
         right_g=node_g - left_g,
         right_h=node_h - left_h,
-        should_split=should_split,
+        should_split=worth_splitting(best_gain),
     )
+
+
+def worth_splitting(gain: Array) -> Array:
+    """Where a node's best split is made: its gain is finite and positive."""
+    return jnp.isfinite(gain) & (gain > 0.0)
 
 
 def leaf_weight(g: Array, h: Array, reg_lambda: float) -> Array:

@@ -219,6 +219,38 @@ def leaf_values(is_leaf: Array, node_g: Array, node_h: Array, reg_lambda: float)
     return jnp.where(is_leaf & reachable, leaf_weight(node_g, node_h, reg_lambda), 0.0)
 
 
+def apply_level_splits(heap: tuple, depth: int, splits: LevelSplits) -> tuple:
+    """Write one depthwise level's splits into the heap arrays.
+
+    ``heap`` is ``(feature, split_bin, default_left, is_leaf, node_g,
+    node_h)``, each (n_total,); the updated tuple is returned. A node of the
+    level splits where ``splits.should_split`` holds and it is still growable
+    (the root, or a child of a split node); its children start growable iff
+    it split, carrying the split's g/h sums.
+    """
+    feature, split_bin, default_left, is_leaf, node_g, node_h = heap
+    offset, count = 2**depth - 1, 2**depth
+    growable = (
+        ~jax.lax.dynamic_slice(is_leaf, (offset,), (count,)) if depth else jnp.ones(count, bool)
+    )
+    do_split = splits.should_split & growable
+
+    idx = offset + jnp.arange(count)
+    feature = feature.at[idx].set(jnp.where(do_split, splits.feature, 0))
+    split_bin = split_bin.at[idx].set(jnp.where(do_split, splits.split_bin, 0))
+    default_left = default_left.at[idx].set(splits.default_left & do_split)
+    is_leaf = is_leaf.at[idx].set(~do_split)
+
+    left_idx, right_idx = 2 * idx + 1, 2 * idx + 2
+    node_g = node_g.at[left_idx].set(jnp.where(do_split, splits.left_g, 0.0))
+    node_h = node_h.at[left_idx].set(jnp.where(do_split, splits.left_h, 0.0))
+    node_g = node_g.at[right_idx].set(jnp.where(do_split, splits.right_g, 0.0))
+    node_h = node_h.at[right_idx].set(jnp.where(do_split, splits.right_h, 0.0))
+    is_leaf = is_leaf.at[left_idx].set(~do_split)
+    is_leaf = is_leaf.at[right_idx].set(~do_split)
+    return feature, split_bin, default_left, is_leaf, node_g, node_h
+
+
 def grow_tree_generic(
     hist_fn: HistFn,
     partition_fn: PartitionFn,
@@ -259,29 +291,9 @@ def grow_tree_generic(
                 lvl_g = jax.lax.dynamic_slice(node_g, (offset,), (count,))
                 lvl_h = jax.lax.dynamic_slice(node_h, (offset,), (count,))
                 splits: LevelSplits = evaluate_splits(hist, lvl_g, lvl_h, bin_valid, params.split)
-
-                # only nodes that are still growable (parent split) may split
-                growable = (
-                    ~jax.lax.dynamic_slice(is_leaf, (offset,), (count,))
-                    if depth
-                    else jnp.ones(count, bool)
+                feature, split_bin, default_left, is_leaf, node_g, node_h = apply_level_splits(
+                    (feature, split_bin, default_left, is_leaf, node_g, node_h), depth, splits
                 )
-                do_split = splits.should_split & growable
-
-                idx = offset + jnp.arange(count)
-                feature = feature.at[idx].set(jnp.where(do_split, splits.feature, 0))
-                split_bin = split_bin.at[idx].set(jnp.where(do_split, splits.split_bin, 0))
-                default_left = default_left.at[idx].set(splits.default_left & do_split)
-                is_leaf = is_leaf.at[idx].set(~do_split)
-
-                left_idx, right_idx = 2 * idx + 1, 2 * idx + 2
-                node_g = node_g.at[left_idx].set(jnp.where(do_split, splits.left_g, 0.0))
-                node_h = node_h.at[left_idx].set(jnp.where(do_split, splits.left_h, 0.0))
-                node_g = node_g.at[right_idx].set(jnp.where(do_split, splits.right_g, 0.0))
-                node_h = node_h.at[right_idx].set(jnp.where(do_split, splits.right_h, 0.0))
-                # children start growable iff parent split
-                is_leaf = is_leaf.at[left_idx].set(~do_split)
-                is_leaf = is_leaf.at[right_idx].set(~do_split)
 
             # counts feed the next level's build/derive plan; skip the bincount
             # when no histogram follows (last level) or subtraction is off

@@ -132,11 +132,14 @@ class _WorkerState:
         return {}
 
     def _op_reset(self, msg: dict) -> dict:
-        from repro.core.booster import GradientBooster
+        from repro.core.booster import GradientBooster, _PagedEngine
 
         n_trees = 0
         for sh in self.shards.values():
             booster = GradientBooster.resume(msg["checkpoint"], sh.dm)
+            # the out-of-core engine's preparation: base margins, then the
+            # restored forest replayed over the shard's pages
+            _PagedEngine(booster, sh.dm, sh.labels_np, fresh=True).replay(booster.trees)
             sh.margins = booster.margins_
             sh.g = sh.h = None
             sh.positions = {}
@@ -235,8 +238,8 @@ class _WorkerState:
         leaf = np.asarray(msg["tree"]["leaf_value"])
         lr = float(msg["learning_rate"])
         for sh in self.shards.values():
-            # identical arithmetic to GradientBooster._update_margins /
-            # .resume: f32 leaf value, f64 multiply, f32 store — so a
+            # identical arithmetic to the out-of-core engine's margin update
+            # and replay (repro.core.booster._PagedEngine.update) — so a
             # checkpoint-reset worker reproduces these margins bit-for-bit
             for i, (ro, nr) in enumerate(sh.pages.page_extents):
                 pos = np.asarray(sh.positions[i])

@@ -53,7 +53,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Callable, Sequence
 
 import jax
@@ -62,6 +61,7 @@ import numpy as np
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro import tracing
+from repro.core.booster import BoosterParams, GradientBooster, _Engine
 from repro.core.histcache import (
     HistogramStore,
     expand_level,
@@ -69,10 +69,13 @@ from repro.core.histcache import (
     node_grad_sums,
     plan_level,
 )
-from repro.core.split import evaluate_splits
+from repro.core.policy import ExecutionPolicy
+from repro.core.sampling import sample
+from repro.core.split import LevelSplits, evaluate_splits, worth_splitting
 from repro.core.tree import (
     TreeArrays,
     TreeParams,
+    apply_level_splits,
     grow_tree_lossguide_generic,
     leaf_values,
 )
@@ -202,17 +205,9 @@ def _psum_hist(hist: Array, cfg: DistConfig, psum=jax.lax.psum) -> Array:
     return q.psum_restore(out)
 
 
-def _feature_shard_info(cfg: DistConfig):
-    if cfg.feature_axis is None:
-        return None
-    return cfg.feature_axis
-
-
-def _global_best(splits, local_m: int, cfg: DistConfig, coll: _Collectives):
-    """All-gather per-shard best candidates over the feature axis and arg-max.
-
-    Returns per-node global (gain, feature, bin, default_left, child sums).
-    """
+def _global_best(splits, local_m: int, cfg: DistConfig, coll: _Collectives) -> LevelSplits:
+    """All-gather per-shard best candidates over the feature axis and arg-max:
+    each node's global best split."""
     ax = cfg.feature_axis
     shard = jax.lax.axis_index(ax)
     cand = jnp.stack(
@@ -230,8 +225,19 @@ def _global_best(splits, local_m: int, cfg: DistConfig, coll: _Collectives):
     )  # (8, n_nodes)
     allc = coll.all_gather(cand, ax)  # (n_shards, 8, n_nodes)
     best_shard = jnp.argmax(allc[:, 0, :], axis=0)  # (n_nodes,)
-    picked = jnp.take_along_axis(allc, best_shard[None, None, :], axis=0)[0]
-    return picked  # (8, n_nodes)
+    picked = jnp.take_along_axis(allc, best_shard[None, None, :], axis=0)[0]  # (8, n_nodes)
+    gain = picked[0]
+    return LevelSplits(
+        gain=gain,
+        feature=picked[1].astype(jnp.int32),
+        split_bin=picked[2].astype(jnp.int32),
+        default_left=picked[3] > 0.5,
+        left_g=picked[4],
+        left_h=picked[5],
+        right_g=picked[6],
+        right_h=picked[7],
+        should_split=worth_splitting(gain),
+    )
 
 
 def _grow_tree_local(
@@ -297,38 +303,10 @@ def _grow_tree_local(
         splits = evaluate_splits(hist, lvl_g, lvl_h, bin_valid, tp.split)
 
         if cfg.feature_axis is not None:
-            picked = _global_best(splits, local_m, cfg, coll)
-            s_gain = picked[0]
-            s_feature = picked[1].astype(jnp.int32)
-            s_bin = picked[2].astype(jnp.int32)
-            s_dleft = picked[3] > 0.5
-            s_lg, s_lh, s_rg, s_rh = picked[4], picked[5], picked[6], picked[7]
-        else:
-            s_gain, s_feature, s_bin = splits.gain, splits.feature, splits.split_bin
-            s_dleft = splits.default_left
-            s_lg, s_lh = splits.left_g, splits.left_h
-            s_rg, s_rh = splits.right_g, splits.right_h
-
-        growable = (
-            ~jax.lax.dynamic_slice(is_leaf, (offset,), (count,))
-            if depth
-            else jnp.ones(count, bool)
+            splits = _global_best(splits, local_m, cfg, coll)
+        feature, split_bin, default_left, is_leaf, node_g, node_h = apply_level_splits(
+            (feature, split_bin, default_left, is_leaf, node_g, node_h), depth, splits
         )
-        do_split = jnp.isfinite(s_gain) & (s_gain > 0.0) & growable
-
-        idx = offset + jnp.arange(count)
-        feature = feature.at[idx].set(jnp.where(do_split, s_feature, 0))
-        split_bin = split_bin.at[idx].set(jnp.where(do_split, s_bin, 0))
-        default_left = default_left.at[idx].set(s_dleft & do_split)
-        is_leaf = is_leaf.at[idx].set(~do_split)
-
-        left_idx, right_idx = 2 * idx + 1, 2 * idx + 2
-        node_g = node_g.at[left_idx].set(jnp.where(do_split, s_lg, 0.0))
-        node_h = node_h.at[left_idx].set(jnp.where(do_split, s_lh, 0.0))
-        node_g = node_g.at[right_idx].set(jnp.where(do_split, s_rg, 0.0))
-        node_h = node_h.at[right_idx].set(jnp.where(do_split, s_rh, 0.0))
-        is_leaf = is_leaf.at[left_idx].set(~do_split)
-        is_leaf = is_leaf.at[right_idx].set(~do_split)
 
         # ---- partition local rows ----
         if cfg.feature_axis is None:
@@ -757,21 +735,19 @@ def fit_sharded(
     ``**kwargs`` construct one); `BoosterParams.tree_params()` stays the
     single TreeParams derivation point, with `DistConfig` growth overrides
     applied on top. Returns a fitted `GradientBooster` (predict / save /
-    get_params all work).
+    get_params all work), trained by the booster's own round loop over a
+    sharded engine.
 
     The quantized matrix is staged once, row-sharded over ``cfg.data_axes``
     (features over ``cfg.feature_axis`` when set), and so are the labels and
-    the starting margin: the gradients, the sampling scale and the margin
-    update of every round stay on the shards that hold their rows. Each
-    round builds one tree with `grow_tree_distributed`, whose SPMD program
-    (histogram psum = the paper's §2.2 AllReduce) is compiled once for the
-    whole fit; the bytes each shard passes into its collectives add up in
+    the margin: the gradients, the sampling scale and the margin update of
+    every round stay on the shards that hold their rows. Each round builds
+    one tree with `grow_tree_distributed`, whose SPMD program (histogram
+    psum = the paper's §2.2 AllReduce) is compiled once for the whole fit;
+    the bytes each shard passes into its collectives add up in
     ``booster.stats.collective_bytes``. The eval rows and their margins live
     on the mesh's first device, where each tree is copied to score them.
     """
-    from repro.core.booster import BoosterParams, GradientBooster, bin_valid_from_cuts
-    from repro.core.policy import ExecutionPolicy
-    from repro.core.sampling import sample
     from repro.data.dmatrix import as_dmatrix
 
     cfg = cfg or DistConfig()
@@ -782,112 +758,90 @@ def fit_sharded(
     tp = cfg.resolve_tree_params(params.tree_params())
     check_feature_parallel_lossguide(tp, cfg)
 
-    dm = as_dmatrix(data, y, max_bin=params.max_bin)
-    labels = dm.require_labels()
-    n_shards = int(np.prod([mesh.shape[a] for a in cfg.data_axes]))
-    if dm.n_rows % n_shards:
-        raise ValueError(
-            f"n_rows={dm.n_rows} must divide evenly over the data axes "
-            f"{cfg.data_axes} ({n_shards} shards); pad or trim the DMatrix"
-        )
-    if cfg.feature_axis is not None and dm.num_features % mesh.shape[cfg.feature_axis]:
-        raise ValueError(
-            f"num_features={dm.num_features} must divide evenly over "
-            f"feature_axis {cfg.feature_axis!r} ({mesh.shape[cfg.feature_axis]} shards)"
-        )
-
-    from repro.data.pages import TransferStats
-
     booster = GradientBooster(params, policy=ExecutionPolicy(mode="in_core"))
-    booster.cuts = dm.cuts
-    # one ledger for the whole sharded fit: the host-driven lossguide store's
-    # histogram spill/fetch traffic (DistConfig.hist_budget_bytes) is
-    # observable on the returned booster, like every other engine
-    booster.stats = TransferStats()
-    n_bins = dm.n_bins
-    bin_valid = bin_valid_from_cuts(dm.cuts, n_bins)
-    from repro.compress import make_transport
+    with span(tracing.FIT):
+        dm = as_dmatrix(data, y, max_bin=params.max_bin)
+        n_shards = int(np.prod([mesh.shape[a] for a in cfg.data_axes]))
+        if dm.n_rows % n_shards:
+            raise ValueError(
+                f"n_rows={dm.n_rows} must divide evenly over the data axes "
+                f"{cfg.data_axes} ({n_shards} shards); pad or trim the DMatrix"
+            )
+        if cfg.feature_axis is not None and dm.num_features % mesh.shape[cfg.feature_axis]:
+            raise ValueError(
+                f"num_features={dm.num_features} must divide evenly over "
+                f"feature_axis {cfg.feature_axis!r} ({mesh.shape[cfg.feature_axis]} shards)"
+            )
+        booster.cuts = dm.cuts
+        engine = functools.partial(_ShardedEngine, booster, dm, mesh=mesh, cfg=cfg, tp=tp)
+        return booster._boost(engine, dm, eval_set, eval_metric, verbose, 0)
 
-    transport = make_transport(cfg.page_codec)
-    host_bins = dm.single_page_bins()
-    if transport is None:
-        bins = jax.device_put(
-            host_bins.astype(np.int32),
-            NamedSharding(mesh, P(cfg.data_axes, cfg.feature_axis)),
+
+class _ShardedEngine(_Engine):
+    """`fit_sharded`'s engine: the quantized matrix, the labels and the
+    margins row-sharded over ``cfg.data_axes``, one `grow_tree_distributed`
+    program a tree, and the eval rows on the mesh's first device. A sharded
+    fit starts from an empty forest, so nothing is replayed."""
+
+    def __init__(self, booster, dm, labels, fresh, *, mesh: Mesh, cfg: DistConfig, tp):
+        from repro.compress import make_transport
+        from repro.data.pages import TransferStats
+
+        super().__init__(booster, dm, tp)
+        self.mesh, self.cfg = mesh, cfg
+        # one ledger for the whole sharded fit: the host-driven lossguide
+        # store's histogram spill/fetch traffic (DistConfig.hist_budget_bytes)
+        # is observable on the returned booster, like every other engine
+        stats = booster.stats = TransferStats()
+        transport = make_transport(cfg.page_codec)
+        host_bins = dm.single_page_bins()
+        if transport is None:
+            self.bins = jax.device_put(
+                host_bins.astype(np.int32),
+                NamedSharding(mesh, P(cfg.data_axes, cfg.feature_axis)),
+            )
+            wire_nbytes = host_bins.nbytes * 4  # the int32 upcast crosses as-is
+        else:
+            # row-wise bitpacking keeps each row's packed bytes self-contained,
+            # so the wire payload row-shards exactly like the raw matrix
+            # (feature_axis is rejected in DistConfig.__post_init__)
+            wire, wire_meta = transport.encode(np.ascontiguousarray(host_bins))
+            self.bins = transport.decode(
+                jax.device_put(wire, NamedSharding(mesh, P(cfg.data_axes))), wire_meta
+            )
+            wire_nbytes = wire.nbytes
+        stats.host_to_device_bytes += wire_nbytes
+        stats.logical_bytes += host_bins.nbytes
+        stats.wire_bytes += wire_nbytes
+        self.rows = NamedSharding(mesh, P(cfg.data_axes))
+        self.labels = jax.device_put(labels, self.rows)
+        self._margin = jnp.full(
+            labels.shape[0], booster.base_margin_, jnp.float32, device=self.rows
         )
-        wire_nbytes = host_bins.nbytes * 4  # the int32 upcast crosses as-is
-    else:
-        # row-wise bitpacking keeps each row's packed bytes self-contained,
-        # so the wire payload row-shards exactly like the raw matrix
-        # (feature_axis is rejected in DistConfig.__post_init__)
-        wire, wire_meta = transport.encode(np.ascontiguousarray(host_bins))
-        bins = transport.decode(
-            jax.device_put(wire, NamedSharding(mesh, P(cfg.data_axes))), wire_meta
+        self.cut_values = jax.device_put(dm.cuts.values, NamedSharding(mesh, P()))
+        self.cut_ptrs = jax.device_put(dm.cuts.ptrs, NamedSharding(mesh, P()))
+        self.eval_device = mesh.devices.flat[0]
+
+    def margin(self) -> Array:
+        return self._margin
+
+    def grow(self, key, g, h):
+        mask, w = sample(key, g, h, self.params.sampling)
+        scale = jnp.where(mask, w, 0.0)
+        return grow_tree_distributed(
+            self.mesh, self.bins, g * scale, h * scale, self.n_bins, self.bin_valid,
+            self.tp, self.cfg, self.cut_values, self.cut_ptrs,
+            transfer_stats=self.booster.stats,
         )
-        wire_nbytes = wire.nbytes
-    booster.stats.host_to_device_bytes += wire_nbytes
-    booster.stats.logical_bytes += host_bins.nbytes
-    booster.stats.wire_bytes += wire_nbytes
-    row_sharding = NamedSharding(mesh, P(cfg.data_axes))
-    labels_j = jax.device_put(labels, row_sharding)
-    booster.base_margin_ = (
-        params.base_score
-        if params.base_score is not None
-        else booster.objective.base_margin(labels)
-    )
-    margin = jnp.full(labels.shape[0], booster.base_margin_, jnp.float32, device=row_sharding)
-    cut_values = jax.device_put(dm.cuts.values, NamedSharding(mesh, P()))
-    cut_ptrs = jax.device_put(dm.cuts.ptrs, NamedSharding(mesh, P()))
 
-    eval_bins = eval_labels = eval_margin = None
-    eval_device = mesh.devices.flat[0]
-    if eval_set is not None:
-        from repro.core.ellpack import bin_batch
+    def update(self, tree: TreeArrays, positions) -> None:
+        # the leaf table is replicated and positions are row-sharded: the
+        # gather keeps the rows' sharding
+        leaf = tree.leaf_value.at[positions].get(out_sharding=self.rows)
+        self._margin = self._margin + self.params.learning_rate * leaf
 
-        eval_bins = jax.device_put(bin_batch(eval_set[0], dm.cuts).astype(np.int32), eval_device)
-        eval_labels = np.asarray(eval_set[1], np.float32)
-        eval_margin = jnp.full(
-            eval_labels.shape[0], booster.base_margin_, jnp.float32, device=eval_device
-        )
-    metric_name = booster._metric_name(eval_metric)
-
-    from repro.core.booster import EvalRecord
-    from repro.core.tree import predict_tree_bins
-
-    t0 = time.perf_counter()
-    for it in range(params.n_estimators):
-        with span(tracing.ROUND, round=it):
-            with span(tracing.GRAD, round=it):
-                g, h = booster.objective.grad_hess(margin, labels_j)
-                booster._rng, k = jax.random.split(booster._rng)
-                mask, w = sample(k, g, h, params.sampling)
-                scale = jnp.where(mask, w, 0.0)
-            with span(tracing.GROW, round=it):
-                tree, positions = grow_tree_distributed(
-                    mesh, bins, g * scale, h * scale, n_bins, bin_valid, tp, cfg,
-                    cut_values, cut_ptrs, transfer_stats=booster.stats,
-                )
-            booster.trees.append(tree)
-            with span(tracing.MARGINS, round=it):
-                # the leaf table is replicated and positions are row-sharded: the
-                # gather keeps the rows' sharding
-                leaf = tree.leaf_value.at[positions].get(out_sharding=row_sharding)
-                margin = margin + params.learning_rate * leaf
-            if eval_bins is None:
-                continue
-            with span(tracing.EVAL, round=it):
-                pred = predict_tree_bins(
-                    jax.tree.map(functools.partial(_held_by, eval_device), tree),
-                    eval_bins, tp.max_depth,
-                )
-                eval_margin = eval_margin + params.learning_rate * pred
-                val = booster._eval(metric_name, eval_labels, eval_margin)
-                booster.eval_history.append(
-                    EvalRecord(it, metric_name, val, time.perf_counter() - t0)
-                )
-        if verbose:
-            print(f"[{it}] {metric_name}={val:.6f}")
-    return booster
+    def eval_tree(self, tree: TreeArrays) -> TreeArrays:
+        return jax.tree.map(functools.partial(_held_by, self.eval_device), tree)
 
 
 def distributed_train_step(*args, **kwargs):

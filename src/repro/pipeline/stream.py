@@ -21,11 +21,11 @@ end-to-end wall time), so callers can report how much of the serial
 transfer+compute cost the pipeline actually hid — the paper's central claim is
 precisely that this ratio can approach the ideal.
 
-Consumers: `ExternalGradientBooster` (Alg. 6 streaming build, Alg. 7 margin
-update), `distributed.gbdt_shard.grow_tree_distributed_paged` (sharded
-staging), and the serving tier (`repro.serve.engine` streams both row pages
-and paged-forest tree-chunks through this engine; see
-`examples/serve_paged.py`).
+Consumers: `GradientBooster`'s out-of-core engine (Alg. 6 streaming build,
+Alg. 7 compaction and margin update),
+`distributed.gbdt_shard.grow_tree_distributed_paged` (sharded staging), and
+the serving tier (`repro.serve.engine` streams both row pages and paged-forest
+tree-chunks through this engine; see `examples/serve_paged.py`).
 """
 from __future__ import annotations
 

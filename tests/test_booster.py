@@ -2,7 +2,8 @@
 import numpy as np
 import pytest
 
-from repro.core import BoosterParams, GradientBooster, SamplingConfig
+from repro.core import BoosterParams, ExecutionPolicy, GradientBooster, SamplingConfig
+from repro.data.dmatrix import ArrayDMatrix
 from repro.core.objectives import auc, rmse
 
 
@@ -50,16 +51,46 @@ def test_sampling_modes_still_learn(small_classification):
         assert auc(y, b.predict(X)) > 0.85, method
 
 
-def test_early_stopping(small_classification):
+@pytest.mark.parametrize("engine", ["in_core", "out_of_core", "sampled", "fit_sharded"])
+def test_early_stopping(small_classification, engine):
+    """Every engine stops once the eval metric has not improved for
+    ``early_stopping_rounds`` rounds and records the best round. The eval
+    labels are flipped, so the eval AUC falls as the model learns."""
     X, y = small_classification
-    b = GradientBooster(
-        BoosterParams(
-            objective="binary:logistic", early_stopping_rounds=2, **PARAMS
-        )
+    params = BoosterParams(objective="binary:logistic", early_stopping_rounds=2, **PARAMS)
+    eval_set = (X, 1.0 - y)
+    if engine == "fit_sharded":
+        import jax
+
+        from repro.distributed import fit_sharded
+
+        mesh = jax.make_mesh((1,), ("data",))
+        b = fit_sharded(mesh, X, y, params=params, eval_set=eval_set)
+    else:
+        dm = ArrayDMatrix(X, y, max_bin=PARAMS["max_bin"], page_bytes=1024)
+        b = GradientBooster(params, policy=ExecutionPolicy(mode=engine))
+        b.fit(dm, eval_set=eval_set)
+        assert b.decision_.mode == engine
+    assert len(b.trees) < PARAMS["n_estimators"]
+    assert len(b.eval_history) == len(b.trees)
+    assert b.best_iteration_ == len(b.trees) - 1 - params.early_stopping_rounds
+
+
+def test_checkpoint_every_saves_an_in_core_fit(tmp_path, small_classification):
+    """``checkpoint_every`` saves the forest every k rounds in core too, and
+    the checkpoint loads to the fit's first k, 2k, ... trees."""
+    X, y = small_classification
+    ckpt = str(tmp_path / "ckpt")
+    policy = ExecutionPolicy(mode="in_core", checkpoint_every=4, checkpoint_dir=ckpt)
+    b = GradientBooster(BoosterParams(objective="binary:logistic", **PARAMS), policy=policy)
+    b.fit(X, y)
+    # rounds 4 and 8 of 10 were saved; the older generation is kept as .prev
+    restored = GradientBooster.load(ckpt)
+    assert len(restored.trees) == 8
+    assert len(GradientBooster.load(ckpt + ".prev").trees) == 4
+    np.testing.assert_array_equal(
+        restored.predict_margin(X), b.predict_margin(X, iteration_range=(0, 8))
     )
-    b.fit(X, y, eval_set=(X, y))
-    assert len(b.trees) <= PARAMS["n_estimators"]
-    assert b.best_iteration_ >= 0
 
 
 def test_save_load_roundtrip(tmp_path, small_classification):

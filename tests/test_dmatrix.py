@@ -5,7 +5,6 @@ every mode from the same `GradientBooster.fit`, the `ExecutionPolicy` decision
 procedure picks the mode the Table-1 byte model prescribes, and the forests
 match across auto-selected vs explicitly-forced modes (shared oracle).
 """
-import warnings
 
 import numpy as np
 import pytest
@@ -14,7 +13,6 @@ from oracle import assert_forests_equal
 from repro.core import (
     BoosterParams,
     ExecutionPolicy,
-    ExternalGradientBooster,
     GradientBooster,
     SamplingConfig,
 )
@@ -313,41 +311,6 @@ def test_booster_params_validation():
         BoosterParams(max_depth=0)
     with pytest.raises(ValueError, match="mode"):
         ExecutionPolicy(mode="gpu")
-
-
-# ------------------------------------------------------------- deprecation shim
-def test_external_booster_shim_warns_once_and_trains(source, arrays):
-    X, y = arrays
-    with warnings.catch_warnings(record=True) as wlist:
-        warnings.simplefilter("always")
-        shim = ExternalGradientBooster(
-            BoosterParams(seed=0, **PARAMS), page_bytes=PAGE_BYTES
-        )
-        shim.fit(source)
-    future = [w for w in wlist if issubclass(w.category, FutureWarning)]
-    assert len(future) == 1, [str(w.message) for w in future]
-    assert "ExecutionPolicy" in str(future[0].message)
-    assert shim.decision_.mode == "out_of_core"
-    assert len(shim.trees) == PARAMS["n_estimators"]
-
-    # the shim's forest is the unified engine's forest (same cuts via the
-    # shared sketch of the shim's IterDMatrix)
-    b_new = _booster(ExecutionPolicy(mode="out_of_core"))
-    b_new.fit(shim._dmatrix)
-    assert_forests_equal(shim.trees, b_new.trees)
-    assert auc(y, shim.predict(X)) > 0.7
-
-
-def test_external_booster_shim_with_cache_dir(tmp_path, source):
-    with pytest.warns(FutureWarning):
-        shim = ExternalGradientBooster(
-            BoosterParams(seed=0, **PARAMS),
-            cache_dir=str(tmp_path / "cache"),
-            page_bytes=PAGE_BYTES,
-        )
-    shim.fit(source)
-    assert shim.pages.store is not None
-    assert shim.stats.disk_read_bytes > 0
 
 
 # ------------------------------------------------------------------ distributed

@@ -10,8 +10,9 @@ import shutil
 import numpy as np
 import pytest
 
-from repro.core import BoosterParams, ExternalGradientBooster, GradientBooster
+from repro.core import BoosterParams, ExecutionPolicy, GradientBooster
 from repro.core.booster import CheckpointCorruptError
+from repro.data.dmatrix import IterDMatrix
 from repro.data.pages import PageCorruptError, PageStore, Prefetcher, TransferStats
 from repro.data.synthetic import SyntheticSource
 from repro.fault import (
@@ -24,6 +25,7 @@ from repro.fault import (
 from repro.fault import inject as fault_inject
 
 PARAMS = dict(n_estimators=3, max_depth=3, max_bin=32, objective="binary:logistic")
+OUT_OF_CORE = ExecutionPolicy(mode="out_of_core")
 
 
 # ------------------------------------------------------------------ RetryPolicy
@@ -284,13 +286,12 @@ def test_fault_injection_on_page_read_is_absorbed_by_retry(tmp_path):
         FaultSpec(site="page_store.read_page", at=3, exc="OSError", message="yanked disk")
     )
     with injected(plan) as inj:
-        b = ExternalGradientBooster(
-            BoosterParams(seed=0, **PARAMS),
-            cache_dir=str(tmp_path / "cache"),
-            page_bytes=4 * 1024,
+        dm = IterDMatrix(
+            source, max_bin=32, cache_dir=str(tmp_path / "cache"), page_bytes=4 * 1024,
             stats=stats,
         )
-        b.fit(source)
+        b = GradientBooster(BoosterParams(seed=0, **PARAMS), policy=OUT_OF_CORE)
+        b.fit(dm)
     assert len(inj.fired) == 1
     assert stats.io_retries >= 1
     assert stats.io_giveups == 0
@@ -302,13 +303,11 @@ def test_fault_injection_on_page_read_is_absorbed_by_retry(tmp_path):
 @pytest.fixture(scope="module")
 def fitted(tmp_path_factory):
     src = SyntheticSource(n_rows=600, num_features=8, batch_rows=200, task="higgs", seed=4)
-    b = ExternalGradientBooster(
-        BoosterParams(seed=0, **PARAMS),
-        cache_dir=str(tmp_path_factory.mktemp("fitcache") / "cache"),
+    dm = IterDMatrix(
+        src, max_bin=32, cache_dir=str(tmp_path_factory.mktemp("fitcache") / "cache"),
         page_bytes=4 * 1024,
     )
-    b.fit(src)
-    return b
+    return GradientBooster(BoosterParams(seed=0, **PARAMS), policy=OUT_OF_CORE).fit(dm)
 
 
 def test_checkpoint_manifest_and_verify(tmp_path, fitted):
@@ -409,27 +408,29 @@ def test_resume_from_previous_generation_reproduces_training(tmp_path):
     cache = str(tmp_path / "cache")
     ckpt = str(tmp_path / "ckpt")
 
-    full = ExternalGradientBooster(params, cache_dir=cache, page_bytes=4 * 1024)
-    full.fit(src)
+    full = GradientBooster(params, policy=OUT_OF_CORE)
+    full.fit(IterDMatrix(src, max_bin=32, cache_dir=cache, page_bytes=4 * 1024))
 
     import dataclasses
 
-    part = ExternalGradientBooster(
-        dataclasses.replace(params, n_estimators=1), page_bytes=4 * 1024
-    )
-    part.fit(src)
+    part = GradientBooster(dataclasses.replace(params, n_estimators=1), policy=OUT_OF_CORE)
+    part_dm = IterDMatrix(src, max_bin=32, page_bytes=4 * 1024)
+    part.fit(part_dm)
     part.save(ckpt)
     part.params = dataclasses.replace(params, n_estimators=2)
-    part.fit(src, start_iteration=1)
+    part.fit(part_dm, start_iteration=1)
     part.save(ckpt)  # generation 2; generation 1 rotates to .prev
 
     shutil.rmtree(ckpt)  # the crash window claims the newest generation
     good = GradientBooster.last_good_checkpoint(ckpt)
     assert good == ckpt + ".prev"
-    resumed = ExternalGradientBooster.resume(good, src, page_bytes=4 * 1024)
+    resumed = GradientBooster.resume(good, src)
     assert len(resumed.trees) == 1
     resumed.params = params
-    resumed.fit(src, start_iteration=1)
+    # the source re-paged with the checkpointed cuts (no re-sketch)
+    resumed.fit(
+        IterDMatrix(src, max_bin=32, cuts=resumed.cuts, page_bytes=4 * 1024), start_iteration=1
+    )
     X, _ = src.materialize()
     np.testing.assert_allclose(
         resumed.predict_margin(X), full.predict_margin(X), rtol=1e-4, atol=1e-5

@@ -28,7 +28,7 @@ from repro.core import BoosterParams, ExecutionPolicy, GradientBooster
 from repro.data.dmatrix import IterDMatrix
 from repro.data.synthetic import SyntheticSource
 from repro.distributed import DistConfig, fit_sharded
-import repro.distributed.gbdt_shard as gs
+import repro.core.booster as booster_module
 
 ROWS, FEATURES, MAX_BIN, DEPTH, TREES, LR = json.loads(sys.argv[1])
 assert len(jax.devices()) == 4, jax.devices()
@@ -41,14 +41,15 @@ def on_event(event, *_, **__):
 jax.monitoring.register_event_listener(on_event)
 jax.monitoring.register_event_duration_secs_listener(on_event)
 
-# the compile count as each boosting round opens its span
+# the compile count as each boosting round opens its span (the booster's
+# round loop runs every engine, fit_sharded's too)
 round_starts = []
-span = gs.span
+span = booster_module.span
 def counting_span(name, **kw):
     if name == tracing.ROUND:
         round_starts.append(compiles[0])
     return span(name, **kw)
-gs.span = counting_span
+booster_module.span = counting_span
 
 source = SyntheticSource(n_rows=ROWS, num_features=FEATURES, task="higgs", seed=7,
                          batch_rows=1024)

@@ -182,23 +182,22 @@ def test_subtraction_grow_tree_matches_full_build(n, m, max_bin, max_depth, miss
 
 
 def test_booster_paths_agree_with_subtraction_off():
-    """End-to-end: ExternalGradientBooster streaming build, subtraction on vs
-    off, same predictions within float tolerance (Table-2 AUC parity)."""
-    from repro.core import BoosterParams, ExternalGradientBooster
+    """End-to-end: the out-of-core streaming build, subtraction on vs off,
+    same predictions within float tolerance (Table-2 AUC parity)."""
+    from repro.core import BoosterParams, ExecutionPolicy, GradientBooster
+    from repro.data.dmatrix import IterDMatrix
     from repro.data.synthetic import SyntheticSource
 
     src = SyntheticSource(n_rows=900, num_features=10, batch_rows=256, task="higgs", seed=5)
     X, y = src.materialize()
     common = dict(n_estimators=4, max_depth=4, max_bin=16, objective="binary:logistic", seed=0)
 
-    b_sub = ExternalGradientBooster(
-        BoosterParams(hist_subtraction=True, **common), page_bytes=8 * 1024
-    )
-    b_sub.fit(src)
-    b_full = ExternalGradientBooster(
-        BoosterParams(hist_subtraction=False, **common), page_bytes=8 * 1024
-    )
-    b_full.fit(src)
+    dm = IterDMatrix(src, max_bin=16, page_bytes=8 * 1024)
+    out_of_core = ExecutionPolicy(mode="out_of_core")
+    b_sub = GradientBooster(BoosterParams(hist_subtraction=True, **common), policy=out_of_core)
+    b_sub.fit(dm)
+    b_full = GradientBooster(BoosterParams(hist_subtraction=False, **common), policy=out_of_core)
+    b_full.fit(dm)
     np.testing.assert_allclose(
         b_sub.predict_margin(X), b_full.predict_margin(X), rtol=1e-4, atol=1e-5
     )

@@ -211,8 +211,9 @@ def test_grow_policy_validation():
 def test_lossguide_booster_end_to_end_and_serialization(tmp_path):
     """Non-complete trees survive the whole life cycle: boosting, margin
     cache, save/load, prediction parity."""
-    from repro.core import BoosterParams, ExternalGradientBooster, GradientBooster
+    from repro.core import BoosterParams, ExecutionPolicy, GradientBooster
     from repro.core.objectives import auc
+    from repro.data.dmatrix import IterDMatrix
     from repro.data.synthetic import SyntheticSource
 
     src = SyntheticSource(
@@ -235,8 +236,8 @@ def test_lossguide_booster_end_to_end_and_serialization(tmp_path):
         b.predict_margin(X), b2.predict_margin(X), rtol=1e-6, atol=1e-7
     )
 
-    eb = ExternalGradientBooster(params, page_bytes=8 * 1024)
-    eb.fit(src)
+    eb = GradientBooster(params, policy=ExecutionPolicy(mode="out_of_core"))
+    eb.fit(IterDMatrix(src, max_bin=16, page_bytes=8 * 1024))
     assert auc(y, eb.predict(X)) > 0.75
     # streaming margin cache stays consistent with full re-prediction
     np.testing.assert_allclose(

@@ -3,13 +3,24 @@ import numpy as np
 import pytest
 from oracle import assert_forests_equal
 
-from repro.core import BoosterParams, ExternalGradientBooster, GradientBooster, SamplingConfig
+from repro.core import BoosterParams, ExecutionPolicy, GradientBooster, SamplingConfig
 from repro.core.objectives import auc
 from repro.core.quantile import QuantileSketch
+from repro.data.dmatrix import IterDMatrix
 from repro.data.pages import TransferStats
 from repro.data.synthetic import SyntheticSource
 
 PARAMS = dict(n_estimators=6, max_depth=3, max_bin=32, objective="binary:logistic")
+
+
+def external(params, mode="out_of_core"):
+    """A booster forced out of core (``mode="sampled"``: Alg. 7)."""
+    return GradientBooster(params, policy=ExecutionPolicy(mode=mode))
+
+
+def paged(source, **kw):
+    """``source`` sketched and paged by an `IterDMatrix` of 32 bins."""
+    return IterDMatrix(source, max_bin=32, **kw)
 
 
 @pytest.fixture(scope="module")
@@ -31,8 +42,8 @@ def test_streaming_equivalent_to_in_core(source, arrays):
     cuts = sk.finalize()
 
     b_in = GradientBooster(BoosterParams(seed=0, **PARAMS)).fit(X, y, cuts=cuts)
-    b_ooc = ExternalGradientBooster(BoosterParams(seed=0, **PARAMS), page_bytes=8 * 1024)
-    b_ooc.fit(source)
+    b_ooc = external(BoosterParams(seed=0, **PARAMS))
+    b_ooc.fit(paged(source, page_bytes=8 * 1024))
     assert b_ooc.pages.n_pages > 1  # actually paged
     # tree-by-tree structural equality (shared oracle), not just final margins
     assert_forests_equal(b_ooc.trees, b_in.trees)
@@ -44,23 +55,16 @@ def test_streaming_equivalent_to_in_core(source, arrays):
 def test_sampled_path_learns(source, arrays):
     X, y = arrays
     cfg = SamplingConfig(method="mvs", f=0.3)
-    b = ExternalGradientBooster(
-        BoosterParams(sampling=cfg, seed=0, **PARAMS), page_bytes=8 * 1024
-    )
-    b.fit(source)
+    b = external(BoosterParams(sampling=cfg, seed=0, **PARAMS), mode="sampled")
+    b.fit(paged(source, page_bytes=8 * 1024))
     assert auc(y, b.predict(X)) > 0.75
 
 
 def test_disk_pages_and_transfer_stats(tmp_path, source, arrays):
     X, y = arrays
     stats = TransferStats()
-    b = ExternalGradientBooster(
-        BoosterParams(seed=0, **PARAMS),
-        cache_dir=str(tmp_path / "cache"),
-        page_bytes=8 * 1024,
-        stats=stats,
-    )
-    b.fit(source)
+    b = external(BoosterParams(seed=0, **PARAMS))
+    b.fit(paged(source, cache_dir=str(tmp_path / "cache"), page_bytes=8 * 1024, stats=stats))
     assert stats.disk_write_bytes > 0
     assert stats.disk_read_bytes > 0
     assert stats.host_to_device_bytes > 0
@@ -72,17 +76,13 @@ def test_disk_pages_and_transfer_stats(tmp_path, source, arrays):
 def test_sampling_reduces_device_traffic(source):
     """The paper's core claim: compaction slashes per-iteration device traffic."""
     stats_full = TransferStats()
-    b1 = ExternalGradientBooster(
-        BoosterParams(seed=0, **PARAMS), page_bytes=8 * 1024, stats=stats_full
-    )
-    b1.fit(source)
+    b1 = external(BoosterParams(seed=0, **PARAMS))
+    b1.fit(paged(source, page_bytes=8 * 1024, stats=stats_full))
 
     stats_mvs = TransferStats()
     cfg = SamplingConfig(method="mvs", f=0.2)
-    b2 = ExternalGradientBooster(
-        BoosterParams(sampling=cfg, seed=0, **PARAMS), page_bytes=8 * 1024, stats=stats_mvs
-    )
-    b2.fit(source)
+    b2 = external(BoosterParams(sampling=cfg, seed=0, **PARAMS), mode="sampled")
+    b2.fit(paged(source, page_bytes=8 * 1024, stats=stats_mvs))
     assert stats_mvs.host_to_device_bytes < stats_full.host_to_device_bytes
 
 
@@ -91,19 +91,18 @@ def test_checkpoint_resume_identical(tmp_path, source, arrays):
     X, y = arrays
     params = BoosterParams(seed=0, **PARAMS)
 
-    full = ExternalGradientBooster(params, page_bytes=8 * 1024)
-    full.fit(source)
+    full = external(params)
+    full.fit(paged(source, page_bytes=8 * 1024))
     want = full.predict_margin(X)
 
-    part = ExternalGradientBooster(
-        dict_replace(params, n_estimators=3), page_bytes=8 * 1024
-    )
-    part.fit(source)
+    part = external(dict_replace(params, n_estimators=3))
+    part.fit(paged(source, page_bytes=8 * 1024))
     part.save(str(tmp_path / "ckpt"))
 
-    resumed = ExternalGradientBooster.resume(str(tmp_path / "ckpt"), source, page_bytes=8 * 1024)
+    resumed = GradientBooster.resume(str(tmp_path / "ckpt"), source)
     resumed.params = params  # continue to the full horizon
-    resumed.fit(source, start_iteration=3)
+    # the source re-paged with the checkpointed cuts (no re-sketch)
+    resumed.fit(paged(source, cuts=resumed.cuts, page_bytes=8 * 1024), start_iteration=3)
     got = resumed.predict_margin(X)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
@@ -117,8 +116,8 @@ def dict_replace(params, **kw):
 def test_margin_cache_consistency(source, arrays):
     """Cached margins equal full re-prediction after training."""
     X, y = arrays
-    b = ExternalGradientBooster(BoosterParams(seed=0, **PARAMS), page_bytes=8 * 1024)
-    b.fit(source)
+    b = external(BoosterParams(seed=0, **PARAMS))
+    b.fit(paged(source, page_bytes=8 * 1024))
     np.testing.assert_allclose(b.margins_, b.predict_margin(X), rtol=1e-4, atol=1e-5)
 
 

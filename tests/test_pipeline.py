@@ -231,25 +231,23 @@ def test_prefetch_failure_surfaces_after_retries():
 def test_booster_sampled_path_uses_device_cache(source_small):
     """f<1 fast path: the auto device cache skips margin-update transfers
     after the first iteration without changing the model."""
-    from repro.core import BoosterParams, ExternalGradientBooster, SamplingConfig
+    from repro.core import BoosterParams, ExecutionPolicy, GradientBooster, SamplingConfig
+    from repro.data.dmatrix import IterDMatrix
 
     params = dict(
         n_estimators=4, max_depth=3, max_bin=32, objective="binary:logistic",
         sampling=SamplingConfig(method="mvs", f=0.4), seed=0,
     )
     stats_on = TransferStats()
-    b_on = ExternalGradientBooster(
-        BoosterParams(**params), page_bytes=4 * 1024, stats=stats_on
-    )
-    b_on.fit(source_small)
+    b_on = GradientBooster(BoosterParams(**params), policy=ExecutionPolicy(mode="sampled"))
+    b_on.fit(IterDMatrix(source_small, max_bin=32, page_bytes=4 * 1024, stats=stats_on))
     assert stats_on.cache_hits > 0
 
     stats_off = TransferStats()
-    b_off = ExternalGradientBooster(
-        BoosterParams(**params), page_bytes=4 * 1024, stats=stats_off,
-        device_cache_pages=0,
+    b_off = GradientBooster(
+        BoosterParams(**params), policy=ExecutionPolicy(mode="sampled", device_cache_pages=0)
     )
-    b_off.fit(source_small)
+    b_off.fit(IterDMatrix(source_small, max_bin=32, page_bytes=4 * 1024, stats=stats_off))
     assert stats_off.cache_hits == 0
     assert stats_on.host_to_device_bytes < stats_off.host_to_device_bytes
     X, _ = source_small.materialize()

@@ -13,11 +13,12 @@ import pytest
 from repro.core import (
     BoosterParams,
     DeviceMemoryModel,
-    ExternalGradientBooster,
+    ExecutionPolicy,
     GradientBooster,
     SamplingConfig,
 )
 from repro.core.objectives import auc
+from repro.data.dmatrix import IterDMatrix
 from repro.data.synthetic import SyntheticSource
 
 PARAMS = dict(n_estimators=10, max_depth=4, max_bin=32, learning_rate=0.1,
@@ -36,8 +37,9 @@ def higgs():
 @pytest.mark.slow
 def test_end_to_end_beats_baseline(higgs):
     train_src, (X, y), (Xe, ye) = higgs
-    b = ExternalGradientBooster(BoosterParams(seed=0, **PARAMS), page_bytes=16 * 1024)
-    b.fit(train_src, eval_set=(Xe, ye))
+    b = GradientBooster(BoosterParams(seed=0, **PARAMS),
+                        policy=ExecutionPolicy(mode="out_of_core"))
+    b.fit(IterDMatrix(train_src, max_bin=32, page_bytes=16 * 1024), eval_set=(Xe, ye))
     assert b.eval_history[-1].value > 0.72  # well above random on held-out data
     # boosting monotonically helps on average
     assert b.eval_history[-1].value > b.eval_history[0].value
@@ -47,15 +49,17 @@ def test_end_to_end_beats_baseline(higgs):
 def test_sampling_auc_close(higgs):
     """Fig-1 claim: sampled AUC within a small margin of full-data AUC."""
     train_src, (X, y), (Xe, ye) = higgs
-    full = ExternalGradientBooster(BoosterParams(seed=0, **PARAMS), page_bytes=16 * 1024)
-    full.fit(train_src)
+    dm = IterDMatrix(train_src, max_bin=32, page_bytes=16 * 1024)
+    full = GradientBooster(BoosterParams(seed=0, **PARAMS),
+                           policy=ExecutionPolicy(mode="out_of_core"))
+    full.fit(dm)
     a_full = auc(ye, full.predict(Xe))
 
-    mvs = ExternalGradientBooster(
+    mvs = GradientBooster(
         BoosterParams(seed=0, sampling=SamplingConfig(method="mvs", f=0.3), **PARAMS),
-        page_bytes=16 * 1024,
+        policy=ExecutionPolicy(mode="sampled"),
     )
-    mvs.fit(train_src)
+    mvs.fit(dm)
     a_mvs = auc(ye, mvs.predict(Xe))
     assert a_full - a_mvs < 0.03, (a_full, a_mvs)
 
