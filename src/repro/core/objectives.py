@@ -95,15 +95,12 @@ def auc(labels: np.ndarray, scores: np.ndarray) -> float:
         return float("nan")
     order = np.argsort(scores, kind="mergesort")
     sorted_scores = scores[order]
+    # Tied scores share the average of their 1-based ranks. A NaN never
+    # equals itself, so each NaN is a group of its own.
+    starts = np.flatnonzero(np.concatenate(([True], sorted_scores[1:] != sorted_scores[:-1])))
+    ends = np.append(starts[1:], labels.size) - 1
     ranks = np.empty(labels.size, dtype=np.float64)
-    # average ranks for tied groups
-    i = 0
-    while i < labels.size:
-        j = i
-        while j + 1 < labels.size and sorted_scores[j + 1] == sorted_scores[i]:
-            j += 1
-        ranks[order[i : j + 1]] = 0.5 * (i + j) + 1.0
-        i = j + 1
+    ranks[order] = np.repeat(0.5 * (starts + ends) + 1.0, ends - starts + 1)
     sum_pos_ranks = float(np.sum(ranks[labels == 1]))
     return (sum_pos_ranks - n_pos * (n_pos + 1) / 2.0) / (n_pos * n_neg)
 
